@@ -34,7 +34,9 @@ import time
 
 
 def run():
-    from repro.core.campaign import run_campaign, run_campaign_sharded
+    from repro.core.campaign import (
+        run_campaign, run_campaign_sharded, workers_allowed,
+    )
 
     kwargs = dict(
         targets=("flexasr", "vecunit", "hlscnn"),
@@ -71,6 +73,11 @@ def run():
     ]
 
     warm_mps = n / warm_s
+    if not workers_allowed():
+        for workers in (1, 2, 4):
+            print(f"sharded {workers}w: not run (one process per chip: "
+                  "worker processes cannot reach a chip this process holds)")
+        return rows
     steady_mps = {}
     for workers in (1, 2, 4):
         # steady-state rate: first-to-last mutant completion, excluding the

@@ -19,6 +19,9 @@ def main() -> None:
         os.environ.setdefault("REPRO_TABLE4_N", "10")
         os.environ.setdefault("REPRO_TABLE4_STEPS", "150")
 
+    from repro.launch.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_campaign, bench_extraction, bench_kernels,
                             bench_sim_speed, roofline_report, table1_matching,
                             table2_mapping_validation, table3_formal,

@@ -42,6 +42,7 @@ operation-level relative errors reproduce Table 2's magnitudes.
 """
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import jax
@@ -52,8 +53,8 @@ from ..core import ir
 from ..core.egraph import P, V as PV, Rewrite, shape_of
 from ..core.ila import (
     ILA, BulkWrite, Command, CompiledFragment, DataStream, FusedRunner,
-    PackedStream, _shard_batched, fingerprint, fused_lowering,
-    fused_pad_streams,
+    PackedStream, _replicated, _shard_batched, fingerprint, fused_lowering,
+    fused_pad_streams, shard_streams, stream_mesh,
 )
 from . import numerics
 from .numerics import AdaptivFloatSpec
@@ -1278,6 +1279,32 @@ def _fused_dispatch(per_sample):
     return dispatch
 
 
+def _linear_pallas(x, n_ts, ba, bo, w, b, bw, m_out, *, interpret):
+    """Per-sample Pallas leg of the fused linear runner. Activation
+    rows/cols beyond (T, I) are zero, and AFq(0) == 0, so the input masks
+    are implicit; Y's bias rows past T are cleared by the post-mask, exactly
+    as _fn_linear's m_ts does."""
+    from ..kernels.af_gemm import af_gemm
+
+    y = af_gemm(x, w, b, ba, bw, bo, spec=AF, interpret=interpret)
+    m_ts = _mask1(n_ts, MAX_TS)
+    return (y * m_ts[:, None] * m_out[None, :])[:, :MAX_IN]
+
+
+@functools.lru_cache(maxsize=None)
+def fused_linear_pallas(interpret: bool, mesh=None):
+    """The jitted batch dispatch of the Pallas linear leg: vmapped over the
+    stacked activations (B, MAX_TS, MAX_IN), num_ts and the per-sample
+    exponent biases; the padded weights (MAX_OUT, MAX_IN), bias, weight
+    exponent bias and output mask are shared. Sharded per device over the
+    stream ``mesh`` when one is given. One jit for every fragment, so
+    fragments of any weights share its compilations."""
+    return jax.jit(shard_streams(jax.vmap(
+        functools.partial(_linear_pallas, interpret=interpret),
+        in_axes=(0, 0, 0, 0, None, None, None, None),
+    ), 4, 4, mesh))
+
+
 def _fused_linear(frag: CompiledFragment) -> FusedRunner:
     meta, act = frag.meta, int(frag.key[3])
     I, O, bw = meta["I"], meta["O"], meta["bw"]
@@ -1291,40 +1318,42 @@ def _fused_linear(frag: CompiledFragment) -> FusedRunner:
     lowering = fused_lowering()
 
     if lowering == "pallas" and act == ACT_NONE:
-        from ..kernels import ops as kops
-        from ..kernels.af_gemm import af_gemm
+        from ..kernels.ops import pallas_interpret
 
-        wp_j, bp_j, m_out_j = jnp.asarray(wp), jnp.asarray(bp), jnp.asarray(m_out)
+        interpret = pallas_interpret()
+        consts = (jnp.asarray(wp), jnp.asarray(bp), jnp.float32(bw),
+                  jnp.asarray(m_out))
 
-        def one(x, n_ts, ba, bo):
-            # activation rows/cols beyond (T, I) are zero, and AFq(0) == 0,
-            # so the input masks are implicit; Y's bias rows past T are
-            # cleared by the post-mask, exactly as _fn_linear's m_ts does
-            y = af_gemm(x, wp_j, bp_j, ba, bw, bo, spec=AF,
-                        interpret=kops.INTERPRET)
-            m_ts = _mask1(n_ts, MAX_TS)
-            return (y * m_ts[:, None] * m_out_j[None, :])[:, :MAX_IN]
-    else:
-        lowering = "xla"
-        m_in_j, m_out_j = jnp.asarray(m_in), jnp.asarray(m_out)
-        Wq = _afq(jnp.asarray(wp), bw) * m_out_j[:, None] * m_in_j[None, :]
-        bvec = jnp.asarray(bp * m_out)
-        act_fn = [
-            lambda v: v,
-            lambda v: jnp.maximum(v, 0.0),
-            lambda v: 1.0 / (1.0 + jnp.exp(-v)),
-            lambda v: jnp.tanh(v),
-        ][act]
+        def dispatch(prepared):
+            xs, num_ts, ba, bo = (_shard_batched(a) for a in prepared)
+            vf = fused_linear_pallas(interpret, stream_mesh())
+            return vf(xs, num_ts, ba, bo, *_replicated(consts))
 
-        def one(x, n_ts, ba, bo):
-            m_ts = _mask1(n_ts, MAX_TS)
-            Xq = _afq(x, ba) * m_ts[:, None] * m_in_j[None, :]
-            Y = act_fn(Xq @ Wq.T + bvec[None, :])
-            Y = _afq(Y, bo) * m_ts[:, None] * m_out_j[None, :]
-            return Y[:, :MAX_IN]
+        return FusedRunner("flexasr-linear-pallas", _fused_stack, dispatch,
+                           read=read_full, lowering="pallas",
+                           interpret=interpret)
 
-    return FusedRunner(f"flexasr-linear-{lowering}", _fused_stack,
-                       _fused_dispatch(one), read=read_full, lowering=lowering)
+    # XLA leg: replicates _fn_linear step for step (bit-exact vs compiled)
+    m_in_j, m_out_j = jnp.asarray(m_in), jnp.asarray(m_out)
+    Wq = _afq(jnp.asarray(wp), bw) * m_out_j[:, None] * m_in_j[None, :]
+    bvec = jnp.asarray(bp * m_out)
+    act_fn = [
+        lambda v: v,
+        lambda v: jnp.maximum(v, 0.0),
+        lambda v: 1.0 / (1.0 + jnp.exp(-v)),
+        lambda v: jnp.tanh(v),
+    ][act]
+
+    def one(x, n_ts, ba, bo):
+        m_ts = _mask1(n_ts, MAX_TS)
+        Xq = _afq(x, ba) * m_ts[:, None] * m_in_j[None, :]
+        Y = act_fn(Xq @ Wq.T + bvec[None, :])
+        Y = _afq(Y, bo) * m_ts[:, None] * m_out_j[None, :]
+        return Y[:, :MAX_IN]
+
+    return FusedRunner("flexasr-linear-xla", _fused_stack,
+                       _fused_dispatch(one), read=read_full, lowering="xla",
+                       exact=True)
 
 
 def _fused_lstm(frag: CompiledFragment) -> FusedRunner:

@@ -23,6 +23,7 @@ Instructions: WR_ACT / WR_WGT (one V-lane word per command), CFG_CONV
 """
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import jax
@@ -33,8 +34,8 @@ from ..core import ir
 from ..core.egraph import P, V as PV, Rewrite, shape_of
 from ..core.ila import (
     ILA, BulkWrite, Command, CompiledFragment, DataStream, FusedRunner,
-    PackedStream, _shard_batched, fingerprint, fused_lowering,
-    fused_pad_streams,
+    PackedStream, _replicated, _shard_batched, fingerprint, fused_lowering,
+    fused_pad_streams, shard_streams, stream_mesh,
 )
 from . import numerics
 from .target import (
@@ -149,9 +150,12 @@ def _conv_start(st, addr, data):
     )
 
     # full-size stride-1 conv; stride/geometry masking applied on readout.
+    # HIGHEST: 16-bit fixed-point operands are not exact in bf16, which is
+    # what the TPU feeds its MXU from f32 at the default precision
     y = jax.lax.conv_general_dilated(
         act_q, wgt_q, window_strides=(1, 1), padding="VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST,
     )  # (1, MAX_H-MAX_KH+1, MAX_W-MAX_KW+1, MAX_K)
     # accumulators are wide (int32); output re-quantized to 16-bit fixed
     y = numerics.fx_quantize(y, ACT_SPEC)
@@ -405,6 +409,42 @@ def _conv_stack(datas: List[DataStream]):
     return (xs.reshape(B, MAX_H, MAX_W, MAX_C),)
 
 
+KFLAT = MAX_KH * MAX_KW * MAX_C
+KPAD = -(-KFLAT // 128) * 128  # im2col contraction width, lane-aligned
+
+
+def _conv_pallas(x, wflat, mh, mw, mc, *, wspec, interpret):
+    """Per-sample Pallas leg of the fused conv runner: quantize + mask the
+    activation SRAM image, im2col it to (FOH*FOW, KPAD) patches and run
+    them through the fixed-point GEMM against the flattened weights."""
+    from ..kernels.fx_gemm import fx_gemm
+
+    act_q = (numerics.fx_quantize(x, ACT_SPEC)
+             * mh[:, None, None] * mw[None, :, None] * mc[None, None, :])
+    pats = jnp.stack(
+        [act_q[i : i + FOH, j : j + FOW, :]
+         for i in range(MAX_KH) for j in range(MAX_KW)],
+        axis=2,
+    ).reshape(FOH * FOW, KFLAT)
+    pats = jnp.pad(pats, ((0, 0), (0, KPAD - KFLAT)))
+    y = fx_gemm(pats, wflat, x_spec=ACT_SPEC, w_spec=wspec,
+                o_spec=ACT_SPEC, interpret=interpret)
+    return y[:, :MAX_K].reshape(1, FOH, FOW, MAX_K)
+
+
+@functools.lru_cache(maxsize=None)
+def fused_conv_pallas(wspec, interpret: bool, mesh=None):
+    """The jitted batch dispatch of the Pallas conv leg: vmapped over the
+    stacked activation images (B, MAX_H, MAX_W, MAX_C); the flattened
+    (128, KPAD) weights and the geometry masks are shared. Sharded per
+    device over the stream ``mesh`` when one is given. One jit per weight
+    datatype, so every conv layer shares its compilations."""
+    return jax.jit(shard_streams(jax.vmap(
+        functools.partial(_conv_pallas, wspec=wspec, interpret=interpret),
+        in_axes=(0, None, None, None, None),
+    ), 1, 4, mesh))
+
+
 def _fused_conv2d(frag: CompiledFragment) -> FusedRunner:
     m = frag.meta
     wspec = W16 if m["wgt_bits"] >= 16 else W8
@@ -420,43 +460,37 @@ def _fused_conv2d(frag: CompiledFragment) -> FusedRunner:
     mh = jnp.asarray((np.arange(MAX_H) < m["h"]).astype(np.float32))
     mw = jnp.asarray((np.arange(MAX_W) < m["wd"]).astype(np.float32))
     mc_j = jnp.asarray(mc)
-    lowering = fused_lowering()
 
-    if lowering == "pallas":
-        from ..kernels import ops as kops
-        from ..kernels.fx_gemm import fx_gemm
+    if fused_lowering() == "pallas":
+        from ..kernels.ops import pallas_interpret
 
-        KFLAT = MAX_KH * MAX_KW * MAX_C
-        KPAD = -(-KFLAT // 128) * 128
+        interpret = pallas_interpret()
         wflat = np.zeros((128, KPAD), np.float32)
         wflat[:MAX_K, :KFLAT] = wgt_q.reshape(KFLAT, MAX_K).T
-        wflat_j = jnp.asarray(wflat)
+        consts = (jnp.asarray(wflat), mh, mw, mc_j)
 
-        def one(x):
-            act_q = (numerics.fx_quantize(x, ACT_SPEC)
-                     * mh[:, None, None] * mw[None, :, None] * mc_j[None, None, :])
-            pats = jnp.stack(
-                [act_q[i : i + FOH, j : j + FOW, :]
-                 for i in range(MAX_KH) for j in range(MAX_KW)],
-                axis=2,
-            ).reshape(FOH * FOW, KFLAT)
-            pats = jnp.pad(pats, ((0, 0), (0, KPAD - KFLAT)))
-            y = fx_gemm(pats, wflat_j, x_spec=ACT_SPEC, w_spec=wspec,
-                        o_spec=ACT_SPEC, interpret=kops.INTERPRET)
-            return y[:, :MAX_K].reshape(1, FOH, FOW, MAX_K)
-    else:
-        lowering = "xla"
-        wgt_j = jnp.asarray(wgt_q)
+        def dispatch(prepared):
+            (xs,) = prepared
+            vf = fused_conv_pallas(wspec, interpret, stream_mesh())
+            return vf(_shard_batched(xs), *_replicated(consts))
 
-        def one(x):
-            act_q = (numerics.fx_quantize(x[None], ACT_SPEC)
-                     * mh[None, :, None, None] * mw[None, None, :, None]
-                     * mc_j[None, None, None, :])
-            y = jax.lax.conv_general_dilated(
-                act_q, wgt_j, window_strides=(1, 1), padding="VALID",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            )
-            return numerics.fx_quantize(y, ACT_SPEC)
+        return FusedRunner("hlscnn-conv2d-pallas", _conv_stack, dispatch,
+                           read=read_full, lowering="pallas",
+                           interpret=interpret)
+
+    # XLA leg: replays _conv_start's exact lax.conv call (bit-exact)
+    wgt_j = jnp.asarray(wgt_q)
+
+    def one(x):
+        act_q = (numerics.fx_quantize(x[None], ACT_SPEC)
+                 * mh[None, :, None, None] * mw[None, None, :, None]
+                 * mc_j[None, None, None, :])
+        y = jax.lax.conv_general_dilated(
+            act_q, wgt_j, window_strides=(1, 1), padding="VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        return numerics.fx_quantize(y, ACT_SPEC)
 
     vf = jax.jit(jax.vmap(one))
 
@@ -464,8 +498,8 @@ def _fused_conv2d(frag: CompiledFragment) -> FusedRunner:
         (xs,) = prepared
         return vf(_shard_batched(xs))
 
-    return FusedRunner(f"hlscnn-conv2d-{lowering}", _conv_stack, dispatch,
-                       read=read_full, lowering=lowering)
+    return FusedRunner("hlscnn-conv2d-xla", _conv_stack, dispatch,
+                       read=read_full, lowering="xla", exact=True)
 
 
 def _fused_factory(frag: CompiledFragment):

@@ -41,13 +41,31 @@ class AdaptivFloatSpec:
         return self.n_bits - 1 - self.n_exp
 
 
+def floor_log2(ax: jnp.ndarray) -> jnp.ndarray:
+    """``floor(log2(ax))`` of positive normal float32 values, read from the
+    exponent field. Exact on every backend, where ``jnp.log2`` is not: XLA
+    on the CPU and on the TPU rounds ``log2(2**k)`` below ``k`` for some
+    ``k``, which floors an exact power of two one binade low."""
+    bits = jax.lax.bitcast_convert_type(
+        jax.lax.stop_gradient(ax).astype(jnp.float32), jnp.int32)
+    return ((bits >> 23) & 0xFF).astype(jnp.float32) - 127.0
+
+
+def exp2_int(e: jnp.ndarray) -> jnp.ndarray:
+    """``2.0 ** e`` for integer-valued ``e``, clamped to the normal float32
+    range [-126, 127] and built from the exponent field. Exact on every
+    backend, where ``jnp.exp2`` is not (on XLA's CPU, ``exp2(-21.0)`` is
+    not ``2**-21``)."""
+    e = jnp.clip(jax.lax.stop_gradient(e), -126, 127).astype(jnp.int32)
+    return jax.lax.bitcast_convert_type((e + 127) << 23, jnp.float32)
+
+
 def af_exp_bias(x: jnp.ndarray, spec: AdaptivFloatSpec) -> jnp.ndarray:
     """Per-tensor exponent bias: align the max representable exponent with
     the tensor's max magnitude (AdaptivFloat Algorithm 1)."""
     amax = jnp.max(jnp.abs(x))
     amax = jnp.where(amax == 0, 1.0, amax)
-    e_max_target = jnp.floor(jnp.log2(amax))
-    return e_max_target - (2 ** spec.n_exp - 1)
+    return floor_log2(amax) - (2 ** spec.n_exp - 1)
 
 
 def af_quantize(
@@ -62,22 +80,19 @@ def af_quantize(
     sign = jnp.sign(x)
     ax = jnp.abs(x)
     # exponent of each value, clamped into the representable window
-    safe = jnp.where(ax > 0, ax, 1.0)
-    e = jnp.clip(jnp.floor(jnp.log2(safe)), e_lo, e_hi)
-    scale = jnp.exp2(e)
+    e_x = floor_log2(jnp.where(ax > 0, ax, 1.0))
+    e = jnp.clip(e_x, e_lo, e_hi)
     # mantissa in [1, 2): round to m bits
-    man = jnp.clip(ax / scale, 1.0, 2.0 - 2.0 ** (-m))
+    man = jnp.clip(ax * exp2_int(-e), 1.0, 2.0 - 2.0 ** (-m))
     man_q = jnp.round(man * 2.0 ** m) / 2.0 ** m
     # rounding can push mantissa to 2.0 -> bump exponent (saturating)
     bump = man_q >= 2.0
     e2 = jnp.clip(e + bump, e_lo, e_hi)
     man_q = jnp.where(bump & (e2 > e), 1.0, jnp.minimum(man_q, 2.0 - 2.0 ** (-m)))
-    q = man_q * jnp.exp2(e2)
-    # saturate above the max normal; flush-to-zero below half the min normal
-    vmax = (2.0 - 2.0 ** (-m)) * jnp.exp2(e_hi)
-    vmin = jnp.exp2(e_lo)
-    q = jnp.minimum(q, vmax)
-    q = jnp.where(ax < vmin * 0.5, 0.0, q)
+    # at most the max normal (2 - 2^-m) * 2^e_hi: saturates above it
+    q = man_q * exp2_int(e2)
+    # flush-to-zero below half the min normal, 2^(e_lo - 1)
+    q = jnp.where(e_x < e_lo - 1, 0.0, q)
     return (sign * q).astype(x.dtype)
 
 

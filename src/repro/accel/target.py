@@ -527,6 +527,11 @@ class AcceleratorTarget:
         self._fused_cache[key] = runner
         return runner
 
+    def fused_runners(self) -> List[FusedRunner]:
+        """Every fused runner resolved so far (declined signatures
+        omitted), in resolution order."""
+        return [r for r in self._fused_cache.values() if r is not None]
+
     # -- what the core layers consume -------------------------------------
     def rewrites(self) -> List[Rewrite]:
         out: List[Rewrite] = []
@@ -572,9 +577,7 @@ class AcceleratorTarget:
         plus the ILA's jit trace / compiled-runner counters."""
         return {
             "fragments": self.fragments.info(),
-            "fused_runners": sum(
-                1 for v in self._fused_cache.values() if v is not None
-            ),
+            "fused_runners": len(self.fused_runners()),
             **self.ila.jit_cache_info(),
         }
 

@@ -1199,6 +1199,16 @@ def _failure_report(meta: Tuple[str, str, str, str], outcome: str,
     ).to_dict()
 
 
+def workers_allowed() -> bool:
+    """Whether campaign worker processes may be spawned here. A TPU
+    belongs to one process at a time, and this one — which has already
+    touched JAX — holds it, so on a TPU backend a spawned worker could
+    never reach the chip."""
+    import jax
+
+    return jax.default_backend() != "tpu"
+
+
 def run_campaign_sharded(
     workers: int = 2,
     mutant_timeout: float = 300.0,
@@ -1213,7 +1223,8 @@ def run_campaign_sharded(
     """The fault-tolerant sharded campaign: mutants fan out across
     ``workers`` subprocesses, each owning a private device fleet and
     registries (spawned, so mutant state can never leak between workers or
-    back into this process).
+    back into this process). Refused on a TPU backend
+    (:func:`workers_allowed`): a chip belongs to one process.
 
     Per-mutant robustness semantics:
 
@@ -1242,6 +1253,13 @@ def run_campaign_sharded(
     """
     import multiprocessing as mp
 
+    if workers >= 1 and not workers_allowed():
+        raise RuntimeError(
+            f"run_campaign_sharded(workers={workers}) refused on a TPU "
+            "backend: one process per chip — this process holds the chip "
+            "and a spawned worker cannot reach it. Use run_campaign "
+            "(in-process) instead."
+        )
     say = progress or (lambda s: None)
     t_start = time.perf_counter()
     config = _resolve_config(**params)

@@ -183,6 +183,24 @@ def _shard_batched(x: np.ndarray):
     return jax.device_put(x, sh)
 
 
+def shard_streams(fn: Callable, n_batched: int, n_shared: int,
+                  mesh: Optional["jax.sharding.Mesh"]) -> Callable:
+    """Run ``fn`` — whose first ``n_batched`` operands are batch-leading
+    and whose remaining ``n_shared`` are shared — on each device's slice of
+    the batch under ``shard_map`` over ``mesh`` (identity for None). XLA
+    partitions plain computations by itself, but cannot partition a Pallas
+    (Mosaic) kernel: a runner built on one must be sharded this way.
+    Per-sample work is independent, so results equal the unsharded ones."""
+    if mesh is None:
+        return fn
+    P = jax.sharding.PartitionSpec
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(P("stream"),) * n_batched + (P(),) * n_shared,
+        out_specs=P("stream"), check_vma=False,
+    )
+
+
 def _replicated(tree):
     """Replicate an unbatched pytree (setup state, shared payload rows)
     across the stream mesh; identity without a mesh."""
@@ -844,18 +862,30 @@ class FragmentCache:
 # --------------------------------------------------------------------------
 
 
+def default_platform() -> str:
+    """The platform new arrays and computations land on: the
+    ``jax.default_device`` in force (a device or a platform name), else the
+    default backend. Kernels and fused runners read it when they are
+    built, so a replay under ``jax.default_device(jax.devices("cpu")[0])``
+    on a TPU host builds CPU lowerings."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def fused_lowering() -> str:
-    """Which lowering a fused runner should build: ``"pallas"`` when a
-    native accelerator backend is available (or ``REPRO_FUSED_PALLAS=1``
-    forces the Pallas leg, interpret mode included), ``"xla"`` otherwise.
-    ``REPRO_FUSED_FALLBACK=1`` forces the XLA-fused fallback everywhere —
-    the conformance suite uses it so the fallback leg is exercised even on
-    hosts where Pallas lowers natively."""
+    """Which lowering a fused runner should build: ``"pallas"`` on the TPU
+    (or where ``REPRO_FUSED_PALLAS=1`` forces the Pallas leg, interpret
+    mode on the CPU), ``"xla"`` otherwise. ``REPRO_FUSED_FALLBACK=1``
+    forces the XLA-fused fallback everywhere — the conformance suite uses
+    it so the fallback leg is exercised even on hosts where Pallas lowers
+    natively."""
     if os.environ.get("REPRO_FUSED_FALLBACK", "") == "1":
         return "xla"
     if os.environ.get("REPRO_FUSED_PALLAS", "") == "1":
         return "pallas"
-    return "pallas" if jax.default_backend() != "cpu" else "xla"
+    return "pallas" if default_platform() == "tpu" else "xla"
 
 
 def fused_pad_streams(datas: Sequence["DataStream"]) -> List["DataStream"]:
@@ -902,6 +932,11 @@ class FusedRunner:
     dispatch: Callable[[Any], jnp.ndarray]
     read: Optional[Callable] = None
     lowering: str = "xla"
+    #: the Pallas leg was built in interpret mode (CPU backend only)
+    interpret: bool = False
+    #: replicates the compiled tier's arithmetic step for step, so its
+    #: outputs are bit-exact against the oracle rather than within tolerance
+    exact: bool = False
 
     def run(self, datas: Sequence["DataStream"]) -> jnp.ndarray:
         return self.dispatch(self.prepare(datas))

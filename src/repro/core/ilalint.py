@@ -16,10 +16,12 @@ AcceleratorTarget`:
    reserved NOP opcode, and emitted opcodes no instruction decodes.
 
 2. **State dataflow / hazards** — per-instruction read/write sets come out
-   of the jaxpr (a state leaf is *read* if its invar feeds any equation,
-   *written* if its outvar is not the pass-through invar), then a linear
-   walk over planner-emitted :class:`~.ila.PackedStream` probes flags
-   reads of never-written state (uninitialized configuration), reports
+   of the jaxpr (a state leaf is *read* where an equation consumes it —
+   inside ``cond``/``switch`` branches and nested calls too, not where it
+   is merely passed along — and *written* if its outvar is not the
+   pass-through invar), then a linear walk over planner-emitted
+   :class:`~.ila.PackedStream` probes flags reads that happen on every
+   path of never-written state (uninitialized configuration), reports
    carried cross-fragment state (the ``stale_state`` surface) and the
    write-then-read pairs that make a stream order-sensitive (the
    ``cmd_reorder`` sensitivity predicate).
@@ -101,7 +103,8 @@ class InstrEffect:
 
     name: str
     opcode: int
-    reads: frozenset            # state keys consumed by any equation
+    reads: frozenset            # state keys consumed on some path
+    must_reads: frozenset       # state keys consumed on every path
     writes: frozenset           # state keys whose output differs from input
     scalar_writes: frozenset    # writes to ndim-0 registers (configuration)
     buffer_writes: frozenset    # writes to tensor-shaped state
@@ -124,6 +127,54 @@ _EFFECTS_CACHE: "weakref.WeakKeyDictionary[ILA, List[InstrEffect]]" = (
 )
 
 
+def _sub_jaxprs(eqn):
+    """(operands, bodies) of an equation that only hands its operands to
+    sub-jaxprs — ``cond``/``switch`` branches, nested ``jit`` and
+    ``closed_call`` — or None for every other primitive (which consumes
+    its operands itself)."""
+    name = eqn.primitive.name
+    if name == "cond":
+        return eqn.invars[1:], [b.jaxpr for b in eqn.params["branches"]]
+    if name == "jit":
+        return eqn.invars, [eqn.params["jaxpr"].jaxpr]
+    if name == "closed_call":
+        return eqn.invars, [eqn.params["call_jaxpr"].jaxpr]
+    return None
+
+
+def _invar_reads(jaxpr) -> Tuple[set, set]:
+    """Positions of ``jaxpr.invars`` that some equation consumes on some
+    path (``may``) and on every path (``must``). Descends into the
+    sub-jaxprs of :func:`_sub_jaxprs`: an operand a branch or nested call
+    only passes through is not a read. Across ``cond`` branches ``may`` is
+    the union and ``must`` the intersection; the branch index itself is
+    consumed."""
+    pos = {id(v): i for i, v in enumerate(jaxpr.invars)}
+    may: set = set()
+    must: set = set()
+    for eqn in jaxpr.eqns:
+        sub = _sub_jaxprs(eqn)
+        if sub is None:
+            hit = {pos[id(v)] for v in eqn.invars if id(v) in pos}
+            may |= hit
+            must |= hit
+            continue
+        operands, bodies = sub
+        if eqn.primitive.name == "cond" and id(eqn.invars[0]) in pos:
+            may.add(pos[id(eqn.invars[0])])
+            must.add(pos[id(eqn.invars[0])])
+        inner = [_invar_reads(b) for b in bodies]
+        b_may = set().union(*(m for m, _ in inner))
+        b_must = set.intersection(*(m for _, m in inner))
+        for k, v in enumerate(operands):
+            if id(v) in pos:
+                if k in b_may:
+                    may.add(pos[id(v)])
+                if k in b_must:
+                    must.add(pos[id(v)])
+    return may, must
+
+
 def _trace_effect(ila: ILA, ins) -> InstrEffect:
     state = ila.init_state()
     keys = sorted(state)
@@ -133,14 +184,8 @@ def _trace_effect(ila: ILA, ins) -> InstrEffect:
     invars = jaxpr.jaxpr.invars
     # pytree flatten order: state leaves in sorted-key order, addr, data
     assert len(invars) == len(keys) + 2, (ila.name, ins.name, len(invars))
-    by_invar = {id(v): k for v, k in zip(invars, keys)}
-    addr_var, data_var = invars[-2], invars[-1]
-
-    consumed = set()
-    for eqn in jaxpr.jaxpr.eqns:
-        for v in eqn.invars:
-            consumed.add(id(v))
-    reads = frozenset(k for v, k in zip(invars, keys) if id(v) in consumed)
+    may, must = _invar_reads(jaxpr.jaxpr)
+    reads = frozenset(keys[i] for i in may if i < len(keys))
 
     outvars = jaxpr.jaxpr.outvars
     assert len(outvars) == len(keys), (ila.name, ins.name, len(outvars))
@@ -153,11 +198,12 @@ def _trace_effect(ila: ILA, ins) -> InstrEffect:
         name=ins.name,
         opcode=ins.opcode,
         reads=reads,
+        must_reads=frozenset(keys[i] for i in must if i < len(keys)),
         writes=frozenset(writes),
         scalar_writes=scalar,
         buffer_writes=frozenset(writes) - scalar,
-        reads_data=id(data_var) in consumed,
-        reads_addr=id(addr_var) in consumed,
+        reads_data=len(keys) + 1 in may,
+        reads_addr=len(keys) in may,
     )
 
 
@@ -301,7 +347,10 @@ def hazard_pass(
                     carried.add(r)
                 elif r in e.writes:
                     continue  # read-modify-write of reset state (accumulate)
-                elif r not in exempt:
+                elif r not in exempt and r in e.must_reads:
+                    # a read on only some paths (one branch of a mode
+                    # switch) depends on which branch the stream selects,
+                    # which this path-insensitive walk cannot tell
                     uninit.setdefault((e.name, r), op)
             for w in sorted(e.writes & scalar_keys):
                 order_pairs.add((e.name, w))

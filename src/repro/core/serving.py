@@ -113,6 +113,24 @@ class ServedApp:
     est_cycles_per_sample: float
 
 
+def build_app(name: str, **compile_kwargs):
+    """Build a bundled application (``core/apps.py``, name matched
+    case-insensitively) at its builder's size and run flexible matching
+    once. Returns ``(CompileResult, params)``."""
+    from . import apps as app_registry
+    from .compile import compile_program
+
+    by_name = {k.lower(): v for k, v in app_registry.APPLICATIONS.items()}
+    if name.lower() not in by_name:
+        raise KeyError(
+            f"unknown application {name!r}; "
+            f"available: {sorted(app_registry.APPLICATIONS)}"
+        )
+    builder, _dsl = by_name[name.lower()]
+    expr, params = builder()
+    return compile_program(expr, **compile_kwargs), params
+
+
 class RequestHandle:
     """A submitted request: its environments, lifecycle status, and — once
     served — one output array per sample. Thread-safe: the submitting
@@ -269,18 +287,7 @@ class CosimServer:
     def add_app(self, name: str, **compile_kwargs) -> ServedApp:
         """Register a bundled application by name: build it, run flexible
         matching once, keep the extracted program for every request."""
-        from . import apps as app_registry
-        from .compile import compile_program
-
-        by_name = {k.lower(): v for k, v in app_registry.APPLICATIONS.items()}
-        if name.lower() not in by_name:
-            raise KeyError(
-                f"unknown application {name!r}; "
-                f"available: {sorted(app_registry.APPLICATIONS)}"
-            )
-        builder, _dsl = by_name[name.lower()]
-        expr, params = builder()
-        res = compile_program(expr, **compile_kwargs)
+        res, params = build_app(name, **compile_kwargs)
         return self.add_program(name.lower(), res.program, params)
 
     def _estimate_cycles(self, program: ir.Expr, params: Dict[str, Any],

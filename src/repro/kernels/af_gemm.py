@@ -16,42 +16,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..accel.numerics import AdaptivFloatSpec
+from ..accel.numerics import AdaptivFloatSpec, af_quantize
 
 _SPEC = AdaptivFloatSpec(8, 3)
 
 
-def _af_quant(x, exp_bias, n_exp: int, n_man: int):
-    """AdaptivFloat lattice projection (mirrors numerics.af_quantize)."""
-    e_lo = exp_bias
-    e_hi = exp_bias + (2.0 ** n_exp - 1.0)
-    sign = jnp.sign(x)
-    ax = jnp.abs(x)
-    safe = jnp.where(ax > 0, ax, 1.0)
-    e = jnp.clip(jnp.floor(jnp.log2(safe)), e_lo, e_hi)
-    scale = jnp.exp2(e)
-    man = jnp.clip(ax / scale, 1.0, 2.0 - 2.0 ** (-n_man))
-    man_q = jnp.round(man * 2.0 ** n_man) / 2.0 ** n_man
-    bump = man_q >= 2.0
-    e2 = jnp.clip(e + bump, e_lo, e_hi)
-    man_q = jnp.where(bump & (e2 > e), 1.0, jnp.minimum(man_q, 2.0 - 2.0 ** (-n_man)))
-    q = man_q * jnp.exp2(e2)
-    vmax = (2.0 - 2.0 ** (-n_man)) * jnp.exp2(e_hi)
-    vmin = jnp.exp2(e_lo)
-    q = jnp.minimum(q, vmax)
-    q = jnp.where(ax < vmin * 0.5, 0.0, q)
-    return sign * q
-
-
-def _kernel(bx_ref, bw_ref, bo_ref, x_ref, w_ref, b_ref, o_ref, *, n_exp, n_man, nk):
+def _kernel(bx_ref, bw_ref, bo_ref, x_ref, w_ref, b_ref, o_ref, *, spec, nk):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    xq = _af_quant(x_ref[...].astype(jnp.float32), bx_ref[0, 0], n_exp, n_man)
-    wq = _af_quant(w_ref[...].astype(jnp.float32), bw_ref[0, 0], n_exp, n_man)
+    xq = af_quantize(x_ref[...].astype(jnp.float32), spec, bx_ref[0, 0])
+    wq = af_quantize(w_ref[...].astype(jnp.float32), spec, bw_ref[0, 0])
     o_ref[...] += jax.lax.dot_general(
         xq, wq, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -59,7 +37,7 @@ def _kernel(bx_ref, bw_ref, bo_ref, x_ref, w_ref, b_ref, o_ref, *, n_exp, n_man,
     @pl.when(k == nk - 1)
     def _finish():
         y = o_ref[...] + b_ref[...]
-        o_ref[...] = _af_quant(y, bo_ref[0, 0], n_exp, n_man)
+        o_ref[...] = af_quantize(y, spec, bo_ref[0, 0])
 
 
 @functools.partial(
@@ -77,7 +55,7 @@ def af_gemm(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """x:(M,K) fp32, w:(N,K) fp32, b:(N,) -> AFq(AFq(x)@AFq(w)^T + b):(M,N)."""
     M, K = x.shape
@@ -86,7 +64,7 @@ def af_gemm(
     nk = K // bk
     grid = (M // bm, N // bn, nk)
     scalar = lambda v: jnp.asarray(v, jnp.float32).reshape(1, 1)
-    kern = functools.partial(_kernel, n_exp=spec.n_exp, n_man=spec.n_man, nk=nk)
+    kern = functools.partial(_kernel, spec=spec, nk=nk)
     return pl.pallas_call(
         kern,
         grid=grid,

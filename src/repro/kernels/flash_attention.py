@@ -69,7 +69,7 @@ def flash_attention(
     causal: bool = True,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """q:(B,Hq,S,D) k,v:(B,Hkv,S,D) with Hq % Hkv == 0 -> (B,Hq,S,D)."""
     B, Hq, S, D = q.shape

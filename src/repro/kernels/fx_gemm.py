@@ -34,8 +34,11 @@ def _kernel(x_ref, w_ref, o_ref, *, xs, ws, os_, nk):
 
     xq = _fx_quant(x_ref[...].astype(jnp.float32), *xs)
     wq = _fx_quant(w_ref[...].astype(jnp.float32), *ws)
+    # HIGHEST: 16-bit fixed-point tiles are not exact in bf16, the MXU's
+    # input type at the default precision
     o_ref[...] += jax.lax.dot_general(
-        xq, wq, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xq, wq, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(k == nk - 1)
@@ -60,7 +63,7 @@ def fx_gemm(
     bm: int = 16,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """x:(M,K) fp32, w:(N,K) fp32 -> FXq_o(FXq_x(x) @ FXq_w(w)^T):(M,N)."""
     M, K = x.shape

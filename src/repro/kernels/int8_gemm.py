@@ -25,11 +25,11 @@ def _kernel(a_ref, b_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
+    # int8 tiles straight into the MXU with an int32 accumulator (Mosaic
+    # refuses an int32 x int32 matmul)
     o_ref[...] += jax.lax.dot_general(
-        a,
-        b,
+        a_ref[...],
+        b_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
@@ -43,7 +43,7 @@ def int8_gemm(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """a:(M,K) int8, b:(N,K) int8 -> (M,N) int32. Shapes must tile evenly
     (ops.py pads); VMEM working set = bm*bk + bn*bk (int8) + bm*bn (int32)."""

@@ -1,20 +1,23 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles padding to block multiples, dtype plumbing, and the interpret-mode
-switch (CPU container -> interpret=True; on a real TPU set
-``REPRO_PALLAS_INTERPRET=0``).
+decision (:func:`pallas_interpret`), made when a kernel call is built.
 """
 from __future__ import annotations
-
-import os
 
 import jax.numpy as jnp
 
 from ..accel import numerics
 from ..accel.numerics import AdaptivFloatSpec
+from ..core.ila import default_platform
 from . import af_gemm as _af, flash_attention as _fl, int8_gemm as _i8
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def pallas_interpret() -> bool:
+    """Interpret mode for a Pallas kernel built now: on the CPU backend
+    only (tests and CPU hosts), never on the TPU. Asked at build time, not
+    at import, so importing the kernels never initialises a JAX backend."""
+    return default_platform() == "cpu"
 
 
 def _pad_to(x, m, axis):
@@ -32,7 +35,7 @@ def int8_gemm(a: jnp.ndarray, b: jnp.ndarray, *, bm=128, bn=128, bk=128) -> jnp.
     M, N = a.shape[0], b.shape[0]
     ap = _pad_to(_pad_to(a, bm, 0), bk, 1)
     bp = _pad_to(_pad_to(b, bn, 0), bk, 1)
-    out = _i8.int8_gemm(ap, bp, bm=bm, bn=bn, bk=bk, interpret=INTERPRET)
+    out = _i8.int8_gemm(ap, bp, bm=bm, bn=bn, bk=bk, interpret=pallas_interpret())
     return out[:M, :N]
 
 
@@ -56,7 +59,7 @@ def af_linear(
     wp = _pad_to(_pad_to(w, bn, 0), bk, 1)
     bp = _pad_to(b, bn, 0)
     out = _af.af_gemm(
-        xp, wp, bp, bx, bw, bo, spec=spec, bm=bm, bn=bn, bk=bk, interpret=INTERPRET
+        xp, wp, bp, bx, bw, bo, spec=spec, bm=bm, bn=bn, bk=bk, interpret=pallas_interpret()
     )
     return out[:M, :N]
 
@@ -74,5 +77,5 @@ def flash_attention(
         # padded KV must never win the softmax: rely on causal mask for
         # causal=True; for non-causal, mask via -inf scores using a pad flag
         pass
-    out = _fl.flash_attention(qp, kp, vp, causal=causal, bq=bq, bk=bk, interpret=INTERPRET)
+    out = _fl.flash_attention(qp, kp, vp, causal=causal, bq=bq, bk=bk, interpret=pallas_interpret())
     return out[:, :, :S, :]

@@ -32,9 +32,11 @@ import json
 
 from ..core.campaign import (
     format_matrix, matrix_digest, run_campaign, run_campaign_sharded,
+    workers_allowed,
 )
 from ..core.faults import DIAGNOSTIC_FAULT_CLASSES, FAULT_CLASSES
 from ..core.ila import TARGETS
+from .jax_cache import enable_compile_cache
 
 
 def _csv(s):
@@ -129,7 +131,13 @@ def main() -> None:
         stat_floor=args.stat_floor,
         stat_calib_seeds=args.stat_calib_seeds,
     )
-    if args.workers > 1:
+    enable_compile_cache()
+    sharded = args.workers > 1 and workers_allowed()
+    if args.workers > 1 and not sharded:
+        print(f"--workers {args.workers}: not run (one process per chip: "
+              "worker processes cannot reach a chip this process holds); "
+              "running in-process")
+    if sharded:
         result = run_campaign_sharded(
             workers=args.workers,
             mutant_timeout=args.mutant_timeout,

@@ -36,7 +36,9 @@ from __future__ import annotations
 
 import argparse
 import os
+import sys
 import time
+import traceback
 
 import jax
 import jax.numpy as jnp
@@ -64,26 +66,60 @@ def _parse_arrival(spec: str):
     raise SystemExit(f'--arrival must be "asap" or "poisson:RATE", got {spec!r}')
 
 
-def serve_cosim(args) -> None:
-    from ..core import apps, ila
-    from ..core.compile import compile_program
-    from ..core.serving import CosimServer, percentiles_ms
-    from ..core.telemetry import TELEMETRY
+def drive_load(server, app: str, *, requests: int, batch: int,
+               concurrency: int, rate=None, seed: int = 0):
+    """The load generator: submit ``requests`` requests of ``batch``
+    samples to a started server, at most ``concurrency`` outstanding,
+    back to back (``rate=None``) or at Poisson ``rate`` requests/s, and
+    wait for every one. Returns ``(handles, seconds)``."""
+    arrival_rng = np.random.default_rng(seed)
+    handles = []
+    t_load = time.perf_counter()
+    for _r in range(requests):
+        outstanding = [h for h in handles if not h.done()]
+        while len(outstanding) >= max(1, concurrency):
+            outstanding[0].wait()
+            outstanding = [h for h in outstanding if not h.done()]
+        handles.append(server.submit(app, batch=batch))
+        if rate is not None:
+            time.sleep(arrival_rng.exponential(1.0 / rate))
+    for h in handles:
+        h.wait()
+    return handles, time.perf_counter() - t_load
 
+
+def exit_on_failed(handles) -> None:
+    """Exit non-zero, printing the first error, when any accepted request
+    ended ``failed``: the server keeps serving past a failed dispatch, so
+    its caller must look."""
+    from ..core.serving import FAILED
+
+    failed = [h for h in handles if h.status == FAILED]
+    if failed:
+        h = failed[0]
+        traceback.print_exception(h.error, file=sys.stderr)
+        raise SystemExit(
+            f"{len(failed)}/{len(handles)} request(s) failed; first, request "
+            f"{h.id} ({h.app}): {type(h.error).__name__}: {h.error}"
+        )
+
+
+def serve_cosim(args) -> None:
+    from ..core import ila
+    from ..core.serving import CosimServer, build_app, percentiles_ms
+    from ..core.telemetry import TELEMETRY
+    from .jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.trace:
         # span recording (Perfetto export at exit); metrics counters are
         # always on — this only turns on the timed-region ring buffer
         TELEMETRY.enable()
 
-    by_name = {k.lower(): v for k, v in apps.APPLICATIONS.items()}
-    if args.cosim.lower() not in by_name:
-        raise SystemExit(
-            f"unknown application {args.cosim!r}; "
-            f"available: {sorted(apps.APPLICATIONS)}"
-        )
-    builder, _dsl = by_name[args.cosim.lower()]
-    expr, params = builder()
-    res = compile_program(expr)
+    try:
+        res, params = build_app(args.cosim)
+    except KeyError as e:
+        raise SystemExit(e.args[0]) from None
     print(f"compiled {args.cosim}: offloads={res.accelerator_calls} "
           f"policy={res.stats['extraction']['policy']}")
     mesh = ila.set_stream_mesh(args.mesh) if args.mesh != "off" else None
@@ -119,21 +155,11 @@ def serve_cosim(args) -> None:
           f"({cold_ms:.1f} ms/sample incl. compile+traces, compiled engine) "
           f"-> serving on {engine}")
 
-    arrival_rng = np.random.default_rng(args.seed)
-    handles = []
-    t_load = time.perf_counter()
-    for _r in range(args.requests):
-        outstanding = [h for h in handles if not h.done()]
-        while len(outstanding) >= max(1, args.concurrency):
-            outstanding[0].wait()
-            outstanding = [h for h in outstanding if not h.done()]
-        handles.append(server.submit(args.cosim.lower(), batch=args.batch))
-        if rate is not None:
-            time.sleep(arrival_rng.exponential(1.0 / rate))
-    for h in handles:
-        h.wait()
-    load_s = time.perf_counter() - t_load
+    handles, load_s = drive_load(
+        server, args.cosim.lower(), requests=args.requests, batch=args.batch,
+        concurrency=args.concurrency, rate=rate, seed=args.seed)
     server.close(drain=True)
+    exit_on_failed(handles)
 
     served = [h for h in handles if h.status == "done"]
     rejected = [h for h in handles if h.rejected]

@@ -1,6 +1,7 @@
 """Accelerator ILA tests: custom numerics, simulators, VT checks."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis_compat import given, settings, st  # property tests skip if absent
 
 from repro.accel import flexasr as fa, hlscnn as hc, numerics, vta as vt
@@ -40,6 +41,30 @@ class TestAdaptivFloat:
         inside = (np.abs(x) >= vmin) & (np.abs(x) <= vmax)
         rel = np.abs(q[inside] - x[inside]) / np.abs(x[inside])
         assert rel.max(initial=0.0) <= 2.0 ** -(spec.n_man + 1) + 1e-6
+
+    @pytest.mark.parametrize("bias", [-40.0, -18.0, -5.0, 0.0, 20.0])
+    def test_matches_float64_reference_at_every_exponent(self, bias):
+        """Bit-exact against a float64 NumPy model of the lattice in any
+        exponent window, exact powers of two included: the quantizer must not
+        lean on XLA's log2/exp2, approximations on the CPU and the TPU."""
+        spec = numerics.AdaptivFloatSpec(8, 3)
+        m, top = spec.n_man, bias + 2 ** spec.n_exp - 1
+        x = np.concatenate([
+            rng.standard_normal(4096) * 2.0 ** (bias + 5),
+            np.exp2(np.arange(bias - 2, top + 2)),
+        ]).astype(np.float32)
+        ax = np.abs(x.astype(np.float64))
+        e = np.clip(np.floor(np.log2(np.where(ax > 0, ax, 1.0))), bias, top)
+        man = np.clip(ax / 2.0 ** e, 1.0, 2.0 - 2.0 ** -m)
+        man_q = np.round(man * 2 ** m) / 2 ** m
+        bump = man_q >= 2.0
+        e2 = np.clip(e + bump, bias, top)
+        man_q = np.where(bump & (e2 > e), 1.0, np.minimum(man_q, 2.0 - 2.0 ** -m))
+        want = np.where(ax < 2.0 ** (bias - 1), 0.0, man_q * 2.0 ** e2)
+        want = (np.sign(x) * want).astype(np.float32)
+        got = np.asarray(numerics.af_quantize(jnp.asarray(x), spec,
+                                              exp_bias=jnp.float32(bias)))
+        np.testing.assert_array_equal(got, want)
 
     def test_fixed_point_grid(self):
         spec = numerics.FixedPointSpec(8, 3)

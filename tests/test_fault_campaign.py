@@ -401,3 +401,21 @@ def test_boundary_sampling_makes_sat_wrap_op_tier_detectable():
             f"{m.key}: boundary-directed samples did not expose sat_wrap "
             f"at the op tier ({m.tiers['op_diff'].detail})"
         )
+
+
+def test_sharded_runner_refuses_workers_on_a_tpu_backend(monkeypatch):
+    """One process per chip: on a TPU backend the sharded runner refuses
+    before it spawns anything, naming the rule."""
+    import multiprocessing
+
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spawned = []
+    monkeypatch.setattr(multiprocessing, "get_context",
+                        lambda *a, **k: spawned.append(a))
+    assert not campaign_mod.workers_allowed()
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        campaign_mod.run_campaign_sharded(
+            workers=1, targets=("vecunit",), faults=("identity",), apps=())
+    assert not spawned

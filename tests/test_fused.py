@@ -24,12 +24,22 @@ import pytest
 from repro.accel import flexasr as fa, hlscnn as hc
 from repro.core import ir, validate
 from repro.core.codegen import Executor
-from repro.core.ila import ILA, CompiledFragment
+from repro.core.ila import ILA, TARGETS, CompiledFragment
 
 #: fused-vs-compiled bound for reassociated (non-bit-exact) lowerings:
 #: both sides quantize to the same lattice, so only fp32 summation-order
 #: noise below the lattice step survives
 TIGHT = 1e-4
+
+
+RUNNER_PREFIX = {"fasr_linear": "flexasr-linear", "fasr_lstm": "flexasr-lstm",
+                 "hlscnn_conv2d": "hlscnn-conv2d"}
+
+
+def _last_runner(op):
+    """The most recently resolved fused runner of ``op``'s family."""
+    return [r for r in TARGETS.intrinsic(op)[0].fused_runners()
+            if r.name.startswith(RUNNER_PREFIX[op])][-1]
 
 
 def _run(op, env_args, attrs, engine, options, **kw):
@@ -86,9 +96,12 @@ def test_xla_fallback_replicates_compiled(op, make, options, exact, monkeypatch)
         np.testing.assert_array_equal(ref, got)
     else:
         assert validate.frob_rel_err(ref, got) <= TIGHT
-    # the fast path actually fired: the owning target resolved a runner
+    # the fast path actually fired: the owning target resolved a runner,
+    # and it declares the exactness this test holds it to
     tname = next(iter(options))
     assert ex.cache_info()[tname]["fused_runners"] >= 1
+    runner = _last_runner(op)
+    assert runner.lowering == "xla" and runner.exact == exact
 
 
 @pytest.mark.parametrize("op,make,options,exact", CASES)
@@ -102,6 +115,10 @@ def test_pallas_lowering_tracks_compiled(op, make, options, exact, monkeypatch):
     ref, _ = _run(op, args, attrs, "compiled", options)
     got, _ = _run(op, args, attrs, "fused", options)
     assert validate.frob_rel_err(ref, got) <= TIGHT
+    # on the CPU backend a Pallas leg is built in interpret mode
+    runner = _last_runner(op)
+    assert runner.interpret == (runner.lowering == "pallas")
+    assert not runner.exact
 
 
 def test_fused_batch_matches_per_sample_numerics():

@@ -184,3 +184,69 @@ def test_close_without_drain_cancels_queued():
     # never started: nothing is in flight, every request is still queued
     srv.close(drain=False)
     assert all(h.status == "cancelled" for h in handles)
+
+
+def test_serve_cli_exits_nonzero_when_a_request_fails(monkeypatch, capsys):
+    """`launch/serve.py --cosim`: a planner that raises once measured
+    requests start (warmup already passed) marks those requests failed;
+    the CLI must then exit non-zero and name the first error rather than
+    report the run as served."""
+    import dataclasses
+    import sys
+
+    from repro.core import serving
+    from repro.launch import jax_cache, serve
+
+    # keep this test process's compile cache where it was
+    monkeypatch.setattr(jax_cache, "enable_compile_cache", lambda: "")
+
+    def boom(ctx, x, args):
+        raise ValueError("planner exploded")
+
+    target, intr = ila.TARGETS.intrinsic("fasr_linear")
+    start = serving.CosimServer.start
+
+    def start_then_break(self, *a, **kw):
+        out = start(self, *a, **kw)
+        monkeypatch.setitem(ila.TARGETS._by_op, "fasr_linear",
+                            (target, dataclasses.replace(intr, planner=boom)))
+        return out
+
+    monkeypatch.setattr(serving.CosimServer, "start", start_then_break)
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--cosim", "LSTM-WLM", "--requests", "2", "--batch", "1",
+        "--engine", "pipelined"])
+    with pytest.raises(SystemExit) as ei:
+        serve.main()
+    assert ei.value.code not in (0, None)
+    assert "planner exploded" in str(ei.value.code)
+    assert "ValueError: planner exploded" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """The entry points' compile cache: where ``JAX_COMPILATION_CACHE_DIR``
+    says, with nothing set in code; else ``.jax_cache/`` at the checkout
+    root, a fixed path (the cache key includes it)."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.launch import jax_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = jax_cache.enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            root = Path(__file__).resolve().parents[1]
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
