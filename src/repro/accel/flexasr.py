@@ -54,8 +54,9 @@ from ..core.egraph import P, V as PV, Rewrite, shape_of
 from ..core.ila import (
     ILA, BulkWrite, Command, CompiledFragment, DataStream, FusedRunner,
     PackedStream, _replicated, _shard_batched, fingerprint, fused_lowering,
-    fused_pad_streams, shard_streams, stream_mesh,
+    fused_pad_streams, named, shard_streams, stream_mesh,
 )
+from ..core.telemetry import TELEMETRY
 from . import numerics
 from .numerics import AdaptivFloatSpec
 from .target import (
@@ -868,7 +869,8 @@ def plan_linear(ctx, x, args):
     orig_shape = a.shape
     a2 = a.reshape(-1, a.shape[-1])
     O = w.shape[0]
-    ideal_full = a2 @ w.T + b
+    with TELEMETRY.span("executor.stats", op="fasr_linear"):
+        ideal_full = a2 @ w.T + b  # read only by ctx.record
     frag = linear_fragment(w, b)
     jobs = [
         SimJob(frag, pack_linear_data(frag, chunk), read_full,
@@ -888,7 +890,8 @@ def plan_lstm(ctx, x, args):
     xs, wi, wh, b = args
     T, B, I = xs.shape
     H = wh.shape[1]
-    ideal = _ideal_lstm(xs, wi, wh, b)
+    with TELEMETRY.span("executor.stats", op="fasr_lstm"):
+        ideal = _ideal_lstm(xs, wi, wh, b)  # read only by ctx.record
     frag = lstm_fragment(wi, wh, b)
     jobs = [
         SimJob(frag, pack_lstm_data(frag, xs[:, bi]), read_full,
@@ -1266,11 +1269,11 @@ def _fused_stack(datas: List[DataStream]):
     return xs, num_ts, ba, bo
 
 
-def _fused_dispatch(per_sample):
+def _fused_dispatch(per_sample, name: str):
     """Dispatch half: vmap the per-sample kernel over the batch axis, with
     the batch sharded across the stream mesh (same axis run_data_batch
-    shards)."""
-    vf = jax.jit(jax.vmap(per_sample))
+    shards). ``name`` names the jitted runner."""
+    vf = jax.jit(named(jax.vmap(per_sample), name))
 
     def dispatch(prepared):
         xs, num_ts, ba, bo = (_shard_batched(a) for a in prepared)
@@ -1299,10 +1302,10 @@ def fused_linear_pallas(interpret: bool, mesh=None):
     exponent bias and output mask are shared. Sharded per device over the
     stream ``mesh`` when one is given. One jit for every fragment, so
     fragments of any weights share its compilations."""
-    return jax.jit(shard_streams(jax.vmap(
+    return jax.jit(named(shard_streams(jax.vmap(
         functools.partial(_linear_pallas, interpret=interpret),
         in_axes=(0, 0, 0, 0, None, None, None, None),
-    ), 4, 4, mesh))
+    ), 4, 4, mesh), "flexasr_fused_linear_pallas"))
 
 
 def _fused_linear(frag: CompiledFragment) -> FusedRunner:
@@ -1352,8 +1355,8 @@ def _fused_linear(frag: CompiledFragment) -> FusedRunner:
         return Y[:, :MAX_IN]
 
     return FusedRunner("flexasr-linear-xla", _fused_stack,
-                       _fused_dispatch(one), read=read_full, lowering="xla",
-                       exact=True)
+                       _fused_dispatch(one, "flexasr_fused_linear"),
+                       read=read_full, lowering="xla", exact=True)
 
 
 def _fused_lstm(frag: CompiledFragment) -> FusedRunner:
@@ -1389,7 +1392,8 @@ def _fused_lstm(frag: CompiledFragment) -> FusedRunner:
         hs = hs * _mask1(n_ts, MAX_TS)[:, None]
         return jnp.zeros((MAX_TS, MAX_IN), jnp.float32).at[:, :MAX_H].set(hs)
 
-    return FusedRunner("flexasr-lstm-xla", _fused_stack, _fused_dispatch(one),
+    return FusedRunner("flexasr-lstm-xla", _fused_stack,
+                       _fused_dispatch(one, "flexasr_fused_lstm"),
                        read=read_full, lowering="xla")
 
 

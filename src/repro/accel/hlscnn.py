@@ -35,7 +35,7 @@ from ..core.egraph import P, V as PV, Rewrite, shape_of
 from ..core.ila import (
     ILA, BulkWrite, Command, CompiledFragment, DataStream, FusedRunner,
     PackedStream, _replicated, _shard_batched, fingerprint, fused_lowering,
-    fused_pad_streams, shard_streams, stream_mesh,
+    fused_pad_streams, named, shard_streams, stream_mesh,
 )
 from . import numerics
 from .target import (
@@ -439,10 +439,10 @@ def fused_conv_pallas(wspec, interpret: bool, mesh=None):
     (128, KPAD) weights and the geometry masks are shared. Sharded per
     device over the stream ``mesh`` when one is given. One jit per weight
     datatype, so every conv layer shares its compilations."""
-    return jax.jit(shard_streams(jax.vmap(
+    return jax.jit(named(shard_streams(jax.vmap(
         functools.partial(_conv_pallas, wspec=wspec, interpret=interpret),
         in_axes=(0, None, None, None, None),
-    ), 1, 4, mesh))
+    ), 1, 4, mesh), "hlscnn_fused_conv2d_pallas"))
 
 
 def _fused_conv2d(frag: CompiledFragment) -> FusedRunner:
@@ -492,7 +492,7 @@ def _fused_conv2d(frag: CompiledFragment) -> FusedRunner:
         )
         return numerics.fx_quantize(y, ACT_SPEC)
 
-    vf = jax.jit(jax.vmap(one))
+    vf = jax.jit(named(jax.vmap(one), "hlscnn_fused_conv2d"))
 
     def dispatch(prepared):
         (xs,) = prepared

@@ -45,6 +45,7 @@ from ..core.ila import (
     FusedRunner,
     fused_lowering,
 )
+from ..core.telemetry import TELEMETRY
 
 
 @dataclasses.dataclass
@@ -524,6 +525,8 @@ class AcceleratorTarget:
             runner = fn(frag)
             if runner is not None:
                 break
+        if runner is not None and TELEMETRY.enabled:
+            _span_first_dispatch(runner, self.name)
         self._fused_cache[key] = runner
         return runner
 
@@ -580,6 +583,20 @@ class AcceleratorTarget:
             "fused_runners": len(self.fused_runners()),
             **self.ila.jit_cache_info(),
         }
+
+
+def _span_first_dispatch(runner: FusedRunner, ila: str) -> None:
+    """Put a new runner's first dispatch (its trace, lower and compile)
+    inside an ``executor.compile`` span. The first call puts the runner's
+    own dispatch back, so later calls go to it directly."""
+    dispatch = runner.dispatch
+
+    def first(prepared):
+        runner.dispatch = dispatch
+        with TELEMETRY.span("executor.compile", kind="fused", ila=ila):
+            return dispatch(prepared)
+
+    runner.dispatch = first
 
 
 def register_target(target: AcceleratorTarget) -> AcceleratorTarget:

@@ -94,7 +94,7 @@ from ..accel.target import (  # importing registers bundled targets
     CostEstimate, GroupTiming, PlanContext, SimJob,
 )
 from . import ir
-from .ila import TARGETS, CompiledFragment, FragmentCache
+from .ila import TARGETS, CompiledFragment, FragmentCache, named
 from .telemetry import TELEMETRY, MetricsRegistry
 
 ENGINES = ("compiled", "pipelined", "fused", "jit", "eager")
@@ -168,6 +168,32 @@ class _Deferred:
 
 def _forced(v):
     return v.force() if isinstance(v, _Deferred) else v
+
+
+def _node_op(x: ir.Expr) -> str:
+    """The ``op`` a host-evaluation span names: the IR op of a call, else
+    ``var`` / ``const``."""
+    return x.op if isinstance(x, ir.Call) else type(x).__name__.lower()
+
+
+def _marshal(x: ir.Call, args_b: List[List[Any]], B: int) -> List[List[np.ndarray]]:
+    """An accelerator call's operands as host arrays, per sample (this
+    waits for the host glue that computes them)."""
+    with TELEMETRY.span("executor.host_eval", op=f"{x.op}.operands"):
+        return [[np.asarray(a[s]) for a in args_b] for s in range(B)]
+
+
+def _host_eval(x: ir.Expr, rec: Callable[[ir.Expr], List[Any]],
+               envs: Sequence[Dict[str, Any]]) -> List[Any]:
+    """One host (not offloaded) IR node for every sample: its operands
+    first, which may dispatch accelerator work, then the node itself in one
+    ``executor.host_eval`` span."""
+    if isinstance(x, ir.Call):
+        for a in x.args:
+            rec(a)
+    with TELEMETRY.span("executor.host_eval", op=_node_op(x)):
+        return [ir._eval(x, (lambda a, s=s: rec(a)[s]), env)
+                for s, env in enumerate(envs)]
 
 
 class Submission:
@@ -473,11 +499,7 @@ class Executor:
             if x in memo:
                 return memo[x]
             if isinstance(x, ir.Call) and x.op in ir.ACCEL_OPS:
-                args_b = [rec(a) for a in x.args]
-                sample_args = [
-                    [np.asarray(args_b[k][s]) for k in range(len(args_b))]
-                    for s in range(B)
-                ]
+                sample_args = _marshal(x, [rec(a) for a in x.args], B)
                 if (
                     self.mode == "ila"
                     and self.engine in ("compiled", "pipelined", "fused")
@@ -487,34 +509,29 @@ class Executor:
                         v = self._node_pipelined(x, sample_args)
                     else:
                         plans, jobs = [], []
-                        t0 = time.perf_counter()
-                        for s in range(B):
-                            s_jobs, assemble = self._plan(x, sample_args[s])
-                            plans.append((len(jobs), len(s_jobs), assemble))
-                            jobs += s_jobs
-                        t1 = time.perf_counter()
-                        dt = t1 - t0
+                        tname = TARGETS.intrinsic(x.op)[0].name
+                        with TELEMETRY.span("pipeline.pack", target=tname,
+                                            op=x.op) as sp:
+                            t0 = time.perf_counter()
+                            for s in range(B):
+                                s_jobs, assemble = self._plan(x, sample_args[s])
+                                plans.append((len(jobs), len(s_jobs), assemble))
+                                jobs += s_jobs
+                            dt = time.perf_counter() - t0
+                            sp.set(jobs=len(jobs))
                         self._stage["pack_s"].inc(dt)
-                        if TELEMETRY.enabled:
-                            TELEMETRY.record_span(
-                                "pipeline.pack", t0, t1,
-                                target=TARGETS.intrinsic(x.op)[0].name,
-                                jobs=len(jobs))
                         if self.collect_stats:
                             self._groups_ctr.inc()
                             self.group_timings.append(GroupTiming(
-                                TARGETS.intrinsic(x.op)[0].name, len(jobs),
-                                PlanContext.data_ncmds(jobs), pack_s=dt,
+                                tname, len(jobs), PlanContext.data_ncmds(jobs),
+                                pack_s=dt,
                             ))
-                        outs = self._execute_jobs(jobs)
+                        outs = self._execute_jobs(jobs, x.op)
                         v = [asm(outs[o : o + n]) for (o, n, asm) in plans]
                 else:
                     v = [self._exec_accel(x, sample_args[s]) for s in range(B)]
             else:
-                v = [
-                    ir._eval(x, (lambda a, s=s: rec(a)[s]), envs[s])
-                    for s in range(B)
-                ]
+                v = _host_eval(x, rec, envs)
             memo[x] = v
             return v
 
@@ -586,12 +603,8 @@ class Executor:
                 return memo[x]
             if isinstance(x, ir.Call) and x.op in ir.ACCEL_OPS:
                 # operand subtrees feed an accelerator call, so they are
-                # never deferred: args_b holds plain per-sample lists
-                args_b = [rec(a) for a in x.args]
-                sample_args = [
-                    [np.asarray(args_b[k][s]) for k in range(len(args_b))]
-                    for s in range(B)
-                ]
+                # never deferred: rec gives plain per-sample lists
+                sample_args = _marshal(x, [rec(a) for a in x.args], B)
                 if TARGETS.has_planner(x.op):
                     v = self._node_pipelined(
                         x, sample_args, defer=x in deferred,
@@ -608,15 +621,10 @@ class Executor:
                 # lazily at result() time
                 for a in x.args:
                     rec(a)
-                v = _Deferred(lambda x=x: [
-                    ir._eval(x, (lambda a, s=s: _forced(memo[a])[s]), envs[s])
-                    for s in range(B)
-                ])
+                v = _Deferred(lambda x=x: _host_eval(
+                    x, lambda a: _forced(memo[a]), envs))
             else:
-                v = [
-                    ir._eval(x, (lambda a, s=s: rec(a)[s]), envs[s])
-                    for s in range(B)
-                ]
+                v = _host_eval(x, rec, envs)
             memo[x] = v
             return v
 
@@ -656,17 +664,18 @@ class Executor:
             ):
                 continue  # not leading: operands depend on accel results
             sample_args = []
-            for s in range(B):
-                ememo: Dict[ir.Expr, Any] = {}
+            with TELEMETRY.span("executor.host_eval", op=f"{x.op}.operands"):
+                for s in range(B):
+                    ememo: Dict[ir.Expr, Any] = {}
 
-                def ev(a, s=s, ememo=ememo):
-                    if a in ememo:
-                        return ememo[a]
-                    v = ir._eval(a, ev, envs[s])
-                    ememo[a] = v
-                    return v
+                    def ev(a, s=s, ememo=ememo):
+                        if a in ememo:
+                            return ememo[a]
+                        v = ir._eval(a, ev, envs[s])
+                        ememo[a] = v
+                        return v
 
-                sample_args.append([np.asarray(ev(a)) for a in x.args])
+                    sample_args.append([np.asarray(ev(a)) for a in x.args])
             spans = [
                 range(i, min(i + self.pipeline_chunk, B))
                 for i in range(0, B, self.pipeline_chunk)
@@ -679,21 +688,22 @@ class Executor:
     def _record(self, op, backend, out, ideal, ncmds, est=None):
         if not self.collect_stats:
             return
-        out = np.asarray(out, np.float64)
-        ideal = np.asarray(ideal, np.float64)
-        denom = np.linalg.norm(ideal)
-        err = float(np.linalg.norm(ideal - out) / denom) if denom > 0 else 0.0
-        self.stats.append(
-            InvocationStat(
-                op, backend, err, float(out.min()), float(out.max()), ncmds, est
+        with TELEMETRY.span("executor.stats", op=op):
+            out = np.asarray(out, np.float64)
+            ideal = np.asarray(ideal, np.float64)
+            denom = np.linalg.norm(ideal)
+            err = float(np.linalg.norm(ideal - out) / denom) if denom > 0 else 0.0
+            self.stats.append(
+                InvocationStat(
+                    op, backend, err, float(out.min()), float(out.max()), ncmds, est
+                )
             )
-        )
-        inv, cmds, cyc, rel = self._inv_for(ir.accel_op_target(op) or backend)
-        inv.inc()
-        cmds.inc(ncmds)
-        if est is not None:
-            cyc.inc(est.cycles)
-        rel.set_max(err)
+            inv, cmds, cyc, rel = self._inv_for(ir.accel_op_target(op) or backend)
+            inv.inc()
+            cmds.inc(ncmds)
+            if est is not None:
+                cyc.inc(est.cycles)
+            rel.set_max(err)
 
     def _estimate(self, target, x: ir.Call, args) -> Optional[CostEstimate]:
         """CostModel prediction for one invocation (None without a model)."""
@@ -719,7 +729,7 @@ class Executor:
         if self.mode == "kernel" and intr.kernel is not None:
             return intr.kernel(self._ctx(target, self._estimate(target, x, args)), x, args)
         jobs, assemble = self._plan(x, args)
-        return assemble(self._execute_jobs(jobs))
+        return assemble(self._execute_jobs(jobs, x.op))
 
     def _ideal(self, x: ir.Call, args):
         vs = [ir.Var(f"_{i}", np.shape(a)) for i, a in enumerate(args)]
@@ -780,6 +790,7 @@ class Executor:
         sync: bool = False,
         pack_ahead: bool = False,
         preps: Optional[Dict[Tuple, Any]] = None,
+        op: str = "",
     ) -> List[Callable[[], np.ndarray]]:
         """Group jobs by (fragment, data signature), schedule the groups
         over the owning targets' simulated devices (greedy LPT on CostModel
@@ -796,7 +807,7 @@ class Executor:
         it overlaps the previous group's simulation; ``preps`` passes in
         host packings already prepared elsewhere (``_node_pipelined`` packs
         them in the worker alongside planning), keyed like
-        :meth:`_group_jobs`.
+        :meth:`_group_jobs`. ``op`` names the intrinsic in the spans.
         """
         handles: List[Optional[Callable[[], np.ndarray]]] = [None] * len(jobs)
         groups = self._group_jobs(jobs)
@@ -832,115 +843,120 @@ class Executor:
                             )
         t_disp = time.perf_counter()
         for _rank, idxs, target in order:
-            frag = jobs[idxs[0]].frag
-            read = jobs[idxs[0]].read
-            t_grp = time.perf_counter()
-            grp_cycles = 0.0
-            dev_name = frag.ila.name
-            # fused resolution happens on the *shared* fragment, before any
-            # device-local clone: runners compute from fragment meta, so a
-            # fused group never pays a per-device setup re-simulation
-            runner = self._fused_for(frag, read, target)
-            n_cmds = sum(len(jobs[i].data) for i in idxs)
-            if target is not None:
-                device = self.devices.pick(target)
-                # book against the chosen device, including its cold-setup
-                # cost (the ranking pass above is placement-blind)
-                if runner is None and device.is_cold(frag):
-                    n_cmds += len(frag.setup)
-                grp_cycles = self._group_cycles(
-                    frag, idxs, jobs, target,
-                    _NullDevice if runner is not None else device,
-                )
-                device.account(len(idxs), grp_cycles)
-                dev_name = device.name
-                if runner is None:
-                    frag = device.resolve(frag)
-            stack_dt = 0.0
-            if len(idxs) == 1:
-                t0 = time.perf_counter()
-                j = jobs[idxs[0]]
-                if runner is not None:
-                    group = _GroupResult(runner.run([j.data]))
-                    handles[idxs[0]] = (
-                        lambda g=group, w=j.window: g.materialize()[0][w]
+            with TELEMETRY.span("pipeline.dispatch_group",
+                                jobs=len(idxs)) as sp:
+                frag = jobs[idxs[0]].frag
+                read = jobs[idxs[0]].read
+                grp_cycles = 0.0
+                dev_name = frag.ila.name
+                # fused resolution happens on the *shared* fragment, before any
+                # device-local clone: runners compute from fragment meta, so a
+                # fused group never pays a per-device setup re-simulation
+                runner = self._fused_for(frag, read, target)
+                n_cmds = sum(len(jobs[i].data) for i in idxs)
+                if target is not None:
+                    device = self.devices.pick(target)
+                    # book against the chosen device, including its cold-setup
+                    # cost (the ranking pass above is placement-blind)
+                    if runner is None and device.is_cold(frag):
+                        n_cmds += len(frag.setup)
+                    grp_cycles = self._group_cycles(
+                        frag, idxs, jobs, target,
+                        _NullDevice if runner is not None else device,
                     )
-                else:
-                    out = read(frag.run(j.data))
-                    group = _GroupResult(out)
-                    handles[idxs[0]] = (
-                        lambda g=group, w=j.window: g.materialize()[w]
-                    )
-            else:
-                datas = [jobs[i].data for i in idxs]
-
-                def _prep():
-                    if runner is not None:
-                        return ("fused", runner.prepare(datas))
-                    return frag.prepare_batch(datas)
-
-                prep = preps.get((id(jobs[idxs[0]].frag), jobs[idxs[0]].data.sig()))
-                if prep is not None:
-                    prepared = prep.result() if hasattr(prep, "result") else prep
-                elif sync:
-                    # host half timed apart so the GroupTiming pack/sim
-                    # split matches what the pipelined engine's pack stage
-                    # actually covers (planner packing + group stacking)
+                    device.account(len(idxs), grp_cycles)
+                    dev_name = device.name
+                    if runner is None:
+                        frag = device.resolve(frag)
+                stack_dt = 0.0
+                if len(idxs) == 1:
                     t0 = time.perf_counter()
-                    prepared = _prep()
-                    stack_dt = time.perf_counter() - t0
+                    j = jobs[idxs[0]]
+                    if runner is not None:
+                        group = _GroupResult(runner.run([j.data]))
+                        handles[idxs[0]] = (
+                            lambda g=group, w=j.window: g.materialize()[0][w]
+                        )
+                    else:
+                        out = read(frag.run(j.data))
+                        group = _GroupResult(out)
+                        handles[idxs[0]] = (
+                            lambda g=group, w=j.window: g.materialize()[w]
+                        )
                 else:
-                    prepared = _prep()
-                # a staged prep can disagree with the resolved path when the
-                # fused env flags flip between pack and dispatch — re-prep
-                if (prepared[0] == "fused") != (runner is not None):
-                    prepared = _prep()
-                t0 = time.perf_counter()
-                if runner is not None:
-                    fulls = runner.dispatch(prepared[1])
-                else:
-                    sts = frag.run_prepared(prepared)
-                    entry = self._batched_reads.get(id(read))
-                    if entry is None:
-                        entry = (read, jax.jit(jax.vmap(read)))
-                        self._batched_reads[id(read)] = entry
-                    fulls = entry[1](sts)
-                group = _GroupResult(fulls)
-                for bi, i in enumerate(idxs):
-                    handles[i] = (
-                        lambda g=group, b=bi, w=jobs[i].window: g.materialize()[b][w]
-                    )
-            if sync:
-                group.materialize()
-                sim_dt = time.perf_counter() - t0
-                if self.collect_stats:
-                    self._groups_ctr.inc()
-                    self.group_timings.append(GroupTiming(
-                        target.name if target is not None else frag.ila.name,
-                        len(idxs), n_cmds, pack_s=stack_dt,
-                        sim_s=sim_dt,
-                    ))
-                    # drift probe: the scheduler priced this group at
-                    # grp_cycles; the simulation actually took sim_dt. On a
-                    # latency-calibrated model (1 cycle == 1 us) the ratio
-                    # is directly actionable (CostModel.drift_summary)
-                    if target is not None and target.cost_model is not None \
-                            and grp_cycles > 0:
-                        target.cost_model.record_drift(
-                            grp_cycles, sim_dt * 1e6)
-            if TELEMETRY.enabled:
-                TELEMETRY.record_span(
-                    "pipeline.dispatch_group", t_grp, time.perf_counter(),
-                    device=dev_name, jobs=len(idxs),
-                    est_cycles=round(grp_cycles, 1))
+                    datas = [jobs[i].data for i in idxs]
+
+                    def _prep():
+                        if runner is not None:
+                            return ("fused", runner.prepare(datas))
+                        return frag.prepare_batch(datas)
+
+                    prep = preps.get((id(jobs[idxs[0]].frag), jobs[idxs[0]].data.sig()))
+                    if hasattr(prep, "result"):
+                        with TELEMETRY.span("pipeline.pack_wait", op=op):
+                            prepared = prep.result()
+                    elif prep is not None:
+                        prepared = prep
+                    elif sync:
+                        # host half timed apart so the GroupTiming pack/sim
+                        # split matches what the pipelined engine's pack stage
+                        # actually covers (planner packing + group stacking)
+                        t0 = time.perf_counter()
+                        prepared = _prep()
+                        stack_dt = time.perf_counter() - t0
+                    else:
+                        prepared = _prep()
+                    # a staged prep can disagree with the resolved path when the
+                    # fused env flags flip between pack and dispatch — re-prep
+                    if (prepared[0] == "fused") != (runner is not None):
+                        prepared = _prep()
+                    t0 = time.perf_counter()
+                    if runner is not None:
+                        fulls = runner.dispatch(prepared[1])
+                    else:
+                        sts = frag.run_prepared(prepared)
+                        entry = self._batched_reads.get(id(read))
+                        if entry is not None:
+                            fulls = entry[1](sts)
+                        else:
+                            ila = frag.ila.name
+                            entry = (read, jax.jit(named(jax.vmap(read), f"{ila}_read")))
+                            self._batched_reads[id(read)] = entry
+                            with TELEMETRY.span("executor.compile", kind="read", ila=ila):
+                                fulls = entry[1](sts)
+                    group = _GroupResult(fulls)
+                    for bi, i in enumerate(idxs):
+                        handles[i] = (
+                            lambda g=group, b=bi, w=jobs[i].window: g.materialize()[b][w]
+                        )
+                if sync:
+                    group.materialize()
+                    sim_dt = time.perf_counter() - t0
+                    if self.collect_stats:
+                        self._groups_ctr.inc()
+                        self.group_timings.append(GroupTiming(
+                            target.name if target is not None else frag.ila.name,
+                            len(idxs), n_cmds, pack_s=stack_dt,
+                            sim_s=sim_dt,
+                        ))
+                        # drift probe: the scheduler priced this group at
+                        # grp_cycles; the simulation actually took sim_dt. On a
+                        # latency-calibrated model (1 cycle == 1 us) the ratio
+                        # is directly actionable (CostModel.drift_summary)
+                        if target is not None and target.cost_model is not None \
+                                and grp_cycles > 0:
+                            target.cost_model.record_drift(
+                                grp_cycles, sim_dt * 1e6)
+                sp.set(device=dev_name, est_cycles=round(grp_cycles, 1))
         self._stage["dispatch_s"].inc(time.perf_counter() - t_disp)
         return handles
 
-    def _execute_jobs(self, jobs: List[SimJob]) -> List[np.ndarray]:
+    def _execute_jobs(self, jobs: List[SimJob], op: str = "") -> List[np.ndarray]:
         """Run simulation jobs to completion. The compiled engine executes
         group-by-group (synchronous); the pipelined engine dispatches every
         group asynchronously — host packing staged through the pack worker
-        — and materializes at the end, in job order."""
+        — and materializes at the end, in job order. ``op`` names the
+        intrinsic in the spans."""
         if self.engine in ("jit", "eager"):
             results = []
             for j in jobs:
@@ -950,15 +966,13 @@ class Executor:
                 results.append(np.asarray(j.read(st))[j.window])
             return results
         sync = self.engine == "compiled"
-        handles = self._dispatch_jobs(jobs, sync=sync, pack_ahead=not sync)
-        t0 = time.perf_counter()
-        results = [h() for h in handles]
-        if not sync:
-            t1 = time.perf_counter()
-            self._stage["readback_s"].inc(t1 - t0)
-            if TELEMETRY.enabled:
-                TELEMETRY.record_span("pipeline.readback", t0, t1,
-                                      jobs=len(jobs))
+        handles = self._dispatch_jobs(jobs, sync=sync, pack_ahead=not sync, op=op)
+        if sync:
+            return [h() for h in handles]
+        with TELEMETRY.span("pipeline.readback", jobs=len(jobs)):
+            t0 = time.perf_counter()
+            results = [h() for h in handles]
+            self._stage["readback_s"].inc(time.perf_counter() - t0)
         return results
 
     def _make_plan_span(self, x: ir.Call, sample_args: List[List[np.ndarray]]):
@@ -975,29 +989,30 @@ class Executor:
         trace_id = TELEMETRY.current_trace() if TELEMETRY.enabled else None
 
         def plan_span(span):
-            t0 = time.perf_counter()
-            planned = [self._plan(x, sample_args[s]) for s in span]
-            jobs = [j for js, _ in planned for j in js]
-            preps = {}
-            for key, idxs in self._group_jobs(jobs).items():
-                if len(idxs) <= 1:
-                    continue
-                frag0 = jobs[idxs[0]].frag
-                runner = self._fused_for(
-                    frag0, jobs[idxs[0]].read, self.devices.owner(frag0)
-                )
-                datas = [jobs[i].data for i in idxs]
-                preps[key] = (
-                    ("fused", runner.prepare(datas))
-                    if runner is not None
-                    else frag0.prepare_batch(datas)
-                )
-            t1 = time.perf_counter()
-            dt = t1 - t0
+            with TELEMETRY.span("pipeline.pack", trace_id, target=target.name,
+                                op=x.op) as sp:
+                t0 = time.perf_counter()
+                with TELEMETRY.span("pipeline.plan", op=x.op):
+                    planned = [self._plan(x, sample_args[s]) for s in span]
+                jobs = [j for js, _ in planned for j in js]
+                preps = {}
+                with TELEMETRY.span("pipeline.stack", op=x.op):
+                    for key, idxs in self._group_jobs(jobs).items():
+                        if len(idxs) <= 1:
+                            continue
+                        frag0 = jobs[idxs[0]].frag
+                        runner = self._fused_for(
+                            frag0, jobs[idxs[0]].read, self.devices.owner(frag0)
+                        )
+                        datas = [jobs[i].data for i in idxs]
+                        preps[key] = (
+                            ("fused", runner.prepare(datas))
+                            if runner is not None
+                            else frag0.prepare_batch(datas)
+                        )
+                dt = time.perf_counter() - t0
+                sp.set(jobs=len(jobs))
             self._stage["pack_s"].inc(dt)
-            if TELEMETRY.enabled:
-                TELEMETRY.record_span("pipeline.pack", t0, t1, trace_id,
-                                      target=target.name, jobs=len(jobs))
             if self.collect_stats:
                 self._groups_ctr.inc()
                 self.group_timings.append(GroupTiming(
@@ -1051,28 +1066,27 @@ class Executor:
         fut = stage(0)
         stages = []
         for ci in range(len(spans)):
-            planned, jobs, preps = fut.result()
+            with TELEMETRY.span("pipeline.pack_wait", op=x.op):
+                planned, jobs, preps = fut.result()
             if ci + 1 < len(spans):
                 fut = stage(ci + 1)
-            handles = self._dispatch_jobs(jobs, preps=preps)
+            handles = self._dispatch_jobs(jobs, preps=preps, op=x.op)
             stages.append((planned, handles))
 
         trace_id = TELEMETRY.current_trace() if TELEMETRY.enabled else None
 
         def readback():
-            t0 = time.perf_counter()
-            v = []
-            for planned, handles in stages:
-                outs = [h() for h in handles]
-                o = 0
-                for js, asm in planned:
-                    v.append(asm(outs[o : o + len(js)]))
-                    o += len(js)
-            t1 = time.perf_counter()
-            self._stage["readback_s"].inc(t1 - t0)
-            if TELEMETRY.enabled:
-                TELEMETRY.record_span("pipeline.readback", t0, t1, trace_id,
-                                      spans=len(stages))
+            with TELEMETRY.span("pipeline.readback", trace_id,
+                                spans=len(stages)):
+                t0 = time.perf_counter()
+                v = []
+                for planned, handles in stages:
+                    outs = [h() for h in handles]
+                    o = 0
+                    for js, asm in planned:
+                        v.append(asm(outs[o : o + len(js)]))
+                        o += len(js)
+                self._stage["readback_s"].inc(time.perf_counter() - t0)
             return v
 
         return _Deferred(readback) if defer else readback()

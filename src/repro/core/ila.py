@@ -65,6 +65,7 @@ numerics, so results stay bit-exact.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import os
 import threading
@@ -85,6 +86,20 @@ NOP_OPCODE = 0
 
 MIN_BUCKET = 16
 MAX_DATA_RUNNERS = 128
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the Python name ``name``. ``jax.jit`` names the
+    compiled module after it (``jit_<name>``), and a profile names every
+    device operation by its module, so each jitted simulator says which
+    ILA it runs and in which role (``flexasr_data_batch``, ``vta_read``)."""
+
+    @functools.wraps(fn)
+    def f(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    f.__name__ = f.__qualname__ = name
+    return f
 
 
 def bucket_length(n: int, min_len: int = MIN_BUCKET) -> int:
@@ -491,7 +506,7 @@ class ILA:
             final, _ = jax.lax.scan(step, state, (ops, addrs, data))
             return final
 
-        return jax.jit(run)
+        return jax.jit(named(run, f"{self.name}_stream_single"))
 
     def make_batch_simulator(self):
         """vmap the scanned simulator over stacked command streams, sharing
@@ -506,7 +521,7 @@ class ILA:
             self.n_traces_batch += 1
             return jax.vmap(run_one, in_axes=(None, 0, 0, 0))(state, ops, addrs, data)
 
-        return jax.jit(run)
+        return jax.jit(named(run, f"{self.name}_stream_batch"))
 
     def simulate_jit(self, commands: Sequence[Command], state: Optional[State] = None) -> State:
         """Jit-compiled simulation; the compiled scan is cached (jax.jit
@@ -577,12 +592,14 @@ class ILA:
         return self._dispatch_stream_batch(self._host_stream_batch(streams), st)
 
     # -- compiled data-stream execution ---------------------------------
-    def _data_runner(self, sig: Tuple, shared_mask: Tuple[bool, ...]):
+    def _data_runner(self, sig: Tuple, shared_mask: Tuple[bool, ...],
+                     batched: bool) -> Callable:
         """Build the jitted executor for one data-stream signature: each
         bulk write lowers to ONE dynamic_update_slice, and the short tail
         *unrolls* with static opcodes — the command skeleton compiles away
         entirely (no per-step lax.switch), which is the compiled-simulator
         analogue of ILAng's generated C++ vs interpreting the command list.
+        ``batched`` vmaps it over stacked streams sharing one state.
 
         ``shared_mask[i]`` marks tail payload rows that are identical across
         a batch: those stay unbatched under vmap, so values derived from
@@ -590,13 +607,6 @@ class ILA:
         FN_START's mode dispatch executes exactly one branch. A batched
         dispatch index would execute every branch at every position.
         """
-        if not hasattr(self, "_data_runners"):
-            self._data_runners: "OrderedDict[Tuple, Tuple]" = OrderedDict()
-        key = (sig, shared_mask)
-        run = self._data_runners.get(key)
-        if run is not None:
-            self._data_runners.move_to_end(key)
-            return run
         bulk_sig, tail_ops, tail_addrs = sig
         updates = [self._by_opcode[op].update for op in tail_ops]
         shared_pos = [i for i, s in enumerate(shared_mask) if s]
@@ -617,9 +627,12 @@ class ILA:
                 st = update(st, jnp.int32(addr), row)
             return st
 
-        def run_single(state, rows_list, shared_data, batched_data):
-            self.n_traces_single += 1
-            return apply(state, rows_list, shared_data, batched_data)
+        if not batched:
+            def run_single(state, rows_list, shared_data, batched_data):
+                self.n_traces_single += 1
+                return apply(state, rows_list, shared_data, batched_data)
+
+            return jax.jit(named(run_single, f"{self.name}_data_single"))
 
         def run_batch(state, rows_list, shared_data, batched_data):
             self.n_traces_batch += 1
@@ -627,14 +640,28 @@ class ILA:
                 state, rows_list, shared_data, batched_data
             )
 
-        run = (jax.jit(run_single), jax.jit(run_batch))
-        self._data_runners[key] = run
+        return jax.jit(named(run_batch, f"{self.name}_data_batch"))
+
+    def _run_data_runner(self, sig: Tuple, shared_mask: Tuple[bool, ...],
+                         batched: bool, *args) -> State:
+        """Call the compiled executor for ``(sig, shared_mask, batched)``,
+        building it on a miss. A miss's first call (trace, lower, compile,
+        then the async dispatch) runs inside an ``executor.compile`` span."""
+        if not hasattr(self, "_data_runners"):
+            self._data_runners: "OrderedDict[Tuple, Callable]" = OrderedDict()
+        key = (sig, shared_mask, batched)
+        run = self._data_runners.get(key)
+        if run is not None:
+            self._data_runners.move_to_end(key)
+            return run(*args)
+        run = self._data_runners[key] = self._data_runner(sig, shared_mask, batched)
         # bound the compiled-executor cache: heavily ragged workloads (a
         # distinct operand shape per sample) would otherwise grow it without
         # limit; evicted signatures simply re-trace on next use
         while len(self._data_runners) > MAX_DATA_RUNNERS:
             self._data_runners.popitem(last=False)
-        return run
+        with TELEMETRY.span("executor.compile", kind="data_runner", ila=self.name):
+            return run(*args)
 
     @staticmethod
     def _split_rows(tail_data: np.ndarray, shared_mask: Tuple[bool, ...]):
@@ -649,10 +676,9 @@ class ILA:
     def run_data(self, data: DataStream, state: Optional[State] = None) -> State:
         st = state if state is not None else self.init_state()
         mask = (True,) * len(data.tail)  # single stream: everything "shared"
-        single, _ = self._data_runner(data.sig(), mask)
         shared, batched = self._split_rows(data.tail.data, mask)
-        return single(
-            st,
+        return self._run_data_runner(
+            data.sig(), mask, False, st,
             [jnp.asarray(b.rows) for b in data.bulk],
             jnp.asarray(shared), jnp.asarray(batched),
         )
@@ -687,9 +713,8 @@ class ILA:
         Batch-leading payloads shard over the stream mesh when one is
         active; setup state and batch-shared rows replicate."""
         sig, shared_mask, rows_list, shared, batched = host
-        _, batch = self._data_runner(sig, shared_mask)
-        return batch(
-            _replicated(state),
+        return self._run_data_runner(
+            sig, shared_mask, True, _replicated(state),
             [_shard_batched(r) for r in rows_list],
             _replicated(jnp.asarray(shared)), _shard_batched(batched),
         )
@@ -827,13 +852,9 @@ class FragmentCache:
             frag = self._entries.get(key)
             if frag is not None:
                 self.hits += 1
-                if TELEMETRY.enabled:
-                    TELEMETRY.counter("fragments.hits").inc()
                 self._entries.move_to_end(key)
                 return frag
             self.misses += 1
-            if TELEMETRY.enabled:
-                TELEMETRY.counter("fragments.misses").inc()
             frag = build()
             frag.key = key
             self._entries[key] = frag

@@ -397,28 +397,26 @@ class CosimServer:
                 self._cond.wait(timeout=0.05)
             if not self._queue:
                 return None
-            t0 = time.perf_counter()
-            first = self._queue.popleft()
-            group = [first]
-            if self.coalesce:
-                n = len(first.envs)
-                taken = []
-                for h in self._queue:
-                    if h.app == first.app and n + len(h.envs) <= self.max_batch:
-                        taken.append(h)
-                        n += len(h.envs)
-                for h in taken:
-                    self._queue.remove(h)
-                group += taken
-            self._inflight_cycles += sum(h.est_cycles for h in group)
-            self._m_queue.set(len(self._queue))
-            self._m_backlog.set(self._backlog_cycles())
-        if TELEMETRY.enabled:
-            TELEMETRY.record_span(
-                "serving.coalesce", t0, time.perf_counter(),
-                trace_id=_group_trace(group), app=first.app,
-                requests=len(group),
-                samples=sum(len(h.envs) for h in group))
+            with TELEMETRY.span("serving.coalesce") as sp:
+                first = self._queue.popleft()
+                group = [first]
+                if self.coalesce:
+                    n = len(first.envs)
+                    taken = []
+                    for h in self._queue:
+                        if h.app == first.app and n + len(h.envs) <= self.max_batch:
+                            taken.append(h)
+                            n += len(h.envs)
+                    for h in taken:
+                        self._queue.remove(h)
+                    group += taken
+                self._inflight_cycles += sum(h.est_cycles for h in group)
+                self._m_queue.set(len(self._queue))
+                self._m_backlog.set(self._backlog_cycles())
+                if TELEMETRY.enabled:
+                    sp.trace_id = _group_trace(group)
+                    sp.set(app=first.app, requests=len(group),
+                           samples=sum(len(h.envs) for h in group))
         return group
 
     def _loop(self) -> None:
@@ -507,20 +505,16 @@ class CosimServer:
 
     def _complete(self, group: List[RequestHandle], outs: List[Any]) -> None:
         enabled = TELEMETRY.enabled
-        t0 = time.perf_counter()
-        o = 0
-        for h in group:
-            n = len(h.envs)
-            h.outputs = [np.asarray(v) for v in outs[o:o + n]]
-            o += n
-            h.t_done = time.perf_counter()
-            self._retire(h)
-            h._finish(DONE)
-        if enabled:
-            grp = _group_trace(group)
-            TELEMETRY.record_span(
-                "serving.deinterleave", t0, time.perf_counter(),
-                trace_id=grp, requests=len(group))
+        grp = _group_trace(group) if enabled else None
+        with TELEMETRY.span("serving.deinterleave", grp, requests=len(group)):
+            o = 0
+            for h in group:
+                n = len(h.envs)
+                h.outputs = [np.asarray(v) for v in outs[o:o + n]]
+                o += n
+                h.t_done = time.perf_counter()
+                self._retire(h)
+                h._finish(DONE)
         with self._cond:
             self._m_served.inc(len(group))
             self._m_batches.inc()

@@ -7,10 +7,15 @@ answer "where did this request's p95 actually go?" *across* layers. This
 module is the one subsystem they all report into:
 
 * **Spans** — nestable, ``trace_id``-correlated timed regions with a
-  context-manager/decorator API (:meth:`Telemetry.span`, :func:`traced`)
-  plus an explicit-timestamps form (:meth:`Telemetry.record_span`) for
-  regions measured across threads (a request's queue wait starts on the
-  submitting thread and ends on the dispatch thread). Spans land in a
+  context-manager API (:meth:`Telemetry.span`) plus an
+  explicit-timestamps form (:meth:`Telemetry.record_span`) for regions
+  measured across threads (a request's queue wait starts on the
+  submitting thread and ends on the dispatch thread). While tracing is
+  enabled, every context-manager span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name on its own thread, so
+  a profiler session records it natively on the trace's clock, beside the
+  device's operations (``record_span`` regions cannot: a profiler event is
+  not written after the fact). Spans land in a
   bounded ring buffer — saturation *drops the oldest and counts the drop*
   (:attr:`Telemetry.spans_dropped`); there is no silent truncation — and
   export as Chrome ``trace_event`` JSON (:meth:`Telemetry.export_trace`)
@@ -22,8 +27,7 @@ module is the one subsystem they all report into:
 * **Metrics registry** (:class:`MetricsRegistry`) — named counters,
   gauges, and **streaming-percentile histograms** (p50/p95/p99 via the
   P-square algorithm: five markers per quantile, O(1) per observation, no
-  stored samples), snapshot-able to JSON (:meth:`Telemetry.export_metrics`)
-  and dumpable as Prometheus-style text (:meth:`Telemetry.prometheus_text`).
+  stored samples), snapshot-able to JSON (:meth:`Telemetry.export_metrics`).
   Components own *scoped* registries (one per Executor / CosimServer)
   attached to the process-wide :data:`TELEMETRY` singleton by weakref, so
   a global snapshot sees every live component without components sharing
@@ -33,8 +37,9 @@ module is the one subsystem they all report into:
   attribute check: ``TELEMETRY.enabled``. Hot paths guard on it before
   building any span arguments, and :meth:`Telemetry.span` returns a
   shared no-op context manager when disabled — the disabled mode
-  allocates nothing (pinned by the zero-allocation smoke test and the
-  ``serving_telemetry_overhead`` bench row). Metrics counters are *not*
+  allocates nothing and never touches JAX (pinned by the zero-allocation
+  smoke test). JAX's profiler is imported on :meth:`Telemetry.enable`, so
+  this module stays importable without JAX. Metrics counters are *not*
   gated: they replace pre-existing always-on accounting (stage timers,
   reject counts) at the same cost.
 
@@ -54,14 +59,13 @@ is its first segment, so Perfetto can filter one layer's lane.
 """
 from __future__ import annotations
 
-import functools
 import json
 import os
 import re
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 #: the documented metric/span naming convention (docs/observability.md)
 NAME_LAYERS = ("serving", "pipeline", "executor", "fragments", "campaign",
@@ -76,6 +80,22 @@ _EPOCH = time.perf_counter()
 
 def _now_us() -> float:
     return (time.perf_counter() - _EPOCH) * 1e6
+
+
+#: ``jax.profiler.TraceAnnotation`` once imported (on the first enable or
+#: enabled span); False where JAX is not installed
+_ANNOTATION: Any = None
+
+
+def _annotation_cls():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 def check_metric_names(names: Iterable[str]) -> List[str]:
@@ -385,9 +405,12 @@ _NOOP_SPAN = _NoopSpan()
 class _Span:
     """An enabled span: records wall-clock at enter/exit, inherits the
     thread's current trace id and span stack (nesting), and lands in the
-    owning :class:`Telemetry` ring buffer on exit."""
+    owning :class:`Telemetry` ring buffer on exit. Between enter and exit
+    a ``jax.profiler.TraceAnnotation`` named like the span (its enter-time
+    args as the event's metadata) is open on the same thread; without a
+    profiler session it records nothing."""
 
-    __slots__ = ("_tel", "name", "trace_id", "args", "_t0")
+    __slots__ = ("_tel", "name", "trace_id", "args", "_t0", "_ann")
 
     def __init__(self, tel: "Telemetry", name: str,
                  trace_id: Optional[Any], args: Dict[str, Any]):
@@ -396,6 +419,7 @@ class _Span:
         self.trace_id = trace_id
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **args: Any) -> None:
         """Attach args discovered after the span opened (e.g. outcome)."""
@@ -409,6 +433,11 @@ class _Span:
         stack = getattr(tls, "stack", None)
         if stack is None:
             stack = tls.stack = []
+        ann = _annotation_cls()
+        if ann:
+            # a TraceMe starts when it is built: build it here, not in span()
+            self._ann = ann(self.name, **self.args)
+            self._ann.__enter__()
         if stack:
             self.args.setdefault("parent", stack[-1].name)
         stack.append(self)
@@ -417,6 +446,8 @@ class _Span:
 
     def __exit__(self, *exc) -> bool:
         t1 = _now_us()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tls = self._tel._tls
         stack = getattr(tls, "stack", None)
         if stack and stack[-1] is self:
@@ -474,6 +505,7 @@ class Telemetry:
     def enable(self, capacity: Optional[int] = None) -> None:
         if capacity is not None:
             self._capacity = int(capacity)
+        _annotation_cls()
         self.enabled = True
 
     def disable(self) -> None:
@@ -522,9 +554,10 @@ class Telemetry:
     # -- spans -----------------------------------------------------------
     def span(self, name: str, trace_id: Optional[Any] = None,
              **args: Any):
-        """Open a timed region (use as a context manager). Disabled mode
-        returns the shared no-op span — zero allocation when called with
-        only the name."""
+        """Open a timed region (use as a context manager) on the calling
+        thread, mirrored into the profiler's trace while one runs. Disabled
+        mode returns the shared no-op span — zero allocation when called
+        with only the name."""
         if not self.enabled:
             return _NOOP_SPAN
         return _Span(self, name, trace_id, args)
@@ -543,7 +576,9 @@ class Telemetry:
         for regions measured across threads (queue wait) or discovered
         after the fact. ``track`` names a synthetic timeline (e.g. one
         lane per in-flight request) instead of the calling thread. A
-        ``trace_id`` of None inherits the thread's bound trace."""
+        ``trace_id`` of None inherits the thread's bound trace. Such a span
+        never reaches the profiler's trace: use :meth:`span` for work done
+        on one thread."""
         if not self.enabled:
             return
         if trace_id is None:
@@ -698,35 +733,6 @@ class Telemetry:
             f.write("\n")
         return path
 
-    def prometheus_text(self) -> str:
-        """Prometheus exposition-style dump (``.`` -> ``_`` in names, the
-        scope as a label; histograms expose count/sum/quantile series)."""
-        lines: List[str] = []
-        for e in self.metrics_snapshot():
-            base = e["name"].replace(".", "_")
-            labels = dict(e["labels"])
-            if e.get("scope"):
-                labels["scope"] = e["scope"]
-
-            def fmt(extra: Dict[str, str] = {}) -> str:
-                lab = {**labels, **extra}
-                if not lab:
-                    return ""
-                inner = ",".join(
-                    f'{k}="{v}"' for k, v in sorted(lab.items()))
-                return "{" + inner + "}"
-
-            if e["type"] == "histogram":
-                lines.append(f"{base}_count{fmt()} {e.get('count', 0)}")
-                lines.append(f"{base}_sum{fmt()} {e.get('sum', 0.0)}")
-                for q in ("p50", "p95", "p99"):
-                    if q in e:
-                        lines.append(
-                            f"{base}{fmt({'quantile': '0.' + q[1:]})} {e[q]}")
-            else:
-                lines.append(f"{base}{fmt()} {e['value']}")
-        return "\n".join(lines) + "\n"
-
     def check_names(self) -> List[str]:
         """Metric names violating the documented convention, across every
         live registry (the CI schema check)."""
@@ -739,27 +745,3 @@ class Telemetry:
 #: the process-wide singleton every layer reports into
 TELEMETRY = Telemetry()
 
-
-def traced(name: str, **args: Any) -> Callable:
-    """Decorator form of :meth:`Telemetry.span`: times every call of the
-    wrapped function (no-op while telemetry is disabled)."""
-
-    def deco(fn: Callable) -> Callable:
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not TELEMETRY.enabled:
-                return fn(*a, **kw)
-            with TELEMETRY.span(name, **args):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
-
-
-# convenience module-level aliases (hot paths use TELEMETRY directly)
-span = TELEMETRY.span
-trace = TELEMETRY.trace
-record_span = TELEMETRY.record_span
-enable = TELEMETRY.enable
-disable = TELEMETRY.disable

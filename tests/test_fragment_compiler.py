@@ -4,7 +4,8 @@ Asserts bit-exact parity across every simulation tier — eager per-command
 ``simulate``, ``simulate_jit``, bucketed/NOP-padded ``simulate_packed``,
 the compiled setup-state + data-stream fast path, and vmapped batching —
 on FlexASR, HLSCNN and VTA fragments, plus regression tests that the
-compiled-function caches stay bounded as stream lengths vary.
+compiled-function caches stay bounded as stream lengths vary, and that
+every jitted runner's module names its ILA and role.
 """
 import jax
 import numpy as np
@@ -211,3 +212,51 @@ def _vars(e, seen=None):
             if id(a) not in seen:
                 seen.add(id(a))
                 yield from _vars(a, seen)
+
+
+def test_jitted_runners_name_their_ila(caplog):
+    """The compiled modules carry the ILA's name and the runner's role, so
+    a profile's device operations say which simulator ran: the data
+    runners and stream simulators (lowered here), and the batched reads
+    and fused runners an Executor creates (from the compile log)."""
+    import jax.numpy as jnp
+
+    from repro.core import ir
+    from repro.core.codegen import Executor
+
+    frag, datas, _read, _w = _linear_case()
+    ila = frag.ila
+    sig, mask, rows, shared, batched = ila._host_data_batch(datas)
+    st = frag.setup_state()
+    low = ila._data_runner(sig, mask, True).lower(st, rows, shared, batched)
+    assert "@jit_flexasr_data_batch" in low.as_text()
+    one = (True,) * len(datas[0].tail)
+    sh, ba = ila._split_rows(datas[0].tail.data, one)
+    low = ila._data_runner(datas[0].sig(), one, False).lower(
+        st, [jnp.asarray(b.rows) for b in datas[0].bulk], sh, ba)
+    assert "@jit_flexasr_data_single" in low.as_text()
+    stream = PackedStream.from_commands([Command(NOP_OPCODE)] * 16, fa.V)
+    args = (st, stream.ops, stream.addrs, stream.data)
+    assert "@jit_flexasr_stream_single" in ila.make_jit_simulator().lower(
+        *args).as_text()
+    assert "@jit_flexasr_stream_batch" in ila.make_batch_simulator().lower(
+        st, *(a[None] for a in args[1:])).as_text()
+
+    # weights of their own: new fragments, so new reads and fused runners
+    r = np.random.default_rng(23)
+    w = (r.standard_normal((12, 20)) * 0.1).astype(np.float32)
+    b = (r.standard_normal((12,)) * 0.1).astype(np.float32)
+    prog = ir.call("vta_relu", ir.call(
+        "fasr_linear", ir.Var("x", (3, 20)), ir.Var("w", w.shape),
+        ir.Var("b", b.shape)))
+    envs = [{"x": r.standard_normal((3, 20)).astype(np.float32), "w": w, "b": b}
+            for _ in range(3)]
+    caplog.set_level("WARNING", logger="jax._src.interpreters.pxla")
+    with jax.log_compiles():
+        for engine in ("pipelined", "fused"):
+            Executor("ila", engine=engine).run_many(prog, envs)
+    compiled = {m.split("jit(", 1)[1].split(")", 1)[0]
+                for m in caplog.messages if m.startswith("Compiling jit(")}
+    assert {"flexasr_read", "vta_read", "flexasr_fused_linear"} <= compiled
+    assert not compiled & {"run", "run_single", "run_batch", "read",
+                           "read_full", "read_out"}

@@ -7,7 +7,9 @@ Pins the three serving contracts the benchmark assumes:
 * admission control rejects — immediately, with a reason — rather than
   queueing unboundedly under a saturating burst;
 * shutdown drains: every accepted request is served before close()
-  returns, and post-shutdown submissions are rejected.
+  returns, and post-shutdown submissions are rejected;
+* traced, the server spans its host work, with one compile span per
+  jitted runner or batched read it creates.
 """
 import numpy as np
 import pytest
@@ -250,3 +252,61 @@ def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
             assert jax.config.jax_compilation_cache_dir == got
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+# ---------------------------------------------------------------------------
+# tracing
+# ---------------------------------------------------------------------------
+
+
+def _runner_keys(srv):
+    """Every jitted data runner (per ILA) and batched read (per executor)
+    created so far."""
+    keys = {(t.ila.name, k) for t in ila.TARGETS.all()
+            for k in getattr(t.ila, "_data_runners", {})}
+    return keys | {("read", k) for k in srv.executor._batched_reads}
+
+
+def test_traced_server_spans_its_host_work():
+    """Tracing on, a pipelined server records where its host time goes:
+    waits on the pack worker, planning and stacking inside pack, host
+    evaluation of the nodes not offloaded, accuracy statistics, and one
+    ``executor.compile`` span per jitted runner or batched read it creates
+    (a shape no other test here uses, so its runners are new). An
+    identical request on the warm server compiles no data runner."""
+    from repro.core.telemetry import TELEMETRY
+
+    srv = CosimServer(engine="pipelined", pipeline_chunk=2, seed=0)
+    expr, params = _tiny_program(I=24, O=12, seed=5)
+    srv.add_program("odd", expr, params)
+    rng = np.random.default_rng(1)
+    envs = [dict(params, x=rng.standard_normal((4, 24)).astype(np.float32))
+            for _ in range(4)]
+    srv.start(warmup=0)
+    TELEMETRY.enable()
+    TELEMETRY.reset()
+    try:
+        before = _runner_keys(srv)
+        srv.submit("odd", envs=envs).result(timeout=300)
+        created = _runner_keys(srv) - before
+        first = TELEMETRY.spans()
+        TELEMETRY.reset()
+        srv.submit("odd", envs=envs).result(timeout=300)
+        again = TELEMETRY.spans()
+    finally:
+        TELEMETRY.disable()
+        TELEMETRY.reset()
+        srv.close()
+    assert {s["name"] for s in first} >= {
+        "pipeline.pack_wait", "pipeline.plan", "pipeline.stack",
+        "executor.host_eval", "executor.stats", "executor.compile"}
+    compiles = [s["args"] for s in first if s["name"] == "executor.compile"]
+    assert created and len(compiles) == len(created)
+    assert {c["kind"] for c in compiles} <= {"data_runner", "read"}
+    assert all(c["ila"] == "flexasr" for c in compiles)
+    packs = [s["args"] for s in first if s["name"] == "pipeline.pack"]
+    assert packs and all(a["op"] == "fasr_linear" for a in packs)
+    evals = {s["args"]["op"] for s in first if s["name"] == "executor.host_eval"}
+    assert evals >= {"var", "relu", "fasr_linear.operands"}
+    assert not [s for s in again if s["name"] == "executor.compile"
+                and s["args"]["kind"] == "data_runner"]
