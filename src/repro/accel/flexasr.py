@@ -437,7 +437,7 @@ def _write_bias_cmds(b: np.ndarray) -> List[Command]:
 
 
 def _exp_biases(*tensors):
-    return [float(numerics.af_exp_bias(jnp.asarray(t), AF)) for t in tensors]
+    return [numerics.af_exp_bias_host(t, AF) for t in tensors]
 
 
 def _read_matrix(st, base: int, T: int, D: int) -> jnp.ndarray:

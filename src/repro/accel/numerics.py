@@ -24,6 +24,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 # --------------------------------------------------------------------------
@@ -66,6 +67,21 @@ def af_exp_bias(x: jnp.ndarray, spec: AdaptivFloatSpec) -> jnp.ndarray:
     amax = jnp.max(jnp.abs(x))
     amax = jnp.where(amax == 0, 1.0, amax)
     return floor_log2(amax) - (2 ** spec.n_exp - 1)
+
+
+def af_exp_bias_host(x, spec: AdaptivFloatSpec) -> float:
+    """``float(af_exp_bias(jnp.asarray(x), spec))``, computed in numpy on
+    the host. FlexASR's planners pack on the pipelined engine's pack worker
+    and must not dispatch JAX: eager device round trips there stall the
+    dispatch thread. Equal to ``af_exp_bias`` for every tensor: ``x`` is
+    rounded to float32 as ``jnp.asarray`` rounds it, and a max magnitude
+    below the smallest normal counts as zero, as XLA flushes subnormals on
+    the CPU and on the TPU (a bare exponent read would floor it at -127)."""
+    amax = np.max(np.abs(np.asarray(x, np.float32)))
+    if amax < np.finfo(np.float32).tiny:
+        amax = np.float32(1.0)
+    bits = int(amax.view(np.int32))
+    return float(((bits >> 23) & 0xFF) - 127 - (2 ** spec.n_exp - 1))
 
 
 def af_quantize(

@@ -5,9 +5,45 @@ import pytest
 from hypothesis_compat import given, settings, st  # property tests skip if absent
 
 from repro.accel import flexasr as fa, hlscnn as hc, numerics, vta as vt
+from repro.accel.target import PlanContext
 from repro.core import ir, validate
+from repro.core.ila import FragmentCache
 
 rng = np.random.default_rng(0)
+
+
+def _random_magnitudes():
+    r = np.random.default_rng(14)
+    shapes = [(7,), (3, 5), (1, 64), (28, 28)]   # few shapes: few compiles
+    return [(r.standard_normal(shapes[i % 4]) * 10.0 ** r.uniform(-30, 30))
+            .astype(np.float32) for i in range(400)]
+
+
+def _mnist_rnn_shapes():
+    r = np.random.default_rng(28)
+    return [r.standard_normal(s).astype(np.float32) * 0.3
+            for s in [(1, 64), (1, 32), (1, 10), (28, 28)]]
+
+
+_TINY = np.finfo(np.float32).tiny
+_EXP_BIAS_CASES = {
+    "zeros": lambda: [np.zeros((4, 8), np.float32), -np.zeros((3,), np.float32)],
+    "subnormal": lambda: [
+        np.full((5,), _TINY / 2, np.float32),
+        np.array([0.0, -_TINY / 4, 1e-45], np.float32),
+        np.array([np.nextafter(_TINY, 0, dtype=np.float32)], np.float32)],
+    "powers_of_two": lambda: [np.array([0.0, 2.0 ** k], np.float32)
+                              for k in range(-126, 128)],
+    "negative_max": lambda: [np.array([0.5, -3.0, 2.9], np.float32),
+                             np.array([-(2.0 ** 40), 1.0], np.float32)],
+    "below_power_of_two": lambda: [
+        np.array([np.nextafter(np.float32(2.0 ** k), 0, dtype=np.float32)],
+                 np.float32) for k in range(-125, 128, 7)],
+    "float64_rounds_to_float32": lambda: [
+        np.array([2.0 - 2.0 ** -30]), np.array([-(1.0 - 2.0 ** -40), 0.25])],
+    "mnist_rnn_shapes": _mnist_rnn_shapes,
+    "random_magnitudes": _random_magnitudes,
+}
 
 
 class TestAdaptivFloat:
@@ -66,12 +102,79 @@ class TestAdaptivFloat:
                                               exp_bias=jnp.float32(bias)))
         np.testing.assert_array_equal(got, want)
 
+    @pytest.mark.parametrize("case", sorted(_EXP_BIAS_CASES))
+    @pytest.mark.parametrize("n_exp", [2, 3])
+    def test_host_exp_bias_matches_jax(self, case, n_exp):
+        """The planners' numpy exponent bias equals ``af_exp_bias`` bit for
+        bit, on every tensor the co-sim path can hand it."""
+        spec = numerics.AdaptivFloatSpec(8, n_exp)
+        for x in _EXP_BIAS_CASES[case]():
+            want = float(numerics.af_exp_bias(jnp.asarray(x), spec))
+            got = numerics.af_exp_bias_host(x, spec)
+            assert type(got) is float
+            assert got == want, (case, x.dtype, x.shape, got, want)
+
     def test_fixed_point_grid(self):
         spec = numerics.FixedPointSpec(8, 3)
         x = jnp.asarray([0.124, -0.3, 5.0, 100.0])
         q = np.asarray(numerics.fx_quantize(x, spec))
         np.testing.assert_allclose(q * 8, np.round(q * 8))   # on the 2^-3 grid
         assert q[3] == spec.qmax / spec.scale                # saturates
+
+
+class _NoJax:
+    def __getattr__(self, name):
+        raise AssertionError(f"jnp.{name} on the pack path")
+
+
+def _fasr_inputs(*shapes, seed=14):
+    r = np.random.default_rng(seed)
+    return [(r.standard_normal(s) * 10.0 ** r.uniform(-3, 1)).astype(np.float32)
+            for s in shapes]
+
+
+def _pack_linear():
+    x, w, b = _fasr_inputs((16, 64), (32, 64), (32,))
+    frag = fa.linear_fragment(w, b)
+    return [fa.pack_linear_data(frag, x).tail]
+
+
+def _pack_lstm():
+    x, wi, wh, b = _fasr_inputs((28, 28), (256, 28), (256, 64), (256,))
+    frag = fa.lstm_fragment(wi, wh, b)
+    return [fa.pack_lstm_data(frag, x).tail]
+
+
+def _pack_pool():
+    (x,) = _fasr_inputs((16, 64))
+    return [fa.pack_pool_data(fa.pool_fragment(64, "max"), x).tail]
+
+
+def _pack_layernorm():
+    x, g, b = _fasr_inputs((8, 32), (32,), (32,))
+    return [fa.pack_layernorm_data(fa.layernorm_fragment(g, b), x).tail]
+
+
+def _pack_attention():
+    q, k, v = _fasr_inputs((8, 16), (12, 16), (12, 16))
+    return [fa.pack_attention_data(fa.attention_fragment(16), q, k, v).tail]
+
+
+def _plan_linear():
+    a, w, b = _fasr_inputs((200, 64), (10, 64), (10,))
+    jobs, _ = fa.plan_linear(PlanContext(record=lambda *a: None), None, (a, w, b))
+    return [j.data.tail for j in jobs]
+
+
+def _plan_lstm():
+    xs, wi, wh, b = _fasr_inputs((28, 3, 28), (256, 28), (256, 64), (256,))
+    jobs, _ = fa.plan_lstm(PlanContext(record=lambda *a: None), None, (xs, wi, wh, b))
+    return [j.data.tail for j in jobs]
+
+
+_PACKERS = {f.__name__[1:]: f for f in (
+    _pack_linear, _pack_lstm, _pack_pool, _pack_layernorm, _pack_attention,
+    _plan_linear, _plan_lstm)}
 
 
 class TestFlexASR:
@@ -121,6 +224,30 @@ class TestFlexASR:
         b = np.zeros((32,), np.float32)
         cmds, _ = fa.build_lstm_fragment(x, wi, wh, b)
         assert sum(1 for c in cmds if c.opcode == fa.FN_START) == 1
+
+    @pytest.mark.parametrize("planner", sorted(_PACKERS))
+    def test_planners_pack_without_jax(self, planner, monkeypatch):
+        """FlexASR's packers and planners dispatch no JAX (they run on the
+        pack worker), and their exponent windows equal the JAX path's."""
+        def numerics_rows(streams):
+            return [s.data[s.ops == fa.CFG_NUMERICS] for s in streams]
+
+        with monkeypatch.context() as m:
+            def eager(*a, **k):
+                raise AssertionError("eager JAX on the pack path")
+            m.setattr(numerics, "af_exp_bias", eager)
+            m.setattr(fa, "jnp", _NoJax())
+            m.setattr(fa, "FRAGMENTS", FragmentCache())
+            got = numerics_rows(_PACKERS[planner]())
+        with monkeypatch.context() as m:
+            m.setattr(fa, "_exp_biases", lambda *ts: [
+                float(numerics.af_exp_bias(jnp.asarray(t), fa.AF)) for t in ts])
+            m.setattr(fa, "FRAGMENTS", FragmentCache())
+            want = numerics_rows(_PACKERS[planner]())
+        assert len(got) == len(want) >= 1
+        for g, w in zip(got, want):
+            assert g.shape == (1, fa.V)
+            np.testing.assert_array_equal(g, w)
 
 
 class TestVTA:
