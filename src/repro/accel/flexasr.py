@@ -42,8 +42,9 @@ operation-level relative errors reproduce Table 2's magnitudes.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import List
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,9 +53,10 @@ import numpy as np
 from ..core import ir
 from ..core.egraph import P, V as PV, Rewrite, shape_of
 from ..core.ila import (
-    ILA, BulkWrite, Command, CompiledFragment, DataStream, FusedRunner,
-    PackedStream, _replicated, _shard_batched, fingerprint, fused_lowering,
-    fused_pad_streams, named, shard_streams, stream_mesh,
+    ILA, BulkWrite, Command, CompiledFragment, DataStream, FragmentCache,
+    FusedRunner, PackedStream, _replicated, _shard_batched, batch_bucket,
+    bucket_length, fingerprint, fused_lowering, fused_pad_streams, named,
+    shard_streams, stream_mesh,
 )
 from ..core.telemetry import TELEMETRY
 from . import numerics
@@ -521,6 +523,296 @@ def build_linear_fragment(x, w, b, act: int = ACT_NONE):
     return cmds, lambda st: _read_matrix(st, BASE_OUT, T, O)
 
 
+# -- tiled LinearLayer: widths over one invocation ---------------------------
+#
+# One LinearLayer invocation holds at most TILE = MAX_IN inputs and TILE
+# outputs (the global buffer keeps MAX_IN-wide rows). A wider linear runs
+# the way a driver for a 128-lane FlexASR runs it: its weights cut into
+# ot x kt tiles of at most TILE x TILE, each tile the setup of its own
+# LinearLayer (the bias on the first K slice only, so it is added once);
+# every row chunk of TILE rows runs through every tile, and the kt partial
+# outputs of an output tile are summed in float32, in K order. Output
+# tiles are exact. A K slice quantizes its partial sum to AF-8 in its own
+# exponent window, sized from that partial's ideal, so the ideal the
+# planner records follows the split.
+#
+# The tiles stay resident on the device (``TiledLinear``), and one jitted
+# runner dispatches every tile invocation of one linear over all the row
+# chunks and samples of a group: each invocation replays FN_START's linear
+# data path (bulk row write, then the GB_CFG_GB_CONTROL / CFG_NUMERICS /
+# FN_START tail, the ILA's own instruction updates) from its tile's
+# post-setup state. Row chunks are padded to TILE rows and their count per
+# sample to a power of two, so ragged rows never make a new runner shape;
+# padded rows are discarded. What the driver computes around the
+# invocations runs in the same dispatch, in float32: each output window
+# from its partial's ideal (at full precision), the partial sums, and the
+# error statistics; the host multiplies nothing and reads back one block
+# per sample.
+
+TILE = MAX_IN
+#: tile invocations the runner vmaps together (lax.map's batch): bounds
+#: the device memory of the per-invocation architectural states
+TILE_BATCH = 64
+#: the resident tiled linears: room for a Moonlight-sized program's 136
+#: with a margin, so the previous program's tiles leave the device as a
+#: new one's arrive (frozen weights are keyed by identity: _weights_key)
+TILED = FragmentCache(maxsize=192)
+
+
+def _frozen(a: np.ndarray) -> bool:
+    return not a.flags.writeable and a.flags.owndata
+
+
+def _weights_key(w: np.ndarray, b: np.ndarray) -> Tuple:
+    """The cache key of a tiled linear's weights: the weight array's
+    identity when it is frozen (read-only and owning its data, as a served
+    program's weights are; the cached entry holds it, so the id is not
+    reused while the entry lives), else a fingerprint of its contents.
+    Hashing gigabytes of weights per request would cost more than the
+    co-simulation; the bias is small and always hashed."""
+    wkey = ("id", id(w)) if _frozen(w) else fingerprint(w)
+    return ("fasr_tiled_linear", w.shape, wkey, fingerprint(b))
+
+
+class TiledLinear:
+    """The resident weight tiles of one linear wider than TILE, in the
+    place of a ``CompiledFragment`` for the Executor (``ila``, ``key``,
+    ``setup``, ``meta``, ``run``, ``prepare_batch``, ``run_prepared``).
+
+    Tile ``t = o * kt + k`` holds rows ``o`` and columns ``k`` of the
+    weight blocks. Its post-setup state (``tile_state``) is what
+    simulating the setup stream of ``linear_fragment(w_tile, b_tile)``
+    leaves; the setup streams themselves are not built (``tile_setup``
+    builds one, for parity checks), so ``setup`` is empty."""
+
+    ila = flexasr
+
+    def __init__(self, w: np.ndarray, b: np.ndarray, key: Tuple = ()):
+        O, I = w.shape
+        ot, kt = _cdiv(O, TILE), _cdiv(I, TILE)
+        wp = np.zeros((ot * TILE, kt * TILE), np.float32)
+        wp[:O, :I] = w
+        tiles = np.ascontiguousarray(
+            wp.reshape(ot, TILE, kt, TILE).transpose(0, 2, 1, 3)
+        ).reshape(ot * kt, TILE, TILE)
+        bias = np.zeros((ot, kt, TILE), np.float32)
+        bias[:, 0] = np.pad(b, (0, ot * TILE - O)).reshape(ot, TILE)
+        n_out = np.minimum(TILE, O - np.arange(ot) * TILE)
+        n_in = np.minimum(TILE, I - np.arange(kt) * TILE)
+        self.key = key
+        self.setup = PackedStream.empty(V)
+        self.meta = {
+            "w": w, "b": b, "I": I, "O": O, "ot": ot, "kt": kt,
+            "bw": numerics.af_exp_bias_amax(np.abs(tiles).max(axis=(1, 2)), AF),
+            "n_in": np.tile(n_in, ot).astype(np.float32),
+            "n_out": np.repeat(n_out, kt).astype(np.float32),
+        }
+        self._tiles = (tiles, bias.reshape(ot * kt, TILE))
+        self._resident = None
+        self._runs = set()  # runner shapes dispatched so far
+
+    @property
+    def n_tiles(self) -> int:
+        return self.meta["ot"] * self.meta["kt"]
+
+    def clone(self) -> "TiledLinear":
+        """Device-local copy for a simulated device: the resident tiles are
+        read-only, so every device shares them."""
+        return self
+
+    def tile_setup(self, t: int) -> CompiledFragment:
+        """Tile ``t``'s own LinearLayer fragment (uncached): its setup
+        stream is what the driver would send to load the tile."""
+        m = self.meta
+        o, k = divmod(t, m["kt"])
+        w = m["w"][o * TILE:(o + 1) * TILE, k * TILE:(k + 1) * TILE]
+        b = m["b"][o * TILE:(o + 1) * TILE] if k == 0 else np.zeros(w.shape[0], np.float32)
+        return linear_fragment(w, b, cache=False)
+
+    def resident(self):
+        """The tiles on the device, put there on first use (from the
+        dispatch thread): weights (n, TILE, TILE), biases (n, TILE), and
+        each tile's weight exponent window and input and output widths
+        (n,)."""
+        if self._resident is None:
+            tiles, bias = self._tiles
+            m = self.meta
+            self._resident = tuple(jnp.asarray(a) for a in (
+                tiles, bias, m["bw"], m["n_in"], m["n_out"]))
+            self._tiles = None
+        return self._resident
+
+    def prepare_batch(self, datas: List["TiledData"]):
+        """Host half: stack the samples' row-chunk blocks, input windows
+        and real rows per chunk, padding the batch to its bucket and the
+        chunk count to a power of two (zero blocks of no rows; their
+        outputs are discarded)."""
+        kt = self.meta["kt"]
+        Bp = batch_bucket(len(datas))
+        C = bucket_length(max(d.slots.shape[0] for d in datas), min_len=1)
+        xs = np.zeros((Bp, C, kt, TILE, TILE), np.float32)
+        ba = np.zeros((Bp, C, kt), np.float32)
+        rows = np.zeros((Bp, C), np.float32)
+        for i, d in enumerate(datas):
+            c = d.slots.shape[0]
+            xs[i, :c], ba[i, :c] = d.slots, d.ba
+            rows[i, :c] = np.minimum(TILE, d.rows - np.arange(c) * TILE)
+        return ("tiled", (xs, ba, rows))
+
+    def run_prepared(self, prepared):
+        """Dispatch half: one runner call over every tile invocation of
+        the group (see ``_run_tiles``)."""
+        xs, ba, rows = prepared[1]
+        m = self.meta
+        shape = (m["ot"], m["kt"], m["O"], xs.shape)
+        run = functools.partial(_tiled_run, ot=m["ot"], kt=m["kt"], O=m["O"])
+        with TELEMETRY.span("flexasr.tiled_linear",
+                            tiles=xs.shape[0] * xs.shape[1] * self.n_tiles,
+                            rows=int(rows.sum())):
+            args = (*self.resident(), jnp.asarray(xs), jnp.asarray(ba),
+                    jnp.asarray(rows))
+            if shape in self._runs:
+                return run(*args)
+            self._runs.add(shape)
+            with TELEMETRY.span("executor.compile", kind="tiled_linear",
+                                ila=flexasr.name):
+                return run(*args)
+
+    def run(self, data: "TiledData"):
+        return self.run_prepared(self.prepare_batch([data]))[0]
+
+
+@dataclasses.dataclass
+class TiledData:
+    """One sample's data streams for every tile invocation of a tiled
+    linear, as arrays: ``slots`` (C, kt, TILE, TILE), each row chunk's K
+    slices as the bulk write lands them in gb_large (zero-padded), and
+    ``ba`` (C, kt), their input exponent windows; ``rows`` real rows."""
+
+    slots: np.ndarray
+    ba: np.ndarray
+    rows: int
+    n_commands: int
+
+    def __len__(self) -> int:
+        return self.n_commands
+
+    def sig(self) -> Tuple:
+        # every sample of one tiled linear shares a dispatch
+        return ("fasr_tiled_linear",)
+
+
+def tiled_linear(w, b) -> TiledLinear:
+    w, b = np.asarray(w, np.float32), np.asarray(b, np.float32)
+    key = _weights_key(w, b)
+    return TILED.get(key, lambda: TiledLinear(w, b, key))
+
+
+def tile_state(base, w, b, n_in, n_out):
+    """The post-setup state of one tile (weights (TILE, TILE), bias
+    (TILE,), its widths): ``base`` (the reset state) with the PE memories
+    and configuration registers a LinearLayer setup stream writes. Traced
+    under vmap by the runner; ``tiled_setup_state`` gives it for one tile."""
+    st = dict(base)
+    st["pe_w"] = base["pe_w"].at[:TILE].set(w)
+    st["pe_b"] = base["pe_b"].at[:TILE].set(b)
+    st["num_in"], st["num_out"] = n_in, n_out
+    st["is_bias"] = jnp.float32(1.0)
+    st["act_mode"] = jnp.float32(ACT_NONE)
+    st["base_in"], st["base_out"] = jnp.float32(BASE_IN), jnp.float32(BASE_OUT)
+    st["base_aux"], st["num_aux"] = jnp.float32(0.0), jnp.float32(0.0)
+    return st
+
+
+def tiled_setup_state(tl: TiledLinear, t: int):
+    """Tile ``t``'s post-setup state as the runner builds it."""
+    w, b, _bw, n_in, n_out = (a[t] for a in tl.resident())
+    return tile_state(flexasr.init_state(), w, b, n_in, n_out)
+
+
+def output_window(x, w, b, rows):
+    """What the driver sends as an invocation's output exponent window:
+    sized from the ideal of its own (partial) product ``x @ w.T + b``,
+    float32 at full precision, over the chunk's ``rows`` real rows.
+    Returns (the window, the ideal)."""
+    part = jnp.dot(x, w.T, precision=jax.lax.Precision.HIGHEST) + b
+    live = jnp.arange(x.shape[0])[:, None] < rows
+    amax = jnp.max(jnp.where(live, jnp.abs(part), 0.0))
+    amax = jnp.where(amax < np.finfo(np.float32).tiny, 1.0, amax)
+    return numerics.floor_log2(amax) - (2 ** AF.n_exp - 1), part
+
+
+def _linear_invocation(st, rows, numerics_row):
+    """One LinearLayer data stream from a post-setup state: the bulk write
+    of a TILE-row chunk at BASE_IN, then the tail GB_CFG_GB_CONTROL
+    (linear, TILE rows), CFG_NUMERICS, FN_START, each through the ILA's own
+    instruction update; returns the output block (``read_full``)."""
+    st = dict(st)
+    st["gb_large"] = jax.lax.dynamic_update_slice(st["gb_large"], rows, (BASE_IN, 0))
+    zero = jnp.zeros((V,), jnp.float32)
+    tail = (
+        (GB_CFG_GB_CONTROL, zero.at[:2].set(jnp.asarray([MODE_LINEAR, TILE], jnp.float32))),
+        (CFG_NUMERICS, zero.at[:3].set(numerics_row)),
+        (FN_START, zero),
+    )
+    for op, row in tail:
+        st = flexasr._by_opcode[op].update(st, jnp.int32(0), row)
+    return read_full(st)
+
+
+def _run_tiles(tiles, bias, bw, n_in, n_out, xs, ba, rows, *, ot, kt, O):
+    """Every tile invocation of one tiled linear over a group: ``xs``
+    (B, C, kt, TILE, TILE) row-chunk blocks, ``ba`` (B, C, kt) their input
+    windows, ``rows`` (B, C) their real rows. Invocation (b, c, o, k) runs
+    tile o * kt + k on block (b, c, k), its CFG_NUMERICS payload the tile's
+    weight window, the block's input window and the output window of
+    ``output_window``; the kt partial outputs (and ideals) are summed in K
+    order. Returns (B, C * TILE + 1, O): the summed outputs, then a row
+    whose first two entries are the squared error against the summed
+    ideals, and the squared ideals, over the real rows."""
+    B, C = xs.shape[:2]
+    j = jnp.arange(B * C * ot * kt)
+    k, o, bc = j % kt, (j // kt) % ot, j // (kt * ot)
+    blocks = xs.reshape(B * C * kt, TILE, TILE)
+    ba, rows = ba.reshape(-1), rows.reshape(-1)
+    base = flexasr.init_state()
+
+    def one(job):
+        t, s, c = job
+        x, w, b = blocks[s], tiles[t], bias[t]
+        bo, part = output_window(x, w, b, rows[c])
+        st = tile_state(base, w, b, n_in[t], n_out[t])
+        y = _linear_invocation(st, x.reshape(TILE * (MAX_IN // V), V),
+                               jnp.stack([bw[t], ba[s], bo]))
+        return y, part
+
+    ys = jax.lax.map(one, (o * kt + k, bc * kt + k, bc), batch_size=TILE_BATCH)
+
+    def k_sum(a):
+        a = a.reshape(B, C, ot, kt, TILE, TILE)
+        acc = a[:, :, :, 0]
+        for kk in range(1, kt):
+            acc = acc + a[:, :, :, kk]
+        return acc.transpose(0, 1, 3, 2, 4).reshape(B, C * TILE, ot * TILE)[:, :, :O]
+
+    out, ideal = k_sum(ys[0]), k_sum(ys[1])
+    # the accuracy statistics over each sample's real rows, so the ideal
+    # is not read back: sum of squared errors, sum of squared ideals
+    live = (jnp.arange(TILE)[None, None, :] < rows.reshape(B, C)[:, :, None]).reshape(B, -1, 1)
+    sq = lambda v: jnp.sum(jnp.where(live, v * v, 0.0), axis=(1, 2))  # noqa: E731
+    stats = jnp.zeros((B, 1, O), jnp.float32)
+    stats = stats.at[:, 0, 0].set(sq(out - ideal)).at[:, 0, 1].set(sq(ideal))
+    return jnp.concatenate([out, stats], axis=1)
+
+
+_tiled_run = jax.jit(named(_run_tiles, "flexasr_tiled_linear"),
+                     static_argnames=("ot", "kt", "O"))
+
+
+def _tiled_read(y):
+    return y
+
+
 # -- LSTM --------------------------------------------------------------------
 
 
@@ -735,8 +1027,8 @@ def build_attention_fragment(q, k, v):
 
 
 def _linear_guard(eg, cid, s):
-    b = shape_of(eg, s["b"])
-    return len(shape_of(eg, s["c"])) == 1 and b[1] <= MAX_IN and b[0] <= MAX_IN
+    # any width: the planner tiles what one invocation cannot hold
+    return len(shape_of(eg, s["c"])) == 1 and len(shape_of(eg, s["b"])) == 2
 
 
 def _lstm_guard(eg, cid, s):
@@ -748,8 +1040,10 @@ def _lstm_guard(eg, cid, s):
 def _attn_guard(eg, cid, s):
     q = shape_of(eg, s["q"])
     k = shape_of(eg, s["k"])
-    # KV length is not driver-chunkable, hence the MAX_TS guard
-    return q[-1] <= MAX_IN and q[-2] <= MAX_TS and k[-2] <= MAX_TS
+    # KV length is not driver-chunkable, hence the MAX_TS guard; the
+    # attention FN_START computes has no causal mask
+    return (not s.get("causal") and q[-1] <= MAX_IN and q[-2] <= MAX_TS
+            and k[-2] <= MAX_TS)
 
 
 def _rewrites():
@@ -768,7 +1062,7 @@ def _rewrites():
         ),
         Rewrite(
             "fasr-attention",
-            P("attention", PV("q"), PV("k"), PV("v")),
+            P("attention", PV("q"), PV("k"), PV("v"), attr_binds=("causal",)),
             P("fasr_attention", PV("q"), PV("k"), PV("v")),
             guard=_attn_guard,
         ),
@@ -866,6 +1160,8 @@ def kernel_linear(ctx, x, args):
 
 def plan_linear(ctx, x, args):
     a, w, b = args
+    if w.shape[0] > TILE or w.shape[1] > TILE:
+        return plan_tiled_linear(ctx, x, args)
     orig_shape = a.shape
     a2 = a.reshape(-1, a.shape[-1])
     O = w.shape[0]
@@ -879,8 +1175,45 @@ def plan_linear(ctx, x, args):
     ]
 
     def assemble(outs):
+        if not outs:  # no rows routed here: nothing was invoked
+            return np.zeros(orig_shape[:-1] + (O,), np.float32)
         out = np.concatenate(outs, axis=0)
         ctx.record("fasr_linear", "flexasr", out, ideal_full, ctx.ncmds(jobs))
+        return out.reshape(orig_shape[:-1] + (O,))
+
+    return jobs, assemble
+
+
+def plan_tiled_linear(ctx, x, args):
+    """A linear wider than one invocation (see ``TiledLinear``): one job
+    per sample, holding every tile invocation of its row chunks: the
+    chunks' K slices and their input exponent windows. The output windows
+    and the recorded error against the ideal come from the runner
+    (``_run_tiles``)."""
+    a, w, b = args
+    orig_shape = a.shape
+    a2 = np.asarray(a.reshape(-1, a.shape[-1]), np.float32)
+    O, I = w.shape
+    rows = a2.shape[0]
+    if rows == 0:  # no rows routed here: nothing is invoked
+        return [], lambda outs: np.zeros(orig_shape[:-1] + (O,), np.float32)
+    tl = tiled_linear(w, b)
+    kt, C = tl.meta["kt"], _cdiv(rows, TILE)
+    xp = np.zeros((C * TILE, kt * TILE), np.float32)
+    xp[:rows, :I] = a2
+    slots = np.ascontiguousarray(xp.reshape(C, TILE, kt, TILE).transpose(0, 2, 1, 3))
+    ba = numerics.af_exp_bias_amax(np.abs(slots).max(axis=(2, 3)), AF)
+    n = C * tl.n_tiles
+    data_cmds = n * (TILE * (MAX_IN // V) + 3)
+    setup_cmds = _tiled_setup_commands(O, I)
+    ctx.count("flexasr.linear_tiles", n)
+    jobs = [SimJob(tl, TiledData(slots, ba, rows, data_cmds), _tiled_read,
+                   (slice(None), slice(0, O)))]
+
+    def assemble(outs):
+        out, (sq_err, sq_ideal) = outs[0][:rows], outs[0][-1, :2]
+        err = float(np.sqrt(sq_err / sq_ideal)) if sq_ideal > 0 else 0.0
+        ctx.record("fasr_linear", "flexasr", out, None, setup_cmds + data_cmds, err=err)
         return out.reshape(orig_shape[:-1] + (O,))
 
     return jobs, assemble
@@ -1013,13 +1346,33 @@ def _nrows(shape) -> int:
     return int(np.prod(shape[:-1])) if len(shape) > 1 else 1
 
 
+def _tile_widths(n: int) -> np.ndarray:
+    return np.minimum(TILE, n - np.arange(_cdiv(n, TILE)) * TILE)
+
+
+def _cdiv_v(n: np.ndarray) -> np.ndarray:
+    return -(-n // V)
+
+
+def _tiled_setup_commands(O: int, I: int) -> int:
+    """Setup commands of every tile of an (O, I) linear: each tile's
+    weight rows, bias and configuration, as ``linear_fragment`` sends
+    them."""
+    o, i = _tile_widths(O), _tile_widths(I)
+    return int(O * _cdiv_v(i).sum() + len(i) * _cdiv_v(o).sum() + 4 * len(o) * len(i))
+
+
 @COSTS.op("fasr_linear")
 def _cost_linear(attrs, shapes):
+    """Every tile's setup and every tile invocation's data stream; one
+    tile (widths up to 128) is one LinearLayer invocation per row chunk."""
     a, w = shapes[0], shapes[1]
     rows, I, O = _nrows(a), a[-1], w[0]
-    setup = O * _cdiv(I, V) + _cdiv(O, V) + 4
-    data = rows * _cdiv(I, V) + 5 * _cdiv(rows, MAX_TS)
-    return setup + data, 4 * (rows * I + O * I + O + rows * O), rows * O * I / V
+    ot, kt = _cdiv(O, TILE), _cdiv(I, TILE)
+    setup = _tiled_setup_commands(O, I)
+    data = ot * (rows * _cdiv_v(_tile_widths(I)).sum() + 5 * kt * _cdiv(rows, MAX_TS))
+    moved = 4 * (ot * rows * I + O * I + O + kt * rows * O)
+    return int(setup + data), moved, rows * O * I / V
 
 
 @COSTS.op("fasr_lstm")

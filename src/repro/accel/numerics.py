@@ -77,11 +77,16 @@ def af_exp_bias_host(x, spec: AdaptivFloatSpec) -> float:
     rounded to float32 as ``jnp.asarray`` rounds it, and a max magnitude
     below the smallest normal counts as zero, as XLA flushes subnormals on
     the CPU and on the TPU (a bare exponent read would floor it at -127)."""
-    amax = np.max(np.abs(np.asarray(x, np.float32)))
-    if amax < np.finfo(np.float32).tiny:
-        amax = np.float32(1.0)
-    bits = int(amax.view(np.int32))
-    return float(((bits >> 23) & 0xFF) - 127 - (2 ** spec.n_exp - 1))
+    return float(af_exp_bias_amax(np.max(np.abs(np.asarray(x, np.float32))), spec))
+
+
+def af_exp_bias_amax(amax, spec: AdaptivFloatSpec) -> np.ndarray:
+    """``af_exp_bias_host`` of tensors given by their max magnitudes (an
+    array of them: one exponent bias per block of a tiled tensor)."""
+    amax = np.asarray(amax, np.float32)
+    amax = np.where(amax < np.finfo(np.float32).tiny, np.float32(1.0), amax)
+    bits = amax.view(np.int32)
+    return (((bits >> 23) & 0xFF) - 127 - (2 ** spec.n_exp - 1)).astype(np.float32)
 
 
 def af_quantize(

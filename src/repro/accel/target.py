@@ -67,6 +67,8 @@ class PlanContext:
 
     record: Callable[..., None]
     options: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: ``count(name, n)``: add to a workload counter (tile invocations...)
+    count: Callable[[str, float], None] = lambda name, n: None
 
     @staticmethod
     def chunk_rows(x: np.ndarray, max_rows: int) -> List[np.ndarray]:

@@ -61,6 +61,10 @@ MODE_SIGMOID = 2
 _WORDS = MAX_ROWS * MAX_COLS // V
 
 vecunit = ILA("vecunit", vwidth=V)
+# the driver's per-chunk scales: never shared across a batch (see
+# ILA.per_stream), so a batch's runner does not depend on whether its
+# chunks' scales happen to agree
+vecunit.per_stream = frozenset({CFG_NUM})
 
 TARGET = AcceleratorTarget(
     "vecunit",
@@ -148,22 +152,6 @@ def _ew_start(st, addr, data):
 # --------------------------------------------------------------------------
 
 
-def _exp_of(x: np.ndarray) -> float:
-    """Driver-chosen power-of-two scale: amax representable on the grid."""
-    amax = float(np.abs(x).max()) if x.size else 0.0
-    if amax <= 0.0:
-        return 0.0
-    return float(np.ceil(np.log2(amax / QMAX)))
-
-
-def _rows_of(x2: np.ndarray) -> np.ndarray:
-    """(R, C) block -> V-lane word rows, zero-padded to the buffer layout."""
-    R = x2.shape[0]
-    buf = np.zeros((R, MAX_COLS), np.float32)
-    buf[:, : x2.shape[1]] = x2
-    return buf.reshape(R * (MAX_COLS // V), V)
-
-
 def ew_fragment(kind: str, cache: bool = True) -> CompiledFragment:
     """No stationary operand: the setup stream is empty; the fragment exists
     to cache/batch same-kind invocations through one compiled runner."""
@@ -177,45 +165,67 @@ def ew_fragment(kind: str, cache: bool = True) -> CompiledFragment:
     return FRAGMENTS.get(key, build) if cache else build()
 
 
-def _tail(entries) -> PackedStream:
-    n = len(entries)
-    ops = np.array([e[0] for e in entries], np.int32)
-    addrs = np.zeros((n,), np.int32)
-    data = np.zeros((n, V), np.float32)
-    for i, (_, vals) in enumerate(entries):
-        vals = np.asarray(vals, np.float32)
-        data[i, : len(vals)] = vals
-    return PackedStream(ops, addrs, data)
-
-
 def pack_ew_data(
     frag: CompiledFragment, a2: np.ndarray, b2: Optional[np.ndarray] = None
 ) -> DataStream:
     """Data stream for one (R, C) chunk: operand rows + geometry/scale
-    config + trigger. The driver sizes the output scale from the ideal fp32
-    result, as the FlexASR driver sizes AF exponent windows."""
+    config + trigger (see ``pack_ew_chunks``)."""
     a2 = np.asarray(a2, np.float32)
-    R, C = a2.shape
-    assert R <= MAX_ROWS and C <= MAX_COLS
-    ea = _exp_of(a2)
-    bulk = [BulkWrite("vec_a", 0, _rows_of(a2), WR_A)]
+    assert a2.shape[0] <= MAX_ROWS and a2.shape[1] <= MAX_COLS
+    ops = [a2]
     if frag.meta["mode"] == MODE_MUL:
-        b2 = np.asarray(b2, np.float32)
-        assert b2.shape == a2.shape
-        eb = _exp_of(b2)
-        eo = _exp_of(a2 * b2)
-        bulk.append(BulkWrite("vec_b", 0, _rows_of(b2), WR_B))
+        ops.append(np.asarray(b2, np.float32))
+        assert ops[1].shape == a2.shape
+    (data,) = pack_ew_chunks(frag, ops)
+    return data
+
+
+WORDS_PER_ROW = MAX_COLS // V
+
+
+def _chunk_exps(x: np.ndarray, starts: np.ndarray) -> np.ndarray:
+    """The driver-chosen power-of-two scale of each chunk of rows of ``x``
+    beginning at ``starts``: the chunk's amax representable on the grid."""
+    amax = np.maximum.reduceat(np.abs(x).max(axis=1), starts).astype(np.float64)
+    with np.errstate(divide="ignore"):
+        e = np.ceil(np.log2(amax / QMAX))
+    return np.where(amax > 0.0, e, 0.0)
+
+
+def pack_ew_chunks(frag: CompiledFragment, blocks) -> list:
+    """The data stream of every MAX_ROWS-row chunk of the (R, C <=
+    MAX_COLS) operand ``blocks``, one list entry each: operand rows (zero
+    padded to MAX_COLS), then the geometry/scale config and the trigger.
+    The driver sizes the output scale from the ideal fp32 result, as the
+    FlexASR driver sizes AF exponent windows; every chunk's scales are
+    found in one pass."""
+    blocks = [np.asarray(x, np.float32) for x in blocks]
+    R, C = blocks[0].shape
+    if C < MAX_COLS:
+        blocks = [np.pad(x, ((0, 0), (0, MAX_COLS - C))) for x in blocks]
+    a = blocks[0]
+    starts = np.arange(0, max(R, 1), MAX_ROWS)
+    ea = _chunk_exps(a, starts)
+    if frag.meta["mode"] == MODE_MUL:
+        b = blocks[1]
+        eb, eo = _chunk_exps(b, starts), _chunk_exps(a * b, starts)
     else:
-        eb = 0.0
-        eo = float(np.ceil(np.log2(1.0 / QMAX)))   # sigmoid range (0, 1)
-    tail = _tail(
-        [
-            (CFG, (frag.meta["mode"], R, C)),
-            (CFG_NUM, (ea, eb, eo)),
-            (EW_START, ()),
-        ]
-    )
-    return DataStream(bulk, tail)
+        eb = np.zeros_like(ea)
+        eo = np.full_like(ea, float(np.ceil(np.log2(1.0 / QMAX))))   # sigmoid range (0, 1)
+    words = [np.ascontiguousarray(x).reshape(R * WORDS_PER_ROW, V) for x in blocks]
+    ops = np.array([CFG, CFG_NUM, EW_START], np.int32)
+    addrs = np.zeros((3,), np.int32)
+    out = []
+    for i, r0 in enumerate(starts):
+        n = min(MAX_ROWS, R - r0)
+        tail = np.zeros((3, V), np.float32)
+        tail[0, :3] = (frag.meta["mode"], n, C)
+        tail[1, :3] = (ea[i], eb[i], eo[i])
+        w0, w1 = r0 * WORDS_PER_ROW, (r0 + n) * WORDS_PER_ROW
+        bulk = [BulkWrite(buf, 0, w[w0:w1], op)
+                for buf, w, op in zip(("vec_a", "vec_b"), words, (WR_A, WR_B))]
+        out.append(DataStream(bulk, PackedStream(ops, addrs, tail)))
+    return out
 
 
 def read_full(st) -> jnp.ndarray:
@@ -266,6 +276,8 @@ def plan_ew(ctx, x, args, kind):
     host-broadcast first (the rewrite guard only admits equal shapes, but
     the intrinsic's declared semantics allow broadcasting)."""
     shape = np.broadcast_shapes(*[np.shape(t) for t in args])
+    if not np.prod(shape):  # no rows routed here: nothing is invoked
+        return [], lambda outs: np.zeros(shape, np.float32)
     args = [np.broadcast_to(np.asarray(t, np.float32), shape) for t in args]
     a = args[0]
     ideal = a * args[1] if kind == "mul" else 1.0 / (1.0 + np.exp(-a))
@@ -276,13 +288,11 @@ def plan_ew(ctx, x, args, kind):
         buf[:n] = np.asarray(t, np.float32).ravel()
     blocks = [buf.reshape(R_total, MAX_COLS) for buf in padded]
     frag = ew_fragment(kind)
-    jobs = []
-    for r0 in range(0, R_total, MAX_ROWS):
-        chunk = [blk[r0 : r0 + MAX_ROWS] for blk in blocks]
-        jobs.append(
-            SimJob(frag, pack_ew_data(frag, *chunk), read_full,
-                   (slice(0, chunk[0].shape[0]), slice(0, MAX_COLS)))
-        )
+    jobs = [
+        SimJob(frag, data, read_full, (slice(0, data.bulk[0].rows.shape[0] // WORDS_PER_ROW),
+                                       slice(0, MAX_COLS)))
+        for data in pack_ew_chunks(frag, blocks)
+    ]
 
     def assemble(outs):
         out = np.concatenate(outs, axis=0).ravel()[:n].reshape(a.shape)

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import ir
-from ..core.egraph import P, V as PV, Rewrite
+from ..core.egraph import P, V as PV, Rewrite, shape_of
 from ..core.ila import (
     ILA, BulkWrite, Command, CompiledFragment, DataStream,
     PackedStream, fingerprint,
@@ -384,11 +384,44 @@ def build_relu_fragment(a_int: np.ndarray):
 # --------------------------------------------------------------------------
 
 
+def _gemm_guard(eg, cid, s):
+    """What ``gemm_fragment`` can hold: one row tile's K tiles fit the
+    input SRAM (``kt <= N_INP``), so K <= T * N_INP; ``plan_gemm`` chunks
+    rows and outputs to fit the rest."""
+    a = shape_of(eg, s["a"])
+    return a is not None and -(-a[-1] // T) <= N_INP
+
+
+def _alu_cols(shape) -> int:
+    """Column tiles ``plan_add``/``plan_relu`` cut the last axis into."""
+    cols = shape[-1] if len(shape) > 1 else int(np.prod(shape))
+    return -(-cols // T)
+
+
+def _add_guard(eg, cid, s):
+    """What ``alu_fragment`` can hold for one row tile of an add: both
+    operands' tiles in the accumulators (``2 * ct <= N_ACC``)."""
+    a, b = shape_of(eg, s["a"]), shape_of(eg, s["b"])
+    if a is None or b is None:
+        return False
+    out = np.broadcast_shapes(a, b)
+    return 2 * _alu_cols(out) <= N_ACC
+
+
+def _relu_guard(eg, cid, s):
+    """One row tile of a relu in the accumulators (``ct <= N_ACC``)."""
+    x = shape_of(eg, s["x"])
+    return x is not None and _alu_cols(x) <= N_ACC
+
+
 def _rewrites():
     return [
-        Rewrite("vta-gemm", P("dense", PV("a"), PV("b")), P("vta_gemm", PV("a"), PV("b"))),
-        Rewrite("vta-add", P("add", PV("a"), PV("b")), P("vta_add", PV("a"), PV("b"))),
-        Rewrite("vta-relu", P("relu", PV("x")), P("vta_relu", PV("x"))),
+        Rewrite("vta-gemm", P("dense", PV("a"), PV("b")), P("vta_gemm", PV("a"), PV("b")),
+                guard=_gemm_guard),
+        Rewrite("vta-add", P("add", PV("a"), PV("b")), P("vta_add", PV("a"), PV("b")),
+                guard=_add_guard),
+        Rewrite("vta-relu", P("relu", PV("x")), P("vta_relu", PV("x")),
+                guard=_relu_guard),
     ]
 
 
@@ -417,10 +450,11 @@ def plan_gemm(ctx, x, args):
     sb = np.abs(b).max() / 127.0 if np.abs(b).max() > 0 else 1.0
     a8 = np.clip(np.round(a / sa), -127, 127)
     b8 = np.clip(np.round(b / sb), -127, 127)
-    # tile rows so SRAM limits hold: mt*kt <= N_INP etc.
+    # tile rows so SRAM limits hold: mt*kt <= N_INP, nt*kt <= N_WGT, and
+    # at most 8 x 8 tiles so mt*nt <= N_ACC and the DRAM layout fits
     kt = (a8.shape[1] + T - 1) // T
-    max_m = max(1, (N_INP // kt)) * T
-    max_n = max(1, (N_WGT // kt)) * T
+    max_m = max(1, min(N_INP // kt, 8)) * T
+    max_n = max(1, min(N_WGT // kt, 8)) * T
     mt_layout = (min(max_m, a8.shape[0]) + T - 1) // T
     jobs, layout = [], []
     for mi in range(0, a8.shape[0], max_m):

@@ -82,6 +82,7 @@ serving path.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -170,10 +171,34 @@ def _forced(v):
     return v.force() if isinstance(v, _Deferred) else v
 
 
+def _norm(a: np.ndarray) -> float:
+    """Frobenius norm, summed in float64 without a float64 copy of ``a``
+    (the stats of a wide layer's output are tens of megabytes)."""
+    v = np.asarray(a).ravel()
+    return math.sqrt(float(np.add.reduce(v * v, dtype=np.float64)))
+
+
 def _node_op(x: ir.Expr) -> str:
     """The ``op`` a host-evaluation span names: the IR op of a call, else
     ``var`` / ``const``."""
     return x.op if isinstance(x, ir.Call) else type(x).__name__.lower()
+
+
+def _env_value(v: ir.Var, env: Dict[str, Any]) -> np.ndarray:
+    """A program input as an accelerator operand: the environment's array
+    itself (as float32), where ``ir._eval`` would put it on the device only
+    for the operand marshalling to read it back."""
+    if v.name not in env:
+        raise KeyError(f"unbound var %{v.name}")
+    return np.asarray(env[v.name], np.float32)
+
+
+def _operands(x: ir.Call, rec: Callable[[ir.Expr], List[Any]],
+              envs: Sequence[Dict[str, Any]]) -> List[List[Any]]:
+    """An accelerator call's operands, per argument and sample: program
+    inputs straight from the environments, anything else evaluated."""
+    return [[_env_value(a, env) for env in envs] if isinstance(a, ir.Var)
+            else rec(a) for a in x.args]
 
 
 def _marshal(x: ir.Call, args_b: List[List[Any]], B: int) -> List[List[np.ndarray]]:
@@ -292,10 +317,7 @@ class SimDevice:
         # long-lived Executor) can build same-key fragments. The cached
         # clone pins frag.ila alive, so the id cannot be recycled while the
         # entry is resident.
-        return self.fragments.get(
-            (frag.key, id(frag.ila)),
-            lambda: CompiledFragment(frag.ila, frag.key, frag.setup, dict(frag.meta)),
-        )
+        return self.fragments.get((frag.key, id(frag.ila)), frag.clone)
 
     def is_cold(self, frag: CompiledFragment) -> bool:
         """True when resolving ``frag`` here would re-simulate its setup
@@ -430,6 +452,9 @@ class Executor:
             for k in ("pack_s", "dispatch_s", "readback_s")
         }
         self._groups_ctr = self.metrics.counter("pipeline.groups")
+        #: where workload counters go (tile invocations planned, rows routed
+        #: to held experts): this registry, or the one of a serving front end
+        self.work_metrics = self.metrics
         self._inv_metrics: Dict[str, Tuple[Any, Any, Any, Any]] = {}
         #: programs already shape/dtype-checked (once per distinct Expr)
         self._checked: set = set()
@@ -499,7 +524,7 @@ class Executor:
             if x in memo:
                 return memo[x]
             if isinstance(x, ir.Call) and x.op in ir.ACCEL_OPS:
-                sample_args = _marshal(x, [rec(a) for a in x.args], B)
+                sample_args = _marshal(x, _operands(x, rec, envs), B)
                 if (
                     self.mode == "ila"
                     and self.engine in ("compiled", "pipelined", "fused")
@@ -531,11 +556,24 @@ class Executor:
                 else:
                     v = [self._exec_accel(x, sample_args[s]) for s in range(B)]
             else:
-                v = _host_eval(x, rec, envs)
+                v = self._eval_host(x, rec, envs)
             memo[x] = v
             return v
 
         return rec(e)
+
+    def _eval_host(self, x: ir.Expr, rec, envs) -> List[Any]:
+        """:func:`_host_eval`, counting what the ops ``ir.ROW_COUNTERS``
+        names amount to (rows routed to held experts) in
+        :attr:`work_metrics`."""
+        v = _host_eval(x, rec, envs)
+        counted = ir.ROW_COUNTERS.get(x.op) if isinstance(x, ir.Call) else None
+        if counted is not None:
+            name, count = counted
+            args = [rec(a) for a in x.args]
+            self.work_metrics.counter(name).inc(sum(
+                count(x, [a[s] for a in args]) for s in range(len(envs))))
+        return v
 
     # -- request-level submit/prepack API (continuous-batching serving) --
     def _defer_split(self, e: ir.Expr) -> set:
@@ -604,7 +642,7 @@ class Executor:
             if isinstance(x, ir.Call) and x.op in ir.ACCEL_OPS:
                 # operand subtrees feed an accelerator call, so they are
                 # never deferred: rec gives plain per-sample lists
-                sample_args = _marshal(x, [rec(a) for a in x.args], B)
+                sample_args = _marshal(x, _operands(x, rec, envs), B)
                 if TARGETS.has_planner(x.op):
                     v = self._node_pipelined(
                         x, sample_args, defer=x in deferred,
@@ -621,10 +659,10 @@ class Executor:
                 # lazily at result() time
                 for a in x.args:
                     rec(a)
-                v = _Deferred(lambda x=x: _host_eval(
+                v = _Deferred(lambda x=x: self._eval_host(
                     x, lambda a: _forced(memo[a]), envs))
             else:
-                v = _host_eval(x, rec, envs)
+                v = self._eval_host(x, rec, envs)
             memo[x] = v
             return v
 
@@ -675,7 +713,9 @@ class Executor:
                         ememo[a] = v
                         return v
 
-                    sample_args.append([np.asarray(ev(a)) for a in x.args])
+                    sample_args.append([
+                        _env_value(a, envs[s]) if isinstance(a, ir.Var)
+                        else np.asarray(ev(a)) for a in x.args])
             spans = [
                 range(i, min(i + self.pipeline_chunk, B))
                 for i in range(0, B, self.pipeline_chunk)
@@ -685,14 +725,17 @@ class Executor:
         return pre
 
     # ------------------------------------------------------------------
-    def _record(self, op, backend, out, ideal, ncmds, est=None):
+    def _record(self, op, backend, out, ideal, ncmds, est=None, err=None):
+        """One invocation's statistics; ``err`` (with ``ideal`` None) when
+        the planner's runner measured the relative error itself."""
         if not self.collect_stats:
             return
         with TELEMETRY.span("executor.stats", op=op):
-            out = np.asarray(out, np.float64)
-            ideal = np.asarray(ideal, np.float64)
-            denom = np.linalg.norm(ideal)
-            err = float(np.linalg.norm(ideal - out) / denom) if denom > 0 else 0.0
+            out = np.asarray(out)
+            if err is None:
+                ideal = np.asarray(ideal)
+                denom = _norm(ideal)
+                err = _norm(ideal - out) / denom if denom > 0 else 0.0
             self.stats.append(
                 InvocationStat(
                     op, backend, err, float(out.min()), float(out.max()), ncmds, est
@@ -717,7 +760,8 @@ class Executor:
             lambda *a, _est=est, **kw: self._record(*a, est=_est, **kw)
         )
         return PlanContext(
-            record=record, options=self.target_options.get(target.name, {})
+            record=record, options=self.target_options.get(target.name, {}),
+            count=lambda name, n: self.work_metrics.counter(name).inc(n),
         )
 
     def _exec_accel(self, x: ir.Call, args: List[np.ndarray]):

@@ -17,6 +17,8 @@ all members of a class must agree on shape, which shape-conditioned rewrites
 from __future__ import annotations
 
 import dataclasses
+import math
+from fractions import Fraction
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -364,7 +366,12 @@ def _op_shape(op, attrs, child_shapes):
     ext = ir.accel_op_shape_fn(op)
     if ext is not None:
         return tuple(ext(dict(attrs), list(cs)))
-    return None
+    if any(c is None for c in cs):
+        return None
+    try:
+        return ir.call_shape(op, attrs, cs)
+    except ir.ShapeError:
+        return None
 
 
 # -- helpers for rewrite guards/appliers (used by plugin targets too) -------
@@ -510,7 +517,14 @@ def extract_best(eg: EGraph, root: int, cost_fn=default_cost) -> Tuple[ir.Expr, 
     mapping failure is debuggable instead of a bare "no expression".
     """
     root = eg.find(root)
-    best: Dict[int, Tuple[float, ENode]] = {}
+    # Costs are summed over the expression *tree*, so a subexpression a
+    # deep program shares (a residual stream, a layer's projections) counts
+    # once per path and totals grow exponentially with depth. In floats the
+    # one-unit difference between offloading a node and not would then be
+    # lost to rounding; each node's own cost (``cost_fn`` over zero-cost
+    # children: every bundled cost function is additive) is summed exactly.
+    best: Dict[int, Tuple[Fraction, ENode]] = {}
+    own: Dict[Tuple[ENode, Tuple], float] = {}
     changed = True
     guard = 0
     while changed:
@@ -520,20 +534,23 @@ def extract_best(eg: EGraph, root: int, cost_fn=default_cost) -> Tuple[ir.Expr, 
             raise RuntimeError("extract: no fixpoint")
         for cid, nodes in eg.classes.items():
             for n in nodes:
-                cc, cs = [], []
+                kids, cs = [], []
                 ok = True
                 for ch in n.children:
                     ch = eg.find(ch)
                     if ch not in best:
                         ok = False
                         break
-                    cc.append(best[ch][0])
+                    kids.append(ch)
                     cs.append(eg.shape.get(ch))
                 if not ok:
                     continue
-                c = cost_fn(n.head, cc, cs)
-                if not np.isfinite(c):
+                key = (n, tuple(cs))
+                if key not in own:
+                    own[key] = cost_fn(n.head, [0.0] * len(kids), cs)
+                if not np.isfinite(own[key]):
                     continue
+                c = Fraction(own[key]) + sum((best[ch][0] for ch in kids), Fraction(0))
                 if cid not in best or c < best[cid][0]:
                     best[cid] = (c, n)
                     changed = True
@@ -573,7 +590,14 @@ def extract_best(eg: EGraph, root: int, cost_fn=default_cost) -> Tuple[ir.Expr, 
         memo[cid] = e
         return e
 
-    return build(root), best[root][0]
+    return build(root), _as_float(best[root][0])
+
+
+def _as_float(c: Fraction) -> float:
+    try:
+        return float(c)
+    except OverflowError:
+        return math.inf
 
 
 def extract(eg: EGraph, root: int, cost_fn=default_cost) -> ir.Expr:

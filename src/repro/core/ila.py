@@ -402,6 +402,11 @@ class ILA:
         # stream length (and per batch shape for the vmapped tier)
         self.n_traces_single = 0
         self.n_traces_batch = 0
+        #: opcodes whose payload is per-stream data (a driver's numeric
+        #: scales), batched even where a batch's rows happen to agree: the
+        #: shared-payload mask keys the compiled runner, and rows that agree
+        #: only by chance would compile a new variant mid-serving
+        self.per_stream: frozenset = frozenset()
         self.instruction("nop", NOP_OPCODE, "identity update (bucket padding)")(
             lambda st, addr, data: st
         )
@@ -694,18 +699,17 @@ class ILA:
         B = len(datas)
         Bp = mesh_pad(batch_bucket(B))
         datas = list(datas) + [datas[-1]] * (Bp - B)
-        tail0 = datas[0].tail.data
-        shared_mask = tuple(
-            bool(all(np.array_equal(d.tail.data[i], tail0[i]) for d in datas[1:]))
-            for i in range(tail0.shape[0])
-        )
+        tails = np.stack([d.tail.data for d in datas])          # (Bp, L, V)
+        same = np.all(tails == tails[:1], axis=(0, 2))
+        if self.per_stream:
+            same &= ~np.isin(datas[0].tail.ops, list(self.per_stream))
+        shared_mask = tuple(bool(v) for v in same)
         rows_list = [
             np.stack([d.bulk[i].rows for d in datas])
             for i in range(len(sig[0]))
         ]
-        splits = [self._split_rows(d.tail.data, shared_mask) for d in datas]
-        shared = splits[0][0]
-        batched = np.stack([s[1] for s in splits])
+        shared = tails[0][same]
+        batched = tails[:, ~same]
         return sig, shared_mask, rows_list, shared, batched
 
     def _dispatch_data_batch(self, host, state: State) -> State:
@@ -823,6 +827,11 @@ class CompiledFragment:
         if kind == "data":
             return self.ila._dispatch_data_batch(host, st)
         return self.ila._dispatch_stream_batch(host, st)
+
+    def clone(self) -> "CompiledFragment":
+        """A copy with its own (not yet simulated) setup state: a simulated
+        device's instance of the fragment."""
+        return CompiledFragment(self.ila, self.key, self.setup, dict(self.meta))
 
     def full_commands(self, data: "DataStream | PackedStream") -> List[Command]:
         """setup + data as one eager-simulable Command list (parity checks)."""

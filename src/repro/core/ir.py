@@ -24,7 +24,27 @@ Op vocabulary (the subset the paper's mappings and rewrites need):
   split_time(x; t)         -- helper for LSTM unrolling patterns
   lstm_cell(x, h, c, wi, wh, b)   -- one LSTM time step (fused gates)
   lstm(x, wi, wh, b)       -- full LSTM over time (the coarse FlexASR op)
-  attention(q, k, v)       -- scaled dot-product attention (FlexASR op)
+  attention(q, k, v; causal) -- scaled dot-product attention (FlexASR op);
+                              ``causal`` masks keys after each query's row
+  slice(x; axis, begin, end)      -- static slice of one axis
+  rms_norm(x, g; eps)             -- x / sqrt(mean(x^2) + eps) * g, last axis
+  rope(x; theta, interleaved)     -- rotary embedding of (T, d) at positions
+                                     0..T-1 (DeepSeek-V3 de-interleaves first)
+  embedding(table, ids)           -- rows of ``table`` at float token ids
+
+Mixture-of-experts routing (host ops; one chip's share of the experts):
+
+  moe_route(logits, bias; top_k, scale, held)
+      sigmoid scores, top-k over every expert by score + ``bias``, the
+      chosen scores normalised and scaled: the combine weights of the
+      experts ``held = (lo, hi)``, (T, hi - lo), zero where not chosen
+  moe_gather(x, w; expert, rows)  -- the rows of x routed to held expert
+                               column ``expert``, zero rows appended up to a
+                               multiple of ``rows`` (ragged: 0..T rows, so
+                               the expert's operands take few shapes; shape
+                               inference gives the capacity)
+  moe_combine(w, y_0, ..., y_n-1) -- each expert's rows scattered back to
+                               their tokens, weighted, summed: (T, D)
 
 Accelerator ops (targets of IR-accelerator rewrites; opaque to IR rewrites):
 
@@ -43,10 +63,14 @@ needs to edit this module.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .telemetry import TELEMETRY
 
 # --------------------------------------------------------------------------
 # Accelerator-op extension registry (the plugin-target hook)
@@ -166,6 +190,30 @@ class Call(Expr):
     args: Tuple[Expr, ...]
     attrs: Tuple[Tuple[str, Any], ...] = ()
 
+    # A program is a DAG: a layer's output feeds the next layer several
+    # times, so the generated recursive hash and equality would revisit
+    # shared subtrees once per path, exponentially in depth. The hash is
+    # computed once per node (not pickled: string hashes are salted per
+    # process), and equality stops at identity or a hash mismatch.
+    def __hash__(self):
+        h = self.__dict__.get("_hash")
+        if h is None:
+            h = hash((self.op, self.args, self.attrs))
+            object.__setattr__(self, "_hash", h)
+        return h
+
+    def __eq__(self, other):
+        if self is other:
+            return True
+        if not isinstance(other, Call) or hash(self) != hash(other):
+            return False
+        return (self.op, self.args, self.attrs) == (other.op, other.args, other.attrs)
+
+    def __getstate__(self):
+        state = dict(self.__dict__)
+        state.pop("_hash", None)
+        return state
+
     def attr(self, key, default=None):
         for k, v in self.attrs:
             if k == key:
@@ -235,6 +283,13 @@ def infer_shape(e: Expr, env: Optional[Dict[str, Tuple[int, ...]]] = None) -> Tu
         return s
 
     return rec(e)
+
+
+def call_shape(op: str, attrs: Dict[str, Any], child_shapes) -> Tuple[int, ...]:
+    """The shape rule of ``op`` over raw operand shapes (the e-graph's
+    shape analysis consults it for the ops it does not mirror)."""
+    args = tuple(Var(f"_{i}", tuple(s)) for i, s in enumerate(child_shapes))
+    return infer_shape(Call(op, args, tuple(sorted(attrs.items()))))
 
 
 def check_expr(
@@ -371,6 +426,30 @@ def _infer(x: Expr, rec, env) -> Tuple[int, ...]:
     if op == "attention":
         q, k, v = rec(args[0]), rec(args[1]), rec(args[2])
         return q[:-1] + (v[-1],)
+    if op == "slice":
+        src = list(rec(args[0]))
+        ax = x.attr("axis") % len(src)
+        b, e = x.attr("begin"), x.attr("end")
+        if not 0 <= b < e <= src[ax]:
+            raise ShapeError(f"slice [{b}:{e}] of axis {ax} of {tuple(src)}")
+        src[ax] = e - b
+        return tuple(src)
+    if op in ("rms_norm", "rope"):
+        return rec(args[0])
+    if op == "embedding":
+        table, ids = rec(args[0]), rec(args[1])
+        return ids + table[1:]
+    if op == "moe_route":
+        logits, lo_hi = rec(args[0]), x.attr("held")
+        return logits[:-1] + (lo_hi[1] - lo_hi[0],)
+    if op == "moe_gather":
+        src, pad = rec(args[0]), x.attr("rows", 1)
+        return (-(-src[0] // pad) * pad,) + src[1:]  # capacity: every row
+    if op == "moe_combine":
+        w, ys = rec(args[0]), [rec(a) for a in args[1:]]
+        if len(ys) != w[-1]:
+            raise ShapeError(f"moe_combine: {len(ys)} experts, weights {w}")
+        return w[:-1] + ys[0][-1:]
     if op == "flatten_window":
         # (OH, OW, WH, WW) -> (OH*OW, WH*WW)
         oh, ow, wh, ww = rec(args[0])
@@ -483,6 +562,91 @@ def _attention(q, k, v):
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     return p @ v
+
+
+# The host ops of a transformer layer run as one compiled program each:
+# eagerly, each would be a dozen device dispatches per call.
+@jax.jit
+def _attention_causal(q, k, v):
+    d = q.shape[-1]
+    s = (q @ jnp.swapaxes(k, -1, -2)) / jnp.sqrt(jnp.asarray(d, q.dtype))
+    tq, tk = s.shape[-2], s.shape[-1]
+    s = jnp.where(jnp.arange(tk)[None, :] <= jnp.arange(tq)[:, None], s, -jnp.inf)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    return p @ v
+
+
+@functools.partial(jax.jit, static_argnames="eps")
+def _rms_norm(x, g, eps):
+    return x * (1.0 / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)) * g
+
+
+@functools.partial(jax.jit, static_argnames=("theta", "interleaved"))
+def _rope(x, theta, interleaved):
+    """Rotary embedding of the rows of ``x`` (T, d) at positions 0..T-1, as
+    HF DeepSeek-V3 applies it: with ``interleaved`` the d features are
+    first de-interleaved (even ones, then odd ones); then ``x * cos +
+    rotate_half(x) * sin`` with frequencies ``theta ** (-2i / d)``."""
+    T, d = x.shape
+    if interleaved:
+        x = x.reshape(T, d // 2, 2).transpose(0, 2, 1).reshape(T, d)
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    f = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([f, f], axis=-1)
+    rot = jnp.concatenate([-x[:, d // 2:], x[:, : d // 2]], axis=-1)
+    return x * jnp.cos(ang) + rot * jnp.sin(ang)
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "scale", "held"))
+def _route(logits, bias, top_k, scale, held):
+    s = _sigmoid(logits)
+    _, top = jax.lax.top_k(s + bias, top_k)
+    w = jnp.take_along_axis(s, top, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    rows = jnp.arange(s.shape[0])[:, None]
+    full = jnp.zeros_like(s).at[rows, top].set(w)
+    return full[:, held[0]:held[1]]
+
+
+def _moe_route(logits, bias, top_k, scale, held):
+    """DeepSeek-V3's ``noaux_tc`` router with one group: sigmoid scores;
+    the experts chosen by top-k of score + correction bias; their scores
+    normalised to sum 1 and multiplied by ``scale``. Returns the combine
+    weights of the experts ``held``, zero where an expert was not chosen,
+    on the host (the gathers and the scatter read them there)."""
+    return np.asarray(_route(logits, bias, top_k, scale, tuple(held)))
+
+
+def routed_rows(w, expert: int) -> np.ndarray:
+    """Indices of the tokens routed to held expert column ``expert``: the
+    rows whose combine weight there is not zero."""
+    return np.flatnonzero(np.asarray(w)[:, expert])
+
+
+# The row movement of MoE dispatch runs in numpy: its shapes follow the
+# routing, and an eager JAX op would compile anew for every row count.
+
+
+def _moe_gather(x, w, expert: int, pad_to: int) -> np.ndarray:
+    """The rows of ``x`` routed to held expert column ``expert``, then zero
+    rows up to a multiple of ``pad_to``."""
+    rows = np.asarray(x, np.float32)[routed_rows(w, expert)]
+    pad = -rows.shape[0] % pad_to
+    if pad:
+        rows = np.concatenate([rows, np.zeros((pad,) + rows.shape[1:], np.float32)])
+    return rows
+
+
+def _moe_combine(w, ys) -> np.ndarray:
+    """Each held expert's rows (padding after them dropped) weighted by
+    their combine weights and added at their tokens, expert by expert."""
+    w = np.asarray(w, np.float32)
+    out = np.zeros((w.shape[0], np.shape(ys[0])[-1]), np.float32)
+    for e, y in enumerate(ys):
+        idx = routed_rows(w, e)
+        out[idx] += np.asarray(y, np.float32)[: len(idx)] * w[idx, e][:, None]
+    return out
 
 
 def _fasr_pool(x, kind):
@@ -602,8 +766,31 @@ def _eval(x: Expr, rec, env):
         return _lstm_cell(*a)[0]
     if op == "lstm" or op == "fasr_lstm":
         return _lstm(*a)
+    if op == "attention" and x.attr("causal"):
+        return _attention_causal(*a)
     if op == "attention" or op == "fasr_attention":
         return _attention(*a)
+    if op == "slice":
+        ax = x.attr("axis") % a[0].ndim
+        idx = [slice(None)] * a[0].ndim
+        idx[ax] = slice(x.attr("begin"), x.attr("end"))
+        return a[0][tuple(idx)]
+    if op == "rms_norm":
+        return _rms_norm(a[0], a[1], x.attr("eps"))
+    if op == "rope":
+        return _rope(a[0], x.attr("theta"), x.attr("interleaved", False))
+    if op == "embedding":
+        return a[0][np.asarray(a[1]).astype(np.int32)]
+    if op == "moe_route":
+        with TELEMETRY.span("moe.route"):
+            return _moe_route(a[0], a[1], x.attr("top_k"), x.attr("scale"),
+                              x.attr("held"))
+    if op == "moe_gather":
+        with TELEMETRY.span("moe.dispatch", op=op):
+            return _moe_gather(a[0], a[1], x.attr("expert"), x.attr("rows", 1))
+    if op == "moe_combine":
+        with TELEMETRY.span("moe.dispatch", op=op):
+            return _moe_combine(a[0], a[1:])
     if op == "fasr_linear":
         return a[0] @ a[1].T + a[2]
     if op in ("fasr_store", "fasr_load", "vta_store", "vta_load"):
@@ -660,6 +847,13 @@ def accelerator_calls(e: Expr) -> Dict[str, int]:
                 out[t] += 1
     return out
 
+
+#: host ops the Executor counts: op -> (counter name, fn(call, operand
+#: values) -> the count for one sample)
+ROW_COUNTERS: Dict[str, Tuple[str, Callable]] = {
+    "moe_gather": ("moe.routed_rows",
+                   lambda x, a: len(routed_rows(a[1], x.attr("expert")))),
+}
 
 # Bundled intrinsic -> target attribution (pass-through fasr_store/fasr_load
 # deliberately absent: data movement is not an invocation).

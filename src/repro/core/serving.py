@@ -252,6 +252,8 @@ class CosimServer:
         # view). Counters/gauges are always on — they replace the previous
         # ad-hoc dict accounting at the same cost; spans are enabled-gated.
         self.metrics = TELEMETRY.attach(MetricsRegistry(scope="serving"))
+        # the work served (tile invocations, routed rows) counts here too
+        self.executor.work_metrics = self.metrics
         self._m_served = self.metrics.counter("serving.served")
         self._m_batches = self.metrics.counter("serving.batches")
         self._m_submitted = self.metrics.counter("serving.submitted")

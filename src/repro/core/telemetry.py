@@ -50,7 +50,9 @@ CI schema step; see ``docs/observability.md``):
                                  by dots; the first segment is the owning
                                  layer (``serving``, ``pipeline``,
                                  ``executor``, ``fragments``,
-                                 ``campaign``, ``telemetry``); unit
+                                 ``campaign``, ``telemetry``, and the
+                                 workload's own: ``flexasr``,
+                                 ``moe``); unit
                                  suffixes ``_s``/``_ms``/``_us``/
                                  ``_cycles``/``_ratio`` where applicable.
 
@@ -69,7 +71,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 #: the documented metric/span naming convention (docs/observability.md)
 NAME_LAYERS = ("serving", "pipeline", "executor", "fragments", "campaign",
-               "telemetry")
+               "telemetry", "flexasr", "moe")
 NAME_RE = re.compile(
     r"^(" + "|".join(NAME_LAYERS) + r")\.[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)*$"
 )

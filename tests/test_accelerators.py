@@ -294,3 +294,65 @@ class TestHLSCNN:
         ref = np.asarray(ir._conv2d(jnp.asarray(x), jnp.asarray(w), (2, 2), (1, 1)))
         assert out.shape == ref.shape
         assert validate.frob_rel_err(ref, out) < 0.02
+
+
+# ---- capacity guards: a matched op is one its planner can run ----------------
+
+
+def _offloads(expr):
+    from repro.core.compile import compile_program
+
+    prog = compile_program(expr).program
+    ops = {}
+    for n in ir.postorder(prog):
+        if isinstance(n, ir.Call) and ir.accel_op_target(n.op):
+            ops[n.op] = ops.get(n.op, 0) + 1
+    return prog, ops
+
+
+@pytest.mark.parametrize("biased", [True, False])
+def test_wide_k_dense_is_not_vta_gemm_and_runs_tiled(biased):
+    """K = 2048 is 128 VTA tiles, past the 64 the input SRAM holds: the
+    dense never matches ``vta_gemm`` and runs as a tiled FlexASR linear."""
+    from repro.core.codegen import Executor
+
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((8, 2048)).astype(np.float32)
+    w = (rng.standard_normal((40, 2048)) * 0.02).astype(np.float32)
+    b = (rng.standard_normal(40) * 0.1).astype(np.float32)
+    d = ir.dense(ir.Var("x", (8, 2048)), ir.Var("w", (40, 2048)))
+    expr = ir.bias_add(d, ir.Var("b", (40,))) if biased else d
+    prog, ops = _offloads(expr)
+    assert ops == {"fasr_linear": 1}
+    env = dict(x=x, w=w, b=b)
+    (out,) = Executor("ila", engine="pipelined").run_many(prog, [env])
+    want = x @ w.T + (b if biased else 0)
+    assert np.linalg.norm(np.asarray(out) - want) / np.linalg.norm(want) < 0.05
+
+
+def test_vta_guards_admit_what_their_planners_hold():
+    """K = 1024 still fits one row tile's input SRAM; an add of rows wider
+    than 512 (2 * 32 accumulator tiles) and a relu wider than 1024 stay on
+    the host."""
+    _, ops = _offloads(ir.dense(ir.Var("x", (4, 1024)), ir.Var("w", (8, 1024))))
+    assert ops == {"vta_gemm": 1}
+    a, b = ir.Var("a", (4, 2048)), ir.Var("b", (4, 2048))
+    assert _offloads(ir.add(a, b))[1] == {}
+    assert _offloads(ir.add(ir.Var("a", (4, 512)), ir.Var("b", (4, 512))))[1] == {"vta_add": 1}
+    assert _offloads(ir.call("relu", ir.Var("r", (4, 1040))))[1] == {}
+
+
+def test_vta_gemm_chunks_wide_outputs_to_fit_its_accumulators():
+    """Small K with many rows and outputs: the planner keeps every chunk
+    within the accumulators and DRAM (this used to assert), exactly."""
+    rng = np.random.default_rng(1)
+    a = rng.integers(-50, 50, (300, 16)).astype(np.float32)
+    b = rng.integers(-50, 50, (300, 16)).astype(np.float32)
+    ctx = PlanContext(record=lambda *args, **kw: None)
+    jobs, asm = vt.plan_gemm(ctx, None, [a, b])
+    out = asm([np.asarray(j.read(vt.vta.run_data(j.data, state=j.frag.setup_state())))[j.window]
+               for j in jobs])
+    sa = np.abs(a).max() / 127.0
+    sb = np.abs(b).max() / 127.0
+    a8, b8 = np.clip(np.round(a / sa), -127, 127), np.clip(np.round(b / sb), -127, 127)
+    np.testing.assert_allclose(out, (a8 @ b8.T) * sa * sb, rtol=1e-5)

@@ -153,12 +153,18 @@ class TestEGraph:
         assert "resolved" in msg
 
     def test_guard_blocks_oversized_linear(self):
-        # feature dim beyond FlexASR SRAM must NOT map to fasr_linear
+        # a linear beyond FlexASR's 128-wide SRAM maps to fasr_linear, which
+        # the planner tiles; attention wider than one invocation, which no
+        # planner tiles, is still blocked
         a = ir.Var("a", (4, 512))
         b = ir.Var("b", (512, 512))
         c = ir.Var("c", (512,))
         prog = ir.bias_add(ir.dense(a, b), c)
         res = compile_program(prog, targets=("flexasr",), flexible=False)
+        assert res.accelerator_calls["flexasr"] == 1
+        q = ir.Var("q", (8, 192))
+        res = compile_program(ir.call("attention", q, q, q), targets=("flexasr",),
+                              flexible=False)
         assert res.accelerator_calls["flexasr"] == 0
 
 

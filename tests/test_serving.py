@@ -307,6 +307,8 @@ def test_traced_server_spans_its_host_work():
     packs = [s["args"] for s in first if s["name"] == "pipeline.pack"]
     assert packs and all(a["op"] == "fasr_linear" for a in packs)
     evals = {s["args"]["op"] for s in first if s["name"] == "executor.host_eval"}
-    assert evals >= {"var", "relu", "fasr_linear.operands"}
+    # program inputs an accelerator call takes come from the environments
+    # as they are: no host evaluation (no device round trip) of them
+    assert evals >= {"relu", "fasr_linear.operands"} and "var" not in evals
     assert not [s for s in again if s["name"] == "executor.compile"
                 and s["args"]["kind"] == "data_runner"]

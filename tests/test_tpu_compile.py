@@ -146,3 +146,20 @@ def test_fused_conv_pallas_dispatch_compiles_over_stream_mesh(stream_mesh4):
         b((BATCH, hc.MAX_H, hc.MAX_W, hc.MAX_C)), s((128, hc.KPAD)),
         s((hc.MAX_H,)), s((hc.MAX_W,)), s((hc.MAX_C,)),
     )
+
+
+def test_tiled_linear_runner_compiles_at_moonlight_widths(chip):
+    """The tiled FlexASR linear's runner (plain XLA, the ILA's own data
+    path per tile invocation) at a Moonlight-16B-A3B width: the dense MLP's
+    down projection, 11264 -> 2048, over a served request of 2 windows of
+    256 tokens. FN_START's mode switch folds away (the mode is constant)."""
+    O, I, B, C = 2048, 11264, 2, 2
+    ot, kt = -(-O // fa.TILE), -(-I // fa.TILE)
+    n = ot * kt
+    compiled = fa._tiled_run.lower(
+        chip((n, fa.TILE, fa.TILE)), chip((n, fa.TILE)), chip((n,)), chip((n,)),
+        chip((n,)), chip((B, C, kt, fa.TILE, fa.TILE)), chip((B, C, kt)),
+        chip((B, C)), ot=ot, kt=kt, O=O).compile()
+    assert "conditional(" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2e9
