@@ -20,6 +20,7 @@ integer inputs (Table 2 row 1: 0.00% error).
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Tuple
 
 import jax
@@ -272,17 +273,28 @@ def pack_gemm_data(frag: CompiledFragment, a_int8: np.ndarray, requant_shift: in
     return DataStream([bulk], _cmd_stream(entries))
 
 
-def read_gemm_full(frag: CompiledFragment):
-    """Vmap-safe fixed-shape read of the whole output region: (mt*T, nt*T);
-    callers slice the valid [:M, :N] window."""
-    m = frag.meta
-    mt, nt, out_base = m["mt"], m["nt"], m["out_base"]
+@functools.lru_cache(maxsize=None)
+def _tile_reader(rows: int, cols: int, out_base: int):
+    """The read of a ``rows x cols``-tile output region stored from DRAM
+    tile ``out_base``, as one (rows*T, cols*T) array. One function object
+    per geometry: the Executor keys its jitted reads by the function, so a
+    per-call closure would compile a fresh read every invocation. The
+    geometry space is finite (``rows * cols <= N_ACC``, ``out_base <
+    DRAM_TILES``), so the cache needs no bound."""
 
     def read(st):
-        region = st["dram"][out_base * T : (out_base + mt * nt) * T]
-        return region.reshape(mt, nt, T, T).transpose(0, 2, 1, 3).reshape(mt * T, nt * T)
+        region = st["dram"][out_base * T : (out_base + rows * cols) * T]
+        return region.reshape(rows, cols, T, T).transpose(0, 2, 1, 3).reshape(rows * T, cols * T)
 
     return read
+
+
+def read_gemm_full(frag: CompiledFragment):
+    """Vmap-safe fixed-shape read of the whole output region: (mt*T, nt*T);
+    callers slice the valid [:M, :N] window. The same function for every
+    fragment of the same tile layout."""
+    m = frag.meta
+    return _tile_reader(m["mt"], m["nt"], m["out_base"])
 
 
 def build_gemm_fragment(a_int8: np.ndarray, b_int8: np.ndarray, requant_shift: int = 0):
@@ -345,15 +357,10 @@ def pack_alu_data(frag: CompiledFragment, a_int: np.ndarray, b_int=None) -> Data
 
 
 def read_alu_full(frag: CompiledFragment):
-    """Vmap-safe read of the whole (rt*T, ct*T) output; slice [:R, :C]."""
+    """Vmap-safe read of the whole (rt*T, ct*T) output; slice [:R, :C].
+    The same function for every fragment of the same tile layout."""
     m = frag.meta
-    rt, ct, out_base = m["rt"], m["ct"], m["out_base"]
-
-    def read(st):
-        region = st["dram"][out_base * T : (out_base + rt * ct) * T]
-        return region.reshape(rt, ct, T, T).transpose(0, 2, 1, 3).reshape(rt * T, ct * T)
-
-    return read
+    return _tile_reader(m["rt"], m["ct"], m["out_base"])
 
 
 def _build_alu_fragment(kind, a_int, b_int=None):

@@ -435,10 +435,11 @@ class Executor:
         #: pipelined engine packs chunk k+1 while chunk k simulates)
         self.pipeline_chunk = max(1, int(pipeline_chunk))
         self.stats: List[InvocationStat] = []
-        #: jit(vmap(read)) per read fn — re-vmapping per group call would
-        #: re-trace on the dispatch thread every time (holds a ref to the
-        #: read fn so the id key cannot be recycled)
-        self._batched_reads: Dict[int, Tuple[Callable, Callable]] = {}
+        #: jitted reads keyed by (read fn, batched): jit(vmap(read)) for a
+        #: group's stacked states, jit(read) for a lone job's. Keyed by the
+        #: function itself, so a planner must hand out one read object per
+        #: output layout (a fresh closure per job compiles every call)
+        self._batched_reads: Dict[Tuple[Callable, bool], Callable] = {}
         #: per-group wall-clock records feeding CostModel.calibrate_from_timings
         self.group_timings: List[GroupTiming] = []
         #: this executor's scoped metrics registry — the single source of
@@ -452,6 +453,8 @@ class Executor:
             for k in ("pack_s", "dispatch_s", "readback_s")
         }
         self._groups_ctr = self.metrics.counter("pipeline.groups")
+        self._read_hits = self.metrics.counter("executor.read_cache.hits")
+        self._read_misses = self.metrics.counter("executor.read_cache.misses")
         #: where workload counters go (tile invocations planned, rows routed
         #: to held experts): this registry, or the one of a serving front end
         self.work_metrics = self.metrics
@@ -922,7 +925,7 @@ class Executor:
                             lambda g=group, w=j.window: g.materialize()[0][w]
                         )
                     else:
-                        out = read(frag.run(j.data))
+                        out = self._read(read, frag.run(j.data), False, frag.ila.name)
                         group = _GroupResult(out)
                         handles[idxs[0]] = (
                             lambda g=group, w=j.window: g.materialize()[w]
@@ -959,15 +962,7 @@ class Executor:
                         fulls = runner.dispatch(prepared[1])
                     else:
                         sts = frag.run_prepared(prepared)
-                        entry = self._batched_reads.get(id(read))
-                        if entry is not None:
-                            fulls = entry[1](sts)
-                        else:
-                            ila = frag.ila.name
-                            entry = (read, jax.jit(named(jax.vmap(read), f"{ila}_read")))
-                            self._batched_reads[id(read)] = entry
-                            with TELEMETRY.span("executor.compile", kind="read", ila=ila):
-                                fulls = entry[1](sts)
+                        fulls = self._read(read, sts, True, frag.ila.name)
                     group = _GroupResult(fulls)
                     for bi, i in enumerate(idxs):
                         handles[i] = (
@@ -994,6 +989,21 @@ class Executor:
                 sp.set(device=dev_name, est_cycles=round(grp_cycles, 1))
         self._stage["dispatch_s"].inc(time.perf_counter() - t_disp)
         return handles
+
+    def _read(self, read: Callable, st, batched: bool, ila: str):
+        """``read`` applied to a simulated state (a group's stacked states
+        when ``batched``) through one cached jit per read function: a miss
+        builds it and its first call compiles, inside an
+        ``executor.compile`` span."""
+        fn = self._batched_reads.get((read, batched))
+        if fn is not None:
+            self._read_hits.inc()
+            return fn(st)
+        self._read_misses.inc()
+        fn = jax.jit(named(jax.vmap(read) if batched else read, f"{ila}_read"))
+        self._batched_reads[(read, batched)] = fn
+        with TELEMETRY.span("executor.compile", kind="read", ila=ila):
+            return fn(st)
 
     def _execute_jobs(self, jobs: List[SimJob], op: str = "") -> List[np.ndarray]:
         """Run simulation jobs to completion. The compiled engine executes

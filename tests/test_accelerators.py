@@ -272,6 +272,44 @@ class TestVTA:
         # acc = 64*2*4 = 512; >>4 = 32
         np.testing.assert_array_equal(out, np.full((4, 4), 32.0))
 
+    @pytest.mark.parametrize("planner,n_args,rows_keys", [
+        (vt.plan_gemm, 2, ("mt", "nt")),
+        (vt.plan_add, 2, ("rt", "ct")),
+        (vt.plan_relu, 1, ("rt", "ct")),
+    ], ids=["gemm", "add", "relu"])
+    def test_planner_reads_are_shared_per_tile_layout(self, planner, n_args, rows_keys):
+        """Two planner calls on operands of one shape hand out the same read
+        object (the Executor's jitted reads are keyed by it); another tile
+        layout gets its own; and the shared read returns, bit for bit, what
+        a read built for the one fragment alone returns."""
+        ctx = PlanContext(record=lambda *args, **kw: None)
+        r = np.random.default_rng(3)
+
+        def plan(rows):
+            args = [r.integers(-50, 50, (rows, 40)).astype(np.float32)
+                    for _ in range(n_args)]
+            return planner(ctx, None, args)[0]
+
+        first, again, other = plan(20), plan(20), plan(36)
+        assert first[0].read is again[0].read
+        assert other[0].read is not first[0].read
+
+        def own_read(frag):
+            rows, cols = (frag.meta[k] for k in rows_keys)
+            base = frag.meta["out_base"]
+
+            def read(st):
+                region = st["dram"][base * vt.T : (base + rows * cols) * vt.T]
+                return region.reshape(rows, cols, vt.T, vt.T).transpose(0, 2, 1, 3) \
+                    .reshape(rows * vt.T, cols * vt.T)
+
+            return read
+
+        for j in (*again, *other):
+            st = vt.vta.run_data(j.data, state=j.frag.setup_state())
+            np.testing.assert_array_equal(np.asarray(j.read(st)),
+                                          np.asarray(own_read(j.frag)(st)))
+
 
 class TestHLSCNN:
     def test_conv_8bit_much_worse_than_16bit(self):

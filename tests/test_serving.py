@@ -273,7 +273,7 @@ def test_traced_server_spans_its_host_work():
     evaluation of the nodes not offloaded, accuracy statistics, and one
     ``executor.compile`` span per jitted runner or batched read it creates
     (a shape no other test here uses, so its runners are new). An
-    identical request on the warm server compiles no data runner."""
+    identical request on the warm server compiles nothing."""
     from repro.core.telemetry import TELEMETRY
 
     srv = CosimServer(engine="pipelined", pipeline_chunk=2, seed=0)
@@ -310,5 +310,59 @@ def test_traced_server_spans_its_host_work():
     # program inputs an accelerator call takes come from the environments
     # as they are: no host evaluation (no device round trip) of them
     assert evals >= {"relu", "fasr_linear.operands"} and "var" not in evals
-    assert not [s for s in again if s["name"] == "executor.compile"
-                and s["args"]["kind"] == "data_runner"]
+    assert not [s for s in again if s["name"] == "executor.compile"]
+
+
+def _read_cache(srv):
+    m = srv.executor.metrics
+    return tuple(m.find(f"executor.read_cache.{k}")[0].value
+                 for k in ("hits", "misses"))
+
+
+def test_warm_server_reuses_vta_reads():
+    """A program that offloads a VTA relu: once warm, the server serves
+    identical requests with no ``executor.compile`` span of any kind (VTA's
+    reads are one function per tile layout, so the executor's jitted read
+    is found again), its jitted reads stay as many as they were, and the
+    read cache counts hits and no new misses."""
+    from repro.core.telemetry import TELEMETRY
+
+    rng = np.random.default_rng(7)
+    w = (rng.standard_normal((20, 16)) * 0.1).astype(np.float32)
+    b = (rng.standard_normal((20,)) * 0.1).astype(np.float32)
+    expr = ir.call(
+        "vta_relu",
+        ir.call("fasr_linear", ir.Var("x", (4, 16)), ir.Var("w", w.shape),
+                ir.Var("b", b.shape)),
+    )
+    srv = CosimServer(engine="pipelined", pipeline_chunk=2, seed=0)
+    srv.add_program("vta", expr, {"w": w, "b": b})
+    # 3 samples in chunks of 2: a batched group and a lone job per node
+    envs = [dict(x=rng.standard_normal((4, 16)).astype(np.float32), w=w, b=b)
+            for _ in range(3)]
+    srv.start(warmup=0)
+    TELEMETRY.enable()
+    TELEMETRY.reset()
+    try:
+        first = srv.submit("vta", envs=envs).result(timeout=300)
+        compiled = [s["args"] for s in TELEMETRY.spans()
+                    if s["name"] == "executor.compile"]
+        TELEMETRY.reset()
+        again = srv.submit("vta", envs=envs).result(timeout=300)
+        recompiled = [s for s in TELEMETRY.spans() if s["name"] == "executor.compile"]
+        reads, (hits, misses) = len(srv.executor._batched_reads), _read_cache(srv)
+        for _ in range(5):
+            srv.submit("vta", envs=envs).result(timeout=300)
+        reads_after, (hits_after, misses_after) = \
+            len(srv.executor._batched_reads), _read_cache(srv)
+    finally:
+        TELEMETRY.disable()
+        TELEMETRY.reset()
+        srv.close()
+    # one jit(vmap(read)) for the pair, one jit(read) for the lone job
+    assert sum(c["ila"] == "vta" and c["kind"] == "read" for c in compiled) == 2
+    assert not recompiled
+    for a, b in zip(first, again):
+        np.testing.assert_array_equal(a, b)
+    assert reads_after == reads
+    assert misses_after == misses and hits_after > hits
