@@ -59,7 +59,7 @@ def run():
         targets=("flexasr", "vecunit", "hlscnn"),
         faults=("identity", "decode_alias", "cmd_reorder"),
         apps=(),                      # protocol-fault ladder cost only
-        engine="compiled", devices_per_target=1,
+        engine="pipelined", devices_per_target=1,
         op_samples=1, vt2_n=2, stat_calib_seeds=0,
     )
     print("\n== protocol-fault ladder cost, escalate (tier-0 skips) "

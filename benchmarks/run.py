@@ -1,9 +1,8 @@
 """Benchmark driver: one function per paper table + framework benches.
 
 Prints ``name,us_per_call,derived`` CSV (plus human-readable tables) and
-writes the machine-readable ``BENCH_cosim.json`` (see benchmarks/_bench_io)
-so the co-sim perf trajectory — steady-state throughput, cold-vs-warm,
-pipelined-vs-sync speedup, batch crossover — is tracked across PRs.
+writes the machine-readable ``BENCH_cosim.json`` (see benchmarks/_bench_io).
+The co-sim's serving speed is measured on the chip by ``bench/``.
 Usage: PYTHONPATH=src python -m benchmarks.run [--fast]
 """
 from __future__ import annotations
@@ -23,7 +22,7 @@ def main() -> None:
 
     enable_compile_cache()
     from benchmarks import (bench_campaign, bench_extraction, bench_kernels,
-                            bench_sim_speed, roofline_report, table1_matching,
+                            roofline_report, table1_matching,
                             table2_mapping_validation, table3_formal,
                             table4_cosim)
 
@@ -32,7 +31,6 @@ def main() -> None:
     rows += bench_extraction.run()
     rows += table2_mapping_validation.run()
     rows += table3_formal.run()
-    rows += bench_sim_speed.run()
     rows += bench_campaign.run()
     rows += bench_kernels.run()
     rows += roofline_report.run()

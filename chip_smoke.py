@@ -123,7 +123,7 @@ def compare(ref: dict, got: dict):
 
 def cpu_replay(server, name, program, ids):
     """(e): the same request ids, operands rebuilt by request_envs, on the
-    host CPU backend with the compiled engine (the bit-exactness oracle)."""
+    host CPU backend with the jit engine (a bit-exactness reference)."""
     import jax
 
     from repro.core.codegen import Executor
@@ -131,7 +131,7 @@ def cpu_replay(server, name, program, ids):
     cpu = jax.devices("cpu")[0]
     outs = {}
     with jax.default_device(cpu):
-        ex = Executor("ila", engine="compiled")
+        ex = Executor("ila", engine="jit")
         for rid in ids:
             got = ex.run_many(program, server.request_envs(name.lower(), rid,
                                                            BATCH))

@@ -29,7 +29,6 @@ covers every declared intrinsic — a fourth backend needs zero edits to
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -77,30 +76,6 @@ class PlanContext:
     @staticmethod
     def ncmds(jobs: Sequence[SimJob]) -> int:
         return sum(len(j.frag.setup) + len(j.data) for j in jobs)
-
-    @staticmethod
-    def data_ncmds(jobs: Sequence[SimJob]) -> int:
-        """Per-invocation (steady-state) command count: the data streams
-        only, excluding the cached setup load. This is the volume the
-        pipelined engine's pack and sim stages both scale with, and what
-        :class:`GroupTiming` records for latency calibration."""
-        return sum(len(j.data) for j in jobs)
-
-
-@dataclasses.dataclass
-class GroupTiming:
-    """Measured wall-clock of one scheduled SimJob group, recorded by the
-    Executor: ``pack_s`` is the host stage (planner packing, vectorized
-    numpy), ``sim_s`` the dispatch-to-materialization stage (a synchronous
-    engine times it exactly; the pipelined engine leaves it 0 because sims
-    overlap). ``CostModel.calibrate_from_timings`` fits per-stage latency
-    models from these."""
-
-    target: str
-    n_jobs: int
-    n_commands: int
-    pack_s: float = 0.0
-    sim_s: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,58 +129,6 @@ class CostModel:
         #: per-op multiplicative correction on the predicted command count,
         #: fitted by :meth:`calibrate` (1.0 = uncalibrated analytic model)
         self.command_scale: Dict[str, float] = {}
-        #: wall-clock latency model fitted by :meth:`calibrate_from_timings`
-        #: (empty = uncalibrated; keys: ``{pack,sim}_us_per_command``,
-        #: ``{pack,sim}_overhead_us``, ``n_groups``). Once fitted, one
-        #: "cycle" of this model means one microsecond of measured latency.
-        self.latency: Dict[str, float] = {}
-        #: streaming predicted-vs-actual drift accumulators fed by
-        #: :meth:`record_drift` (count / log-ratio sums / extremes)
-        self._drift = [0, 0.0, 0.0, float("inf"), float("-inf")]
-
-    def record_drift(self, predicted_cycles: float, actual_us: float) -> None:
-        """One drift observation: the scheduler priced a group at
-        ``predicted_cycles`` and its simulation measured ``actual_us``.
-        On a latency-calibrated model (1 cycle == 1 us) the ratio
-        ``actual / predicted`` is the mispricing factor the admission
-        controller and LPT placement are operating under; before
-        calibration it is the analytic-to-wall-clock conversion. Ratios
-        accumulate in log space so over- and under-prediction average
-        symmetrically."""
-        if predicted_cycles <= 0 or actual_us <= 0:
-            return
-        r = float(actual_us) / float(predicted_cycles)
-        lr = math.log(r)
-        d = self._drift
-        d[0] += 1
-        d[1] += lr
-        d[2] += lr * lr
-        d[3] = min(d[3], r)
-        d[4] = max(d[4], r)
-
-    def drift_summary(self) -> Optional[Dict[str, float]]:
-        """Aggregate predicted-vs-actual drift: geometric-mean ratio of
-        actual microseconds to predicted cycles, its log-space spread, and
-        the extremes. None until :meth:`record_drift` has observations.
-        A calibrated model tracking reality sits near ``ratio_geomean``
-        1.0; a drifting one is the signal to re-run
-        ``calibrate_from_timings``."""
-        n, s, s2, lo, hi = self._drift
-        if n == 0:
-            return None
-        mean = s / n
-        var = max(0.0, s2 / n - mean * mean)
-        return {
-            "n": float(n),
-            "ratio_geomean": math.exp(mean),
-            "log_ratio_std": math.sqrt(var),
-            "ratio_min": lo,
-            "ratio_max": hi,
-            "calibrated": 1.0 if self.latency else 0.0,
-        }
-
-    def reset_drift(self) -> None:
-        self._drift = [0, 0.0, 0.0, float("inf"), float("-inf")]
 
     def op(self, name: str):
         """Decorator registering the pricing rule for intrinsic ``name``."""
@@ -232,84 +155,11 @@ class CostModel:
         cycles = self.cycles_per_command * commands + float(compute)
         return CostEstimate(commands, float(nbytes), cycles, float(raw))
 
-    def job_cycles(self, n_commands: float, pipelined: bool = False) -> float:
+    def job_cycles(self, n_commands: float) -> float:
         """Scheduler estimate for a SimJob batch of ``n_commands`` interface
         commands (the compute term is already proportional to the data
-        stream for every bundled fragment, so commands dominate ranking).
-
-        With a fitted :attr:`latency` model the estimate is measured
-        microseconds. ``pipelined=True`` prices the group for a pipelined
-        engine, where host packing overlaps device simulation: the group
-        occupies the pipeline for ``max(pack, sim)`` rather than their sum
-        (sum without overlap). Uncalibrated models have no pack term, so
-        both forms reduce to the analytic ``cycles_per_command * n``.
-        """
-        n = float(n_commands)
-        if self.latency:
-            sim = (
-                self.latency.get("sim_us_per_command", self.cycles_per_command) * n
-                + self.latency.get("sim_overhead_us", 0.0)
-            )
-            pack = (
-                self.latency.get("pack_us_per_command", 0.0) * n
-                + self.latency.get("pack_overhead_us", 0.0)
-            )
-            return max(pack, sim) if pipelined else pack + sim
-        return self.cycles_per_command * n
-
-    def calibrate_from_timings(self, timings) -> Dict[str, float]:
-        """Fit the wall-clock latency model from measured per-group timings
-        (:class:`GroupTiming`, recorded in ``Executor.stats``-side logs).
-
-        Each stage (host pack, device sim) is fitted as an affine model
-        ``seconds ~= overhead + s_per_command * n_commands`` by least
-        squares over this target's groups; negative slopes/intercepts from
-        degenerate samples are clamped to a through-origin ratio fit. The
-        fit lives in :attr:`latency` — the measured-latency replacement for
-        the analytic per-command cost — and ``job_cycles`` switches to it
-        (in microseconds: **1 cycle == 1 us** once fitted), so the
-        scheduler ranks groups by measured latency (the ROADMAP's learned
-        cost-model step) and the pipelined scheduler prices groups as
-        ``max(pack, sim)``. :attr:`cycles_per_command` itself is left in
-        analytic units on purpose: ``estimate()`` feeds *extraction*, which
-        compares costs across targets, and rescaling one target's cycles to
-        microseconds while competitors stay analytic would make those
-        comparisons incommensurate. Returns the fitted model (empty if this
-        target has no usable timings yet).
-        """
-
-        def affine(pts: List[Tuple[float, float]]) -> Optional[Tuple[float, float]]:
-            if not pts:
-                return None
-            xs = np.asarray([p[0] for p in pts], np.float64)
-            ys = np.asarray([p[1] for p in pts], np.float64)
-            if len(pts) >= 2 and float(np.ptp(xs)) > 0:
-                slope, intercept = np.polyfit(xs, ys, 1)
-                if slope > 0 and intercept >= 0:
-                    return float(slope), float(intercept)
-            return float(ys.sum() / xs.sum()), 0.0
-
-        sims, packs = [], []
-        for t in timings:
-            if t.target != self.target or t.n_commands <= 0:
-                continue
-            if t.sim_s > 0:
-                sims.append((float(t.n_commands), t.sim_s))
-            if t.pack_s > 0:
-                packs.append((float(t.n_commands), t.pack_s))
-        sim_fit, pack_fit = affine(sims), affine(packs)
-        if sim_fit is not None:
-            self.latency["sim_us_per_command"] = sim_fit[0] * 1e6
-            self.latency["sim_overhead_us"] = sim_fit[1] * 1e6
-        if pack_fit is not None:
-            self.latency["pack_us_per_command"] = pack_fit[0] * 1e6
-            self.latency["pack_overhead_us"] = pack_fit[1] * 1e6
-        if sim_fit is not None or pack_fit is not None:
-            self.latency["n_groups"] = float(len(sims) + len(packs))
-            # pricing just changed: drift observed under the old model no
-            # longer measures this model's error
-            self.reset_drift()
-        return dict(self.latency)
+        stream for every bundled fragment, so commands dominate ranking)."""
+        return self.cycles_per_command * float(n_commands)
 
     def calibrate(self, stats) -> Dict[str, float]:
         """Fit per-op command-count scales from ``Executor.stats``.

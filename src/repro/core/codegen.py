@@ -22,28 +22,25 @@ accelerator therefore never touches this file.
 Execution engine
 ----------------
 
-``engine="compiled"`` (default) routes every accelerator invocation through
-the fragment-compiler fast path of :mod:`..core.ila`: each op is *planned*
-into simulation jobs (CompiledFragment + per-sample DataStream + output
-window), jobs sharing a fragment and stream signature are batched through
-one ``vmap``-ed simulator call, and fragment setup (weight load) is
+``engine="pipelined"`` (default) routes every accelerator invocation
+through the fragment-compiler tier of :mod:`..core.ila`: each op is
+*planned* into simulation jobs (CompiledFragment + per-sample DataStream +
+output window), jobs sharing a fragment and stream signature are batched
+through one ``vmap``-ed simulator call, and fragment setup (weight load) is
 simulated once per parameter set and cached in the owning target's
-fragment cache. Minibatched evaluation flows through :meth:`Executor.run_many`.
+fragment cache. Dispatch is asynchronous: host packing (planner calls +
+batch stacking, vectorized numpy that releases the GIL) runs in a pack
+worker thread for chunk *k+1* while the main thread dispatches JAX
+simulation of chunk *k* (JAX dispatch is async, so readback of chunk *k-1*
+overlaps both), and results materialize only at ``assemble()`` barriers,
+in submission order — results and stat order are deterministic.
+Minibatched evaluation flows through :meth:`Executor.run_many`;
+:meth:`Executor.submit_many` also defers a request's readback barrier for
+the serving front end.
 
 ``engine="jit"`` re-derives and scans the full command stream per invocation
 (the pre-fragment-compiler behavior); ``engine="eager"`` interprets commands
-one by one. Both exist as bit-exact references for the compiled path.
-
-``engine="pipelined"`` layers an asynchronous dispatch pipeline on top of
-the compiled path: host packing (planner calls + batch stacking, vectorized
-numpy that releases the GIL) runs in a pack worker thread for chunk *k+1*
-while the main thread dispatches JAX simulation of chunk *k* (JAX dispatch
-is async, so readback of chunk *k-1* overlaps both), and results
-materialize only at ``assemble()`` barriers. Pipelining reorders
-*scheduling* only — per-sample packing, grouping semantics and simulation
-are the compiled engine's, so results stay bit-exact and deterministic
-(materialization and stat recording follow submission order). Set
-``REPRO_ENGINE=pipelined`` to make it the process default.
+one by one. Both exist as bit-exact references for the pipelined engine.
 
 ``engine="fused"`` keeps the pipelined engine's scheduling (pack worker,
 async dispatch, assemble barriers) but, per signature group, consults the
@@ -51,11 +48,13 @@ owning target for a :class:`~repro.core.ila.FusedRunner` — a registered
 fast path that lowers bulk-write + per-sample compute + read-out into one
 fused computation on the stream payloads, skipping architectural-state
 materialization (see ``docs/simulation.md``). Groups without a declared
-runner execute on the compiled path unchanged, so the engine is safe for
-every target; the compiled tier remains the bit-exactness oracle the fused
-tier is conformance-checked against. ``REPRO_ENGINE=fused`` flips the
-process default; ``REPRO_FUSED_FALLBACK=1`` forces runners' XLA-fused
-fallback lowering even where Pallas is available.
+runner execute on the fragment-compiler tier unchanged, so the engine is
+safe for every target; that tier remains the bit-exactness oracle the fused
+tier is conformance-checked against. ``REPRO_FUSED_FALLBACK=1`` forces
+runners' XLA-fused fallback lowering even where Pallas is available.
+
+``REPRO_ENGINE`` names the engine of every Executor constructed without an
+explicit one.
 
 Multi-device scheduling
 -----------------------
@@ -92,13 +91,13 @@ import jax
 import numpy as np
 
 from ..accel.target import (  # importing registers bundled targets
-    CostEstimate, GroupTiming, PlanContext, SimJob,
+    CostEstimate, PlanContext, SimJob,
 )
 from . import ir
 from .ila import TARGETS, CompiledFragment, FragmentCache, named
 from .telemetry import TELEMETRY, MetricsRegistry
 
-ENGINES = ("compiled", "pipelined", "fused", "jit", "eager")
+ENGINES = ("pipelined", "fused", "jit", "eager")
 
 #: process-wide pack worker for the pipelined engine. One thread by design:
 #: numpy packing releases the GIL and overlaps XLA compute, but multiple
@@ -246,7 +245,8 @@ class Submission:
     @property
     def done(self) -> bool:
         """True once :meth:`result` has materialized the outputs (or the
-        submission was created already-complete, e.g. on a sync engine)."""
+        submission was created already-complete, e.g. on a reference
+        engine)."""
         return self._done
 
     def result(self) -> List[Any]:
@@ -271,20 +271,6 @@ class Prepack:
         #: leading accel node -> list of pack-pool futures, one per
         #: pipeline_chunk span, each resolving to (planned, jobs, preps)
         self.spans: Dict[ir.Expr, List[Any]] = {}
-
-
-class _NullDeviceType:
-    """Placement stand-in for fragments of unregistered ILAs (no device
-    pool): index 0 means "setup already cached", so no cold-load term."""
-
-    index = 0
-
-    @staticmethod
-    def is_cold(frag) -> bool:
-        return False
-
-
-_NullDevice = _NullDeviceType()
 
 
 class SimDevice:
@@ -412,7 +398,6 @@ class Executor:
         self,
         mode: str = "ila",
         collect_stats: bool = True,
-        jit_sim: bool = True,
         engine: Optional[str] = None,
         target_options: Optional[Dict[str, Dict[str, Any]]] = None,
         devices_per_target: Union[int, Dict[str, int]] = 1,
@@ -421,14 +406,11 @@ class Executor:
         assert mode in ("ila", "kernel", "ideal")
         self.mode = mode
         self.collect_stats = collect_stats
-        # explicit engine > REPRO_ENGINE env (lets CI/serving flip every
-        # Executor in the process) > jit_sim legacy default
-        self.engine = (
-            engine
-            or os.environ.get("REPRO_ENGINE")
-            or ("compiled" if jit_sim else "eager")
-        )
-        assert self.engine in ENGINES, f"unknown engine {self.engine!r}"
+        # explicit engine > REPRO_ENGINE env (lets CI flip every Executor
+        # in the process) > pipelined
+        self.engine = engine or os.environ.get("REPRO_ENGINE") or "pipelined"
+        if self.engine not in ENGINES:
+            raise ValueError(f"unknown engine {self.engine!r}; one of {ENGINES}")
         self.target_options = {k: dict(v) for k, v in (target_options or {}).items()}
         self.devices = DeviceRegistry(devices_per_target)
         #: samples planned per pack-pipeline stage in ``run_many`` (the
@@ -440,8 +422,6 @@ class Executor:
         #: function itself, so a planner must hand out one read object per
         #: output layout (a fresh closure per job compiles every call)
         self._batched_reads: Dict[Tuple[Callable, bool], Callable] = {}
-        #: per-group wall-clock records feeding CostModel.calibrate_from_timings
-        self.group_timings: List[GroupTiming] = []
         #: this executor's scoped metrics registry — the single source of
         #: truth for stage timers and invocation aggregates; attached to the
         #: process TELEMETRY singleton (weakref) so global snapshots see it
@@ -530,32 +510,10 @@ class Executor:
                 sample_args = _marshal(x, _operands(x, rec, envs), B)
                 if (
                     self.mode == "ila"
-                    and self.engine in ("compiled", "pipelined", "fused")
+                    and self.engine in ("pipelined", "fused")
                     and TARGETS.has_planner(x.op)
                 ):
-                    if self.engine in ("pipelined", "fused"):
-                        v = self._node_pipelined(x, sample_args)
-                    else:
-                        plans, jobs = [], []
-                        tname = TARGETS.intrinsic(x.op)[0].name
-                        with TELEMETRY.span("pipeline.pack", target=tname,
-                                            op=x.op) as sp:
-                            t0 = time.perf_counter()
-                            for s in range(B):
-                                s_jobs, assemble = self._plan(x, sample_args[s])
-                                plans.append((len(jobs), len(s_jobs), assemble))
-                                jobs += s_jobs
-                            dt = time.perf_counter() - t0
-                            sp.set(jobs=len(jobs))
-                        self._stage["pack_s"].inc(dt)
-                        if self.collect_stats:
-                            self._groups_ctr.inc()
-                            self.group_timings.append(GroupTiming(
-                                tname, len(jobs), PlanContext.data_ncmds(jobs),
-                                pack_s=dt,
-                            ))
-                        outs = self._execute_jobs(jobs, x.op)
-                        v = [asm(outs[o : o + n]) for (o, n, asm) in plans]
+                    v = self._node_pipelined(x, sample_args)
                 else:
                     v = [self._exec_accel(x, sample_args[s]) for s in range(B)]
             else:
@@ -623,8 +581,8 @@ class Executor:
         completes, instead of draining the pipeline per request.
 
         ``prepack`` hands in host packings staged earlier for the *same*
-        program and environment list (anything else is ignored). On
-        synchronous engines (or non-ILA modes) this degrades to an
+        program and environment list (anything else is ignored). On the
+        reference engines (or non-ILA modes) this degrades to an
         already-complete submission wrapping :meth:`run_many`: correct
         everywhere, overlapped only where the engine pipelines."""
         if self.mode != "ila" or self.engine not in ("pipelined", "fused") \
@@ -687,7 +645,7 @@ class Executor:
         for request ``k+1`` while request ``k``'s simulation tail is in
         flight: the single pack worker fills the readback gap instead of
         idling. Numerics are unchanged (same planners, same span grouping
-        as :meth:`_node_pipelined`); on synchronous engines this is a
+        as :meth:`_node_pipelined`); on the reference engines this is a
         no-op."""
         pre = Prepack(e, envs)
         if self.mode != "ila" or self.engine not in ("pipelined", "fused") \
@@ -792,26 +750,26 @@ class Executor:
         return intr.planner(self._ctx(target, self._estimate(target, x, args)), x, args)
 
     # -- job execution ---------------------------------------------------
-    def _group_cycles(self, frag, idxs: List[int], jobs, target, device) -> float:
+    def _group_cycles(self, frag, idxs: List[int], jobs, target,
+                      device: Optional[SimDevice] = None) -> float:
         """Estimated cycles for one signature group on ``device``: data
         commands for every job, plus the setup stream when this device has
-        not simulated it yet (cold weight load). Under the pipelined engine
-        a latency-calibrated CostModel prices the group ``max(pack, sim)``
-        — the stage the group actually occupies the pipeline for — instead
-        of their serial sum."""
+        not simulated it yet (cold weight load), priced by the owning
+        target's CostModel. No device (placement-blind ranking, fused
+        groups) means no cold-load term."""
         n = sum(len(jobs[i].data) for i in idxs)
-        if device.is_cold(frag):
+        if device is not None and device.is_cold(frag):
             n += len(frag.setup)
         model = target.cost_model if target is not None else None
         if model is None:
             return float(n)
-        return model.job_cycles(n, pipelined=self.engine in ("pipelined", "fused"))
+        return model.job_cycles(n)
 
     def _fused_for(self, frag, read, target):
         """The fused fast-path runner for one job group, or None when the
-        compiled tier should execute it: only under ``engine="fused"``, only
-        for fragments whose owning target resolves a
-        :class:`~repro.core.ila.FusedRunner` for the signature, and only
+        fragment-compiler tier should execute it: only under
+        ``engine="fused"``, only for fragments whose owning target resolves
+        a :class:`~repro.core.ila.FusedRunner` for the signature, and only
         when the runner fuses the group's read function (runners bake the
         read-out into the kernel; a planner using a different read falls
         back to the oracle path)."""
@@ -834,8 +792,6 @@ class Executor:
     def _dispatch_jobs(
         self,
         jobs: List[SimJob],
-        sync: bool = False,
-        pack_ahead: bool = False,
         preps: Optional[Dict[Tuple, Any]] = None,
         op: str = "",
     ) -> List[Callable[[], np.ndarray]]:
@@ -845,51 +801,36 @@ class Executor:
         materializer per job (JAX dispatch is asynchronous, so the calls
         return while simulation is still in flight).
 
-        ``sync=True`` (the compiled engine) materializes each group before
-        dispatching the next — the pre-pipeline behavior — and records a
-        :class:`~repro.accel.target.GroupTiming` with the group's exact
-        dispatch-to-materialization wall clock for latency calibration.
-        ``pack_ahead=True`` (the pipelined engine) stages each group's host
-        packing (stacking, shared-payload detection) in the pack worker so
-        it overlaps the previous group's simulation; ``preps`` passes in
-        host packings already prepared elsewhere (``_node_pipelined`` packs
-        them in the worker alongside planning), keyed like
-        :meth:`_group_jobs`. ``op`` names the intrinsic in the spans.
+        Each group's host packing (stacking, shared-payload detection) is
+        staged in the pack worker so it overlaps the previous group's
+        simulation, unless ``preps`` passes it in already prepared
+        elsewhere (``_node_pipelined`` packs it in the worker alongside
+        planning), keyed like :meth:`_group_jobs`. ``op`` names the
+        intrinsic in the spans.
         """
         handles: List[Optional[Callable[[], np.ndarray]]] = [None] * len(jobs)
-        groups = self._group_jobs(jobs)
+        order = [(key, idxs, self.devices.owner(jobs[idxs[0]].frag))
+                 for key, idxs in self._group_jobs(jobs).items()]
         # longest-processing-time-first over each target's device pool; a
         # single-device pool preserves the original group order exactly
-        order = []
-        for key, idxs in groups.items():
-            frag = jobs[idxs[0]].frag
-            target = self.devices.owner(frag)
-            rank = self._group_cycles(frag, idxs, jobs, target, _NullDevice)
-            order.append((rank, idxs, target))
-        multi = any(
-            t is not None and self.devices.n_for(t.name) > 1 for _, _, t in order
-        )
-        if multi:
-            order.sort(key=lambda e: -e[0])
+        if any(t is not None and self.devices.n_for(t.name) > 1
+               for _, _, t in order):
+            order.sort(key=lambda e: -self._group_cycles(
+                jobs[e[1][0]].frag, e[1], jobs, e[2]))
         preps = dict(preps or {})
-        if pack_ahead:
-            for _rank, idxs, _t in order:
-                if len(idxs) > 1:
-                    frag = jobs[idxs[0]].frag
-                    key = (id(frag), jobs[idxs[0]].data.sig())
-                    if key not in preps:
-                        runner = self._fused_for(frag, jobs[idxs[0]].read, _t)
-                        datas = [jobs[i].data for i in idxs]
-                        if runner is not None:
-                            preps[key] = _pack_pool().submit(
-                                lambda r=runner, ds=datas: ("fused", r.prepare(ds))
-                            )
-                        else:
-                            preps[key] = _pack_pool().submit(
-                                frag.prepare_batch, datas
-                            )
+        for key, idxs, target in order:
+            if len(idxs) > 1 and key not in preps:
+                frag = jobs[idxs[0]].frag
+                runner = self._fused_for(frag, jobs[idxs[0]].read, target)
+                datas = [jobs[i].data for i in idxs]
+                if runner is not None:
+                    preps[key] = _pack_pool().submit(
+                        lambda r=runner, ds=datas: ("fused", r.prepare(ds))
+                    )
+                else:
+                    preps[key] = _pack_pool().submit(frag.prepare_batch, datas)
         t_disp = time.perf_counter()
-        for _rank, idxs, target in order:
+        for key, idxs, target in order:
             with TELEMETRY.span("pipeline.dispatch_group",
                                 jobs=len(idxs)) as sp:
                 frag = jobs[idxs[0]].frag
@@ -900,24 +841,19 @@ class Executor:
                 # device-local clone: runners compute from fragment meta, so a
                 # fused group never pays a per-device setup re-simulation
                 runner = self._fused_for(frag, read, target)
-                n_cmds = sum(len(jobs[i].data) for i in idxs)
                 if target is not None:
                     device = self.devices.pick(target)
                     # book against the chosen device, including its cold-setup
                     # cost (the ranking pass above is placement-blind)
-                    if runner is None and device.is_cold(frag):
-                        n_cmds += len(frag.setup)
                     grp_cycles = self._group_cycles(
                         frag, idxs, jobs, target,
-                        _NullDevice if runner is not None else device,
+                        device if runner is None else None,
                     )
                     device.account(len(idxs), grp_cycles)
                     dev_name = device.name
                     if runner is None:
                         frag = device.resolve(frag)
-                stack_dt = 0.0
                 if len(idxs) == 1:
-                    t0 = time.perf_counter()
                     j = jobs[idxs[0]]
                     if runner is not None:
                         group = _GroupResult(runner.run([j.data]))
@@ -931,33 +867,17 @@ class Executor:
                             lambda g=group, w=j.window: g.materialize()[w]
                         )
                 else:
-                    datas = [jobs[i].data for i in idxs]
-
-                    def _prep():
-                        if runner is not None:
-                            return ("fused", runner.prepare(datas))
-                        return frag.prepare_batch(datas)
-
-                    prep = preps.get((id(jobs[idxs[0]].frag), jobs[idxs[0]].data.sig()))
-                    if hasattr(prep, "result"):
+                    prepared = preps[key]
+                    if hasattr(prepared, "result"):
                         with TELEMETRY.span("pipeline.pack_wait", op=op):
-                            prepared = prep.result()
-                    elif prep is not None:
-                        prepared = prep
-                    elif sync:
-                        # host half timed apart so the GroupTiming pack/sim
-                        # split matches what the pipelined engine's pack stage
-                        # actually covers (planner packing + group stacking)
-                        t0 = time.perf_counter()
-                        prepared = _prep()
-                        stack_dt = time.perf_counter() - t0
-                    else:
-                        prepared = _prep()
+                            prepared = prepared.result()
                     # a staged prep can disagree with the resolved path when the
                     # fused env flags flip between pack and dispatch — re-prep
                     if (prepared[0] == "fused") != (runner is not None):
-                        prepared = _prep()
-                    t0 = time.perf_counter()
+                        datas = [jobs[i].data for i in idxs]
+                        prepared = (("fused", runner.prepare(datas))
+                                    if runner is not None
+                                    else frag.prepare_batch(datas))
                     if runner is not None:
                         fulls = runner.dispatch(prepared[1])
                     else:
@@ -968,24 +888,6 @@ class Executor:
                         handles[i] = (
                             lambda g=group, b=bi, w=jobs[i].window: g.materialize()[b][w]
                         )
-                if sync:
-                    group.materialize()
-                    sim_dt = time.perf_counter() - t0
-                    if self.collect_stats:
-                        self._groups_ctr.inc()
-                        self.group_timings.append(GroupTiming(
-                            target.name if target is not None else frag.ila.name,
-                            len(idxs), n_cmds, pack_s=stack_dt,
-                            sim_s=sim_dt,
-                        ))
-                        # drift probe: the scheduler priced this group at
-                        # grp_cycles; the simulation actually took sim_dt. On a
-                        # latency-calibrated model (1 cycle == 1 us) the ratio
-                        # is directly actionable (CostModel.drift_summary)
-                        if target is not None and target.cost_model is not None \
-                                and grp_cycles > 0:
-                            target.cost_model.record_drift(
-                                grp_cycles, sim_dt * 1e6)
                 sp.set(device=dev_name, est_cycles=round(grp_cycles, 1))
         self._stage["dispatch_s"].inc(time.perf_counter() - t_disp)
         return handles
@@ -1006,11 +908,11 @@ class Executor:
             return fn(st)
 
     def _execute_jobs(self, jobs: List[SimJob], op: str = "") -> List[np.ndarray]:
-        """Run simulation jobs to completion. The compiled engine executes
-        group-by-group (synchronous); the pipelined engine dispatches every
-        group asynchronously — host packing staged through the pack worker
-        — and materializes at the end, in job order. ``op`` names the
-        intrinsic in the spans."""
+        """Run simulation jobs to completion: the reference engines one job
+        at a time; the pipelined engines dispatch every group
+        asynchronously — host packing staged through the pack worker — and
+        materialize at the end, in job order. ``op`` names the intrinsic in
+        the spans."""
         if self.engine in ("jit", "eager"):
             results = []
             for j in jobs:
@@ -1019,10 +921,7 @@ class Executor:
                 st = ila.simulate_jit(cmds) if self.engine == "jit" else ila.simulate(cmds)
                 results.append(np.asarray(j.read(st))[j.window])
             return results
-        sync = self.engine == "compiled"
-        handles = self._dispatch_jobs(jobs, sync=sync, pack_ahead=not sync, op=op)
-        if sync:
-            return [h() for h in handles]
+        handles = self._dispatch_jobs(jobs, op=op)
         with TELEMETRY.span("pipeline.readback", jobs=len(jobs)):
             t0 = time.perf_counter()
             results = [h() for h in handles]
@@ -1069,10 +968,6 @@ class Executor:
             self._stage["pack_s"].inc(dt)
             if self.collect_stats:
                 self._groups_ctr.inc()
-                self.group_timings.append(GroupTiming(
-                    target.name, len(jobs), PlanContext.data_ncmds(jobs),
-                    pack_s=dt,
-                ))
             return planned, jobs, preps
 
         return plan_span
@@ -1091,7 +986,7 @@ class Executor:
         simulations to the device queues; results materialize at the final
         assemble barrier, in submission order (deterministic stats/order).
         Chunking only regroups the vmapped batches — per-sample numerics
-        are grouping-independent, so results match the compiled engine
+        are grouping-independent, so results match the reference engines
         bit-for-bit.
 
         ``defer=True`` (submit_many's terminal nodes) dispatches every span
@@ -1147,14 +1042,13 @@ class Executor:
 
     # -- statistics & cache surfacing ------------------------------------
     def reset_stats(self) -> None:
-        """Clear every accumulated statistic: invocation stats, per-group
-        timing records, per-stage timers AND the per-device scheduling
+        """Clear every accumulated statistic: invocation stats, per-stage
+        timers AND the per-device scheduling
         accumulators (cycles/jobs/groups) — so ``stats_summary()``
         utilization after a reset reflects only post-reset work (the
         serving path resets between warmup and measured requests). Warm
         state (fragment caches, compiled runners) is untouched."""
         self.stats.clear()
-        self.group_timings.clear()
         self.metrics.reset()
         for devs in self.devices._devices.values():
             for d in devs:
@@ -1197,22 +1091,6 @@ class Executor:
                 out[t.name] = t.cost_model.calibrate(self.stats)
         return out
 
-    def calibrate_from_timings(self) -> Dict[str, Dict[str, float]]:
-        """Fit every registered target's wall-clock latency model
-        (``CostModel.calibrate_from_timings``) from the per-group timings
-        recorded so far. Synchronous (``compiled``) runs record exact
-        per-group sim timings, so the serving path calibrates during its
-        warmup requests and the pipelined scheduler then prices groups as
-        measured ``max(pack, sim)`` microseconds. Returns the fitted models
-        keyed by target name (targets without usable timings are omitted)."""
-        out: Dict[str, Dict[str, float]] = {}
-        for t in TARGETS.all():
-            if t.cost_model is not None:
-                fit = t.cost_model.calibrate_from_timings(self.group_timings)
-                if fit:
-                    out[t.name] = fit
-        return out
-
     def pipeline_summary(self) -> Dict[str, float]:
         """Per-stage accumulated wall clock plus an overlap estimate:
         ``overlap_s`` is pack time hidden behind simulation (pack runs in
@@ -1220,17 +1098,9 @@ class Executor:
         engine's whole win. All values reset with :meth:`reset_stats`.
         A thin view over the registry's ``pipeline.*`` counters."""
         stages = self.stage_seconds
-        packed = stages["pack_s"]
         busy = stages["dispatch_s"] + stages["readback_s"]
-        return dict(
-            stages,
-            groups=self._groups_ctr.value,
-            overlap_s=(
-                min(packed, busy)
-                if self.engine in ("pipelined", "fused")
-                else 0.0
-            ),
-        )
+        return dict(stages, groups=self._groups_ctr.value,
+                    overlap_s=min(stages["pack_s"], busy))
 
     def cache_info(self, targets: Optional[Sequence[str]] = None) -> Dict[str, Dict]:
         """Per-target warm-cache health: fragment-cache hits/misses plus jit

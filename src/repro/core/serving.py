@@ -198,7 +198,7 @@ class CosimServer:
 
         server = CosimServer(engine="pipelined", queue_depth=32)
         server.add_program("resmlp", program, params)   # or add_app("resmlp")
-        server.start(warmup=1)                          # compiled warmup + calibration
+        server.start(warmup=1)                          # warm every app once
         h = server.submit("resmlp", batch=4)
         outs = h.result()
         server.close(drain=True)
@@ -265,8 +265,7 @@ class CosimServer:
         self._m_coalesced_max.set(1.0)
         self._m_latency = self.metrics.histogram("serving.latency_ms")
         # predicted-vs-actual request pricing: actual served microseconds
-        # over admission-control est_cycles (1.0 == perfectly priced once
-        # the CostModel is latency-calibrated)
+        # over the analytic est_cycles admission control priced it at
         self._m_drift = self.metrics.histogram("serving.drift_ratio")
         self._m_rejected: Dict[str, Any] = {}
 
@@ -546,18 +545,17 @@ class CosimServer:
     # -- lifecycle -------------------------------------------------------
     def start(self, warmup: int = 1, warm_batch: Optional[int] = None) -> "CosimServer":
         """Start the dispatch thread. ``warmup`` > 0 first runs every
-        registered app on the synchronous compiled engine (filling
-        fragment caches AND recording the exact per-group timings that
-        calibrate each target's wall-clock CostModel), then one trace
-        request per app on the serving engine, then resets the stats so
-        measured serving starts clean. Also switches the vmapped batch
-        axis to the serving bucket ladder (restored by :meth:`close`)."""
+        registered app ``warmup`` times at ``warm_batch`` samples (default
+        ``max_batch``) through the serving engine itself, filling fragment
+        caches and compiling its vmap shapes (and fused runners) so
+        measured requests start warm, then resets the stats so measured
+        serving starts clean. Also switches the vmapped batch axis to the
+        serving bucket ladder (restored by :meth:`close`)."""
         if self._thread is not None:
             raise RuntimeError("server already started")
         self._prev_ladder = ila.set_batch_ladder(self.batch_ladder)
         if warmup > 0:
             ex = self.executor
-            engine = ex.engine
             wb = warm_batch or self.max_batch
             rng = np.random.default_rng(self.seed)  # warmup-only stream
             warm_envs = {
@@ -568,16 +566,8 @@ class CosimServer:
                 ]
                 for name, a in self._apps.items()
             }
-            ex.engine = "compiled"
             for name, a in self._apps.items():
                 for _ in range(warmup):
-                    ex.run_many(a.program, warm_envs[name])
-            ex.calibrate_from_timings()
-            ex.engine = engine
-            if engine != "compiled":
-                # trace the serving engine's own vmap shapes (and fused
-                # runners) so measured requests start warm
-                for name, a in self._apps.items():
                     ex.submit_many(a.program, warm_envs[name]).result()
             ex.reset_stats()
         self._thread = threading.Thread(

@@ -55,7 +55,7 @@ def main() -> None:
     ap.add_argument("--apps", default="resmlp,lstm-wlm",
                     help="applications for the app-metric tier")
     ap.add_argument("--engine", default="pipelined",
-                    choices=["compiled", "pipelined", "jit", "eager"])
+                    choices=["pipelined", "jit", "eager"])
     ap.add_argument("--devices-per-target", type=int, default=2)
     ap.add_argument("--ladder", default="full", choices=["full", "escalate"],
                     help="full = every tier on every mutant (complete "

@@ -18,10 +18,9 @@ serves it through :class:`repro.core.serving.CosimServer`: a bounded
 request queue + single dispatch thread where request k+1's host packing
 overlaps request k's simulation tail (``submit_many``/``prepack_many``),
 queued same-app requests coalesce into one vmapped dispatch, and
-admission control rejects work beyond ``--queue-depth``. Warmup runs on
-the synchronous ``compiled`` engine — filling every fragment cache and
-calibrating each target's wall-clock CostModel — then measured requests
-run on ``--engine`` (default ``pipelined``, or ``REPRO_ENGINE``).
+admission control rejects work beyond ``--queue-depth``. Warmup requests
+and measured requests both run on ``--engine`` (default ``pipelined``, or
+``REPRO_ENGINE``).
 
 ``--concurrency N`` bounds the load generator's outstanding requests;
 ``--arrival poisson:RATE`` draws exponential inter-arrival gaps at RATE
@@ -35,7 +34,6 @@ coalescing order). See ``docs/serving.md``.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 import traceback
@@ -126,15 +124,12 @@ def serve_cosim(args) -> None:
     if args.mesh != "off":
         print(f"stream mesh: {mesh if mesh is not None else 'disabled (single device host)'}")
 
-    # the serving path defaults to the async engine (unlike the Executor's
-    # process-wide compiled default): --engine > REPRO_ENGINE > pipelined.
-    # The chunk size is clamped so even the default --batch splits into
+    # the chunk size is clamped so even the default --batch splits into
     # >= 2 pack/sim chunks per node — a single-chunk batch has nothing for
-    # the pipeline to overlap.
-    engine = args.engine or os.environ.get("REPRO_ENGINE") or "pipelined"
+    # the pipeline to overlap
     rate = _parse_arrival(args.arrival)
     server = CosimServer(
-        engine=engine,
+        engine=args.engine,
         devices_per_target=args.devices_per_target,
         pipeline_chunk=max(1, min(8, -(-args.batch // 2))),
         queue_depth=args.queue_depth,
@@ -145,6 +140,7 @@ def serve_cosim(args) -> None:
     )
     server.add_program(args.cosim.lower(), res.program, params)
     ex = server.executor
+    engine = ex.engine  # --engine > REPRO_ENGINE > pipelined
 
     warmup = max(args.warmup, 1)
     t0 = time.perf_counter()
@@ -152,7 +148,7 @@ def serve_cosim(args) -> None:
     warm_s = time.perf_counter() - t0
     cold_ms = warm_s / (warmup * args.batch) * 1e3
     print(f"warmup: {warmup} request(s) x batch {args.batch} in {warm_s:.3f}s "
-          f"({cold_ms:.1f} ms/sample incl. compile+traces, compiled engine) "
+          f"({cold_ms:.1f} ms/sample incl. compile+traces) "
           f"-> serving on {engine}")
 
     handles, load_s = drive_load(
@@ -183,8 +179,8 @@ def serve_cosim(args) -> None:
               f"mean {summ['mean_batch']:.1f} req/batch, "
               f"max {summ['coalesced_max']}")
         if warm_ms > 0 and np.isfinite(warm_ms) and np.isfinite(cold_ms):
-            print(f"cold vs steady state: {cold_ms:.1f} ms/sample (warmup, "
-                  f"compiled) vs {warm_ms:.1f} ms/sample (mean of "
+            print(f"cold vs steady state: {cold_ms:.1f} ms/sample (warmup) "
+                  f"vs {warm_ms:.1f} ms/sample (mean of "
                   f"{len(served)} served, {engine}) "
                   f"-> {cold_ms / warm_ms:.1f}x")
     else:
@@ -209,31 +205,13 @@ def serve_cosim(args) -> None:
               f"(overlap ~{stages['overlap_s']:.3f}s)")
     print("\ncache health:", ex.cache_info())
 
-    # drift probes: how far the CostModel's pricing sits from measured
-    # latency (docs/observability.md, "Drift probes") — request-level
-    # drift is in the serving.drift_ratio histogram of --metrics
-    from ..core.ila import TARGETS
-    drifts = {
-        t.name: t.cost_model.drift_summary()
-        for t in TARGETS.all()
-        if t.cost_model is not None and t.cost_model.drift_summary()
-    }
-    if drifts:
-        print("cost-model drift (actual us / predicted cycles):")
-        for tname, d in sorted(drifts.items()):
-            print(f"  {tname}: geomean {d['ratio_geomean']:.2f} "
-                  f"(spread {d['log_ratio_std']:.2f}, n={d['n']:.0f}, "
-                  f"{'latency-calibrated' if d['calibrated'] else 'analytic'})")
-    else:
-        # pipelined serving: per-group drift needs a synchronous
-        # materialize (and warmup calibration just reset the probes), so
-        # fall back to the request-level ratio admission control ran under
-        dr = server.metrics.find("serving.drift_ratio")
-        if dr and dr[0].snapshot()["count"]:
-            s = dr[0].snapshot()
-            print(f"admission drift (service us / priced cycles): "
-                  f"p50 {s['p50']:.2f} p95 {s['p95']:.2f} "
-                  f"(n={s['count']}, latency-calibrated)")
+    # request-level drift: the ratio admission control ran under
+    # (docs/observability.md, "Drift probes")
+    dr = server.metrics.find("serving.drift_ratio")
+    if dr and dr[0].snapshot()["count"]:
+        s = dr[0].snapshot()
+        print(f"admission drift (service us / priced cycles): "
+              f"p50 {s['p50']:.2f} p95 {s['p95']:.2f} (n={s['count']})")
 
     if args.trace:
         path = TELEMETRY.export_trace(args.trace)
@@ -296,10 +274,9 @@ def main():
     ap.add_argument("--devices-per-target", type=int, default=1,
                     help="simulated device instances per accelerator target")
     ap.add_argument("--engine", default=None,
-                    choices=["compiled", "pipelined", "fused", "jit", "eager"],
-                    help="co-sim engine for measured requests (default: "
-                         "REPRO_ENGINE or pipelined); warmup always runs "
-                         "compiled to calibrate the cost models")
+                    choices=["pipelined", "fused", "jit", "eager"],
+                    help="co-sim engine for warmup and measured requests "
+                         "(default: REPRO_ENGINE or pipelined)")
     ap.add_argument("--mesh", default="off",
                     help='"off" (default), "auto" (all host devices) or an '
                          "int: shard the vmapped batch axis over a device mesh")

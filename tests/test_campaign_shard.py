@@ -38,7 +38,7 @@ def _registry_snapshot():
 _BASE = dict(
     targets=("vecunit",),
     faults=("identity", "drop_cfg", "trunc_width"),
-    apps=(), engine="compiled", devices_per_target=1,
+    apps=(), engine="pipelined", devices_per_target=1,
     op_samples=1, vt2_n=2, seed=0, stat_calib_seeds=0,
 )
 

@@ -214,7 +214,7 @@ def test_stat_tier_disabled_without_calibration(monkeypatch):
     monkeypatch.setattr(campaign_mod, "_prepare_app", fake_prepare)
     r = campaign_mod.run_campaign(
         targets=("vecunit",), faults=("identity",), apps=("resmlp",),
-        engine="compiled", devices_per_target=1, op_samples=1, vt2_n=2,
+        engine="pipelined", devices_per_target=1, op_samples=1, vt2_n=2,
         stat_calib_seeds=0,
     )
     (rep,) = r.reports

@@ -19,7 +19,7 @@ def static_campaign():
         targets=["vecunit", "hlscnn"],
         faults=["identity", "decode_alias", "cmd_reorder", "drop_cfg"],
         apps=(),
-        engine="compiled",
+        engine="pipelined",
         devices_per_target=1,
         ladder="escalate",
         op_samples=1,

@@ -91,7 +91,7 @@ def test_identity_fault_bit_exact_across_engines(t):
     (inst,) = faults.fault_instances(t, ("identity",))
     mutant = faults.make_mutant(t, inst)
     with faults.swapped_in(mutant):
-        for engine in ("compiled", "pipelined", "jit", "eager"):
+        for engine in ("pipelined", "jit", "eager"):
             got = np.asarray(
                 Executor("ila", engine=engine, target_options=opts).run(expr, env)
             )
@@ -103,7 +103,7 @@ def test_identity_fault_bit_exact_across_engines(t):
 
 def test_mutated_write_instruction_holds_on_every_engine():
     """A bulk-mutating fault (write-path semantics change) produces the
-    SAME faulty output on compiled, pipelined, jit and eager engines: the
+    SAME faulty output on the pipelined, jit and eager engines: the
     mutant planner's stream conversion keeps the fragment compiler honest
     when its slice-update lowering assumption is broken."""
     t = TARGETS.get("vecunit")
@@ -116,14 +116,14 @@ def test_mutated_write_instruction_holds_on_every_engine():
     with faults.swapped_in(mutant):
         outs = {
             engine: np.asarray(Executor("ila", engine=engine).run(expr, env))
-            for engine in ("compiled", "pipelined", "jit", "eager")
+            for engine in ("pipelined", "jit", "eager")
         }
-    assert validate.frob_rel_err(golden, outs["compiled"]) > 0, (
+    assert validate.frob_rel_err(golden, outs["pipelined"]) > 0, (
         "addr_swap mutant did not perturb the output at all"
     )
     for engine, got in outs.items():
         np.testing.assert_array_equal(
-            outs["compiled"], got,
+            outs["pipelined"], got,
             err_msg=f"mutated write path drifted between engines ({engine})",
         )
 
@@ -132,8 +132,7 @@ def test_payload_fault_holds_on_every_engine():
     """A payload-transform fault (write-datapath corruption applied
     host-side, keeping the bulk fast path) produces the SAME faulty output
     on all engines: eager/jit consume the transformed full command list,
-    compiled/pipelined the transformed streams through the rebound
-    fragments."""
+    pipelined the transformed streams through the rebound fragments."""
     t = TARGETS.get("vecunit")
     (inst,) = faults.fault_instances(t, ("round_floor",))
     assert inst.payload is not None and not inst.mutates_bulk
@@ -144,14 +143,14 @@ def test_payload_fault_holds_on_every_engine():
     with faults.swapped_in(mutant):
         outs = {
             engine: np.asarray(Executor("ila", engine=engine).run(expr, env))
-            for engine in ("compiled", "pipelined", "jit", "eager")
+            for engine in ("pipelined", "jit", "eager")
         }
-    assert validate.frob_rel_err(golden, outs["compiled"]) > 0, (
+    assert validate.frob_rel_err(golden, outs["pipelined"]) > 0, (
         "round_floor mutant did not perturb the output at all"
     )
     for engine, got in outs.items():
         np.testing.assert_array_equal(
-            outs["compiled"], got,
+            outs["pipelined"], got,
             err_msg=f"payload fault drifted between engines ({engine})",
         )
 
@@ -170,7 +169,7 @@ def test_campaign_leaves_registry_bit_identical():
         targets=("vecunit", "hlscnn"),
         faults=("identity", "drop_cfg", "trunc_width"),
         apps=(),                      # no app tier: lifecycle-only campaign
-        engine="compiled", devices_per_target=1,
+        engine="pipelined", devices_per_target=1,
         op_samples=1, vt2_n=2,
     )
     assert len(result.reports) == 6
@@ -189,7 +188,7 @@ def test_serial_crash_isolated_and_registry_clean():
     result = campaign_mod.run_campaign(
         targets=("vecunit",),
         faults=("identity", "drop_cfg", "crash_inject"),
-        apps=(), engine="compiled", devices_per_target=1,
+        apps=(), engine="pipelined", devices_per_target=1,
         op_samples=1, vt2_n=2, stat_calib_seeds=0,
     )
     assert _registry_snapshot() == before
@@ -227,7 +226,7 @@ def test_mutant_raising_inside_app_tier_leaves_registry_clean(monkeypatch):
     before = _registry_snapshot()
     result = campaign_mod.run_campaign(
         targets=("vecunit",), faults=("identity", "drop_cfg"),
-        apps=("resmlp",), engine="compiled", devices_per_target=1,
+        apps=("resmlp",), engine="pipelined", devices_per_target=1,
         op_samples=1, vt2_n=2, stat_calib_seeds=0, ladder="full",
     )
     assert _registry_snapshot() == before, (
@@ -383,7 +382,7 @@ def test_boundary_sampling_makes_sat_wrap_op_tier_detectable():
     phenomenon above stays reproducible."""
     base = dict(
         targets=("flexasr",), faults=("sat_wrap",), apps=(),
-        engine="compiled", devices_per_target=1,
+        engine="pipelined", devices_per_target=1,
         op_samples=1, vt2_n=2, stat_calib_seeds=0, ladder="full",
     )
     miss = campaign_mod.run_campaign(**base)

@@ -180,7 +180,7 @@ def test_fragment_cache_reuses_setup():
 
 
 def test_executor_engines_agree():
-    """Compiled Executor == seed-style jit-scan Executor == run_many."""
+    """Pipelined Executor == seed-style jit-scan Executor == run_many."""
     from repro.core import apps
     from repro.core.codegen import Executor
     from repro.core.compile import compile_program
@@ -191,7 +191,7 @@ def test_executor_engines_agree():
         v.shape for v in _vars(res.program) if v.name == "x"
     )
     X = [rng.standard_normal(xs_shape).astype(np.float32) for _ in range(3)]
-    ex_c = Executor("ila", engine="compiled")
+    ex_c = Executor("ila", engine="pipelined")
     ex_j = Executor("ila", engine="jit")
     outs_c = [np.asarray(ex_c.run(res.program, dict(params, x=x))) for x in X]
     outs_j = [np.asarray(ex_j.run(res.program, dict(params, x=x))) for x in X]

@@ -90,7 +90,7 @@ def test_xla_fallback_replicates_compiled(op, make, options, exact, monkeypatch)
     bit-for-bit; the LSTM's hoisted projection stays within TIGHT."""
     monkeypatch.setenv("REPRO_FUSED_FALLBACK", "1")
     args, attrs = make()
-    ref, _ = _run(op, args, attrs, "compiled", options)
+    ref, _ = _run(op, args, attrs, "pipelined", options)
     got, ex = _run(op, args, attrs, "fused", options)
     if exact:
         np.testing.assert_array_equal(ref, got)
@@ -112,7 +112,7 @@ def test_pallas_lowering_tracks_compiled(op, make, options, exact, monkeypatch):
     lowering — covered here for the parity contract all the same."""
     monkeypatch.setenv("REPRO_FUSED_PALLAS", "1")
     args, attrs = make()
-    ref, _ = _run(op, args, attrs, "compiled", options)
+    ref, _ = _run(op, args, attrs, "pipelined", options)
     got, _ = _run(op, args, attrs, "fused", options)
     assert validate.frob_rel_err(ref, got) <= TIGHT
     # on the CPU backend a Pallas leg is built in interpret mode
@@ -179,7 +179,7 @@ def test_repro_engine_env_selects_fused(monkeypatch):
     ex = Executor("ila")
     assert ex.engine == "fused"
     args, attrs = _hlscnn_conv_args()
-    ref, _ = _run("hlscnn_conv2d", args, attrs, "compiled",
+    ref, _ = _run("hlscnn_conv2d", args, attrs, "pipelined",
                   {"hlscnn": {"wgt_bits": 16}})
     vs = tuple(ir.Var(f"_{i}", a.shape) for i, a in enumerate(args))
     env = {f"_{i}": a for i, a in enumerate(args)}

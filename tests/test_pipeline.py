@@ -1,6 +1,6 @@
-"""Pipelined-engine unit tests: the Executor's stage plumbing, the
-latency-calibrated cost model, stats reset semantics, the host/dispatch
-split of the batched simulation tiers, and the bench JSON writer.
+"""Pipelined-engine unit tests: the Executor's stage plumbing and engine
+selection, stats reset semantics, the host/dispatch split of the batched
+simulation tiers, and the bench JSON writer.
 
 Target-parameterized pipelined bit-exactness/determinism/mesh coverage
 lives in tests/test_target_conformance.py; these tests pin the pieces the
@@ -13,9 +13,8 @@ import numpy as np
 import pytest
 
 from repro.accel import flexasr as fa
-from repro.accel.target import CostModel, GroupTiming
 from repro.core import ila as ila_mod, ir
-from repro.core.codegen import Executor
+from repro.core.codegen import ENGINES, Executor
 
 
 def _linear_program(T=8, I=32, O=16, seed=0):
@@ -77,14 +76,14 @@ def test_reset_stats_clears_devices_timers_and_timings():
     reflects post-reset work (the serving path resets between warmup and
     measured requests)."""
     expr, env = _linear_program()
-    ex = Executor("ila", engine="compiled", devices_per_target=2)
+    ex = Executor("ila", engine="pipelined", devices_per_target=2)
     ex.run_many(expr, [env, env, env])
-    assert ex.stats and ex.group_timings
+    assert ex.stats
     assert sum(ex.stage_seconds.values()) > 0
     devs = [d for ds in ex.devices._devices.values() for d in ds]
     assert sum(d.n_jobs for d in devs) >= 3
     ex.reset_stats()
-    assert not ex.stats and not ex.group_timings
+    assert not ex.stats
     assert sum(ex.stage_seconds.values()) == 0.0
     for d in devs:
         assert d.busy_cycles == 0.0 and d.n_jobs == 0 and d.n_groups == 0
@@ -110,59 +109,40 @@ def test_pipeline_summary_reports_stage_seconds():
 
 
 # ---------------------------------------------------------------------------
-# latency-calibrated cost model
+# engine selection: one compiled execution path plus its references
 # ---------------------------------------------------------------------------
 
 
-def test_calibrate_from_timings_fits_affine_stage_models():
-    """Synthetic timings with known slopes/intercepts are recovered (in us,
-    1 cycle == 1 us for the fitted job_cycles model) and the pipelined job
-    price is max(pack, sim) vs their serial sum. cycles_per_command stays
-    in analytic units: estimate() feeds cross-target extraction, which must
-    not compare microseconds against competitors' analytic cycles."""
-    m = CostModel("synth", cycles_per_command=7.0)
-    timings = [
-        GroupTiming("synth", 1, n, pack_s=3e-6 * n + 1e-4,
-                    sim_s=1e-6 * n + 2e-4)
-        for n in (100, 500, 1000, 4000)
-    ] + [GroupTiming("other", 1, 50, pack_s=1.0, sim_s=1.0)]  # ignored
-    fit = m.calibrate_from_timings(timings)
-    assert fit["sim_us_per_command"] == pytest.approx(1.0, rel=1e-3)
-    assert fit["sim_overhead_us"] == pytest.approx(200.0, rel=1e-3)
-    assert fit["pack_us_per_command"] == pytest.approx(3.0, rel=1e-3)
-    assert m.cycles_per_command == 7.0  # analytic units untouched
-    n = 1000.0
-    serial = m.job_cycles(n)
-    overlapped = m.job_cycles(n, pipelined=True)
-    assert serial == pytest.approx((1000 + 200) + (3000 + 100), rel=1e-3)
-    assert overlapped == pytest.approx(3000 + 100, rel=1e-3)  # pack-bound
+def test_engines_are_the_served_path_and_its_references():
+    assert ENGINES == ("pipelined", "fused", "jit", "eager")
 
 
-def test_calibrate_from_timings_single_group_falls_back_to_ratio():
-    m = CostModel("synth")
-    fit = m.calibrate_from_timings([GroupTiming("synth", 1, 200, sim_s=4e-4)])
-    assert fit["sim_us_per_command"] == pytest.approx(2.0, rel=1e-6)
-    assert fit["sim_overhead_us"] == 0.0
-    assert m.calibrate_from_timings([]) == fit  # no new data: fit unchanged
+def test_compiled_engine_is_rejected():
+    with pytest.raises(ValueError, match="unknown engine 'compiled'"):
+        Executor("ila", engine="compiled")
 
 
-def test_executor_calibrate_from_timings_end_to_end():
-    """Synchronous runs record per-group sim timings; calibration turns
-    them into a measured-latency cost model for the owning target."""
-    expr, env = _linear_program()
-    ex = Executor("ila", engine="compiled")
-    ex.run_many(expr, [env, env])
-    fits = ex.calibrate_from_timings()
-    assert "flexasr" in fits
-    assert fits["flexasr"]["sim_us_per_command"] > 0
-    assert fits["flexasr"]["pack_us_per_command"] > 0
-    from repro.core.ila import TARGETS
+def test_default_engine_is_pipelined_and_bit_exact_vs_jit(monkeypatch):
+    """An Executor built with no engine serves a bundled application on
+    the pipelined engine, bit-for-bit with the jit-scan reference."""
+    from repro.core import apps
+    from repro.core.compile import compile_program
 
-    target_model = TARGETS.get("flexasr").cost_model
-    try:
-        assert target_model.latency  # stored on the model for the scheduler
-    finally:
-        target_model.latency.clear()  # leave the process-wide model clean
+    monkeypatch.delenv("REPRO_ENGINE", raising=False)
+    expr, params = apps.build_resmlp(seed=0)
+    program = compile_program(expr).program
+    xshape = next(v.shape for v in ir.postorder(program)
+                  if isinstance(v, ir.Var) and v.name == "x")
+    rng = np.random.default_rng(5)
+    envs = [dict(params, x=rng.standard_normal(xshape).astype(np.float32))
+            for _ in range(2)]
+    ex = Executor("ila")
+    assert ex.engine == "pipelined"
+    got = ex.run_many(program, envs)
+    assert ex.pipeline_summary()["groups"] >= 1  # the pack stage ran
+    ref = Executor("ila", engine="jit").run_many(program, envs)
+    for r, g in zip(ref, got):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
 
 
 # ---------------------------------------------------------------------------

@@ -55,8 +55,8 @@ def test_coalesced_equals_serial_bit_exact():
     """Submit a burst of batch-1 requests before start(): the dispatch
     thread wakes to a full queue and must coalesce them into shared
     vmapped dispatches, and every request's outputs must be bit-identical
-    to running its (seed, request_id)-derived envs alone on a synchronous
-    executor."""
+    to running its (seed, request_id)-derived envs alone on a fresh
+    one-device executor."""
     srv = _server(seed=3, max_batch=8, queue_depth=32)
     handles = [srv.submit("tiny", batch=1) for _ in range(6)]
     try:
@@ -70,7 +70,7 @@ def test_coalesced_equals_serial_bit_exact():
     )
     assert srv.summary()["coalesced_max"] > 1
 
-    serial = Executor("ila", engine="compiled")
+    serial = Executor("ila", engine="pipelined")
     expr, _params = _tiny_program()
     for h in handles:
         envs = srv.request_envs("tiny", h.id, 1)
@@ -83,6 +83,67 @@ def test_coalesced_equals_serial_bit_exact():
         np.testing.assert_array_equal(
             np.asarray(ref), outs[h.id][0],
             err_msg=f"request {h.id}: coalesced result differs from serial")
+
+
+def test_warmup_leaves_engine_and_cost_models_unchanged():
+    """start(warmup=1) warms through the serving engine itself: it never
+    switches the executor's engine and never refits a target's CostModel
+    (job pricing stays the analytic one every single-device run uses)."""
+    import copy
+
+    from repro.core.ila import TARGETS
+
+    def models():
+        return {t.name: (copy.deepcopy(vars(t.cost_model)),
+                         t.cost_model.job_cycles(1000.0))
+                for t in TARGETS.all() if t.cost_model is not None}
+
+    srv = _server(seed=1, max_batch=4)
+    ex = srv.executor
+    seen = []
+    for name in ("run_many", "submit_many"):
+        call = getattr(ex, name)
+
+        def spy(*a, _call=call, **kw):
+            seen.append(ex.engine)
+            return _call(*a, **kw)
+
+        setattr(ex, name, spy)
+    before = models()
+    srv.start(warmup=1)
+    try:
+        assert seen and set(seen) == {"pipelined"}
+        assert ex.engine == "pipelined"
+        assert models() == before
+    finally:
+        srv.close()
+
+
+def test_served_requests_leave_no_growing_record_on_the_executor():
+    """Serving more requests grows nothing on the executor but ``stats``
+    (which ``collect_stats`` governs): no per-request or per-group log."""
+    srv = _server(seed=2, max_batch=4)
+    ex = srv.executor
+
+    def sizes():
+        out = {k: len(v) for k, v in vars(ex).items()
+               if k != "stats" and isinstance(v, (list, dict, set, tuple))}
+        out["metrics"] = len(ex.metrics.snapshot())
+        return out
+
+    def serve(n):
+        for _ in range(n):
+            srv.submit("tiny", batch=2).result(timeout=300)
+
+    srv.start(warmup=1)
+    try:
+        serve(2)
+        first, n_stats = sizes(), len(ex.stats)
+        serve(6)
+        assert sizes() == first
+        assert len(ex.stats) > n_stats
+    finally:
+        srv.close()
 
 
 def test_request_rng_is_interleaving_independent():

@@ -8,7 +8,7 @@ draws random operands within the target's declared capability limits):
 * ideal-vs-numerics (VT1-style): the ILA co-simulation of each intrinsic
   tracks the fp32 IR-interpreter oracle within the intrinsic's declared
   tolerance;
-* engine parity: eager per-command simulation == jit scan == compiled
+* engine parity: eager per-command simulation == jit scan == pipelined
   fragment fast path == batched ``run_many``, bit-for-bit;
 * rewrite soundness: each target-declared VT2 fragment pair agrees under
   ideal semantics, and compiling the compiler-IR side against that target
@@ -24,16 +24,18 @@ draws random operands within the target's declared capability limits):
   unregistered inside the test — no bundled backend is named);
 * multi-device scheduling: with ``devices_per_target=2`` results stay
   bit-exact and ``stats_summary`` reports per-device utilization;
-* pipelined engine: async pack/sim pipelining is bit-exact vs the compiled
-  engine for every target and device count, deterministic across runs
+* pipelined engine: chunked pack/sim pipelining is bit-exact vs the
+  one-device, default-chunk run for every target and device count,
+  deterministic across runs
   (identical results AND stable assemble/stat order), and the mesh-sharded
   batch tier matches unsharded execution (skipped on single-device hosts —
   CI forces 4 virtual devices with XLA_FLAGS).
 
-* fused engine: ``engine="fused"`` matches the compiled oracle within each
+* fused engine: ``engine="fused"`` matches the pipelined engine within each
   intrinsic's declared tolerance for every target and device count —
   targets registering fused runners (``declare_fused``) take the fast
-  path, everything else falls back to per-group compiled execution — and
+  path, everything else falls back to per-group fragment-compiler
+  execution — and
   the forced XLA-fallback lowering (``REPRO_FUSED_FALLBACK=1``) is
   exercised explicitly so the ``jnp`` leg stays covered even on hosts
   where Pallas lowers natively.
@@ -98,22 +100,19 @@ def test_ideal_vs_numerics_within_declared_tol(t, intr):
 
 @pytest.mark.parametrize("t,intr", _intrinsic_params())
 def test_engines_bit_exact(t, intr):
-    """eager per-command == jit scan == compiled fast path == pipelined
-    == run_many."""
+    """eager per-command == jit scan == pipelined fast path == run_many."""
     expr, env = _case(t, intr, 2)
     _, env2 = _case(t, intr, 3)
-    out_c = np.asarray(_executor(t, intr, engine="compiled").run(expr, env))
     out_j = np.asarray(_executor(t, intr, engine="jit").run(expr, env))
     out_e = np.asarray(_executor(t, intr, engine="eager").run(expr, env))
     out_p = np.asarray(_executor(t, intr, engine="pipelined").run(expr, env))
-    np.testing.assert_array_equal(out_c, out_j, err_msg=f"{t.name}:{intr.op} compiled != jit")
-    np.testing.assert_array_equal(out_c, out_e, err_msg=f"{t.name}:{intr.op} compiled != eager")
-    np.testing.assert_array_equal(out_c, out_p, err_msg=f"{t.name}:{intr.op} compiled != pipelined")
+    np.testing.assert_array_equal(out_p, out_j, err_msg=f"{t.name}:{intr.op} pipelined != jit")
+    np.testing.assert_array_equal(out_p, out_e, err_msg=f"{t.name}:{intr.op} pipelined != eager")
     # batched path: same env twice through one vmapped call per node
-    outs_m = _executor(t, intr, engine="compiled").run_many(expr, [env, env])
+    outs_m = _executor(t, intr, engine="pipelined").run_many(expr, [env, env])
     for o in outs_m:
         np.testing.assert_array_equal(
-            out_c, np.asarray(o), err_msg=f"{t.name}:{intr.op} run_many != run"
+            out_p, np.asarray(o), err_msg=f"{t.name}:{intr.op} run_many != run"
         )
     # a second distinct sample keeps its own numerics when batched
     ref2 = np.asarray(_executor(t, intr).run(expr, env2))
@@ -125,31 +124,34 @@ def test_engines_bit_exact(t, intr):
 @pytest.mark.parametrize("ndev", (1, 2), ids=("1dev", "2dev"))
 @pytest.mark.parametrize("t,intr", _intrinsic_params())
 def test_fused_parity_within_declared_tol(t, intr, ndev, mode, monkeypatch):
-    """engine="fused" tracks the compiled oracle within the intrinsic's
-    declared tolerance (bit-exact where the fused numerics round-trip the
-    compiled arithmetic — asserted per-target in test_fused.py), through
+    """engine="fused" tracks the pipelined engine at one device within the
+    intrinsic's declared tolerance (bit-exact where the fused numerics
+    round-trip the fragment-compiler arithmetic — asserted per-target in
+    test_fused.py), through
     run and run_many, across device counts. ``mode=fallback`` forces the
     XLA-fused lowering (``REPRO_FUSED_FALLBACK=1``) so the ``jnp`` leg is
     covered even on hosts where Pallas lowers natively; targets without a
-    registered fused runner execute per-group compiled and must stay
-    bit-exact either way."""
+    registered fused runner execute per-group on the fragment-compiler tier
+    and must stay bit-exact either way."""
     if intr.planner is None:
         pytest.skip("pass-through intrinsic: nothing to fuse")
     if mode == "fallback":
         monkeypatch.setenv("REPRO_FUSED_FALLBACK", "1")
     expr, env = _case(t, intr, 2)
     _, env2 = _case(t, intr, 3)
-    ref = np.asarray(_executor(t, intr, engine="compiled").run(expr, env))
+    ref = np.asarray(_executor(t, intr, engine="pipelined",
+                               devices_per_target=1).run(expr, env))
     got = np.asarray(
         _executor(t, intr, engine="fused", devices_per_target=ndev).run(expr, env)
     )
     assert got.shape == ref.shape
     err = validate.frob_rel_err(ref, got)
     assert err <= intr.tol, (
-        f"{t.name}:{intr.op} fused-vs-compiled rel err {err} > tol {intr.tol}"
+        f"{t.name}:{intr.op} fused-vs-pipelined rel err {err} > tol {intr.tol}"
     )
     # batched parity through run_many (the vmapped/fused-dispatch path)
-    refs = _executor(t, intr, engine="compiled").run_many(expr, [env, env2, env])
+    refs = _executor(t, intr, engine="pipelined",
+                     devices_per_target=1).run_many(expr, [env, env2, env])
     outs = _executor(t, intr, engine="fused",
                      devices_per_target=ndev).run_many(expr, [env, env2, env])
     for r, o in zip(refs, outs):
@@ -394,21 +396,24 @@ def test_multi_device_bit_exact_and_utilization_reported(t):
 
 def _pipelined_envs(t, intr, n=5):
     """n environments (two distinct samples interleaved) for one intrinsic,
-    plus the compiled-engine reference outputs."""
+    plus reference outputs from the pipelined engine at one device and its
+    default chunk."""
     expr, env = _case(t, intr, 11)
     _, env2 = _case(t, intr, 12)
     envs = [env, env2, env, env2, env][:n]
-    ref = _executor(t, intr, engine="compiled").run_many(expr, envs)
+    ref = _executor(t, intr, engine="pipelined",
+                    devices_per_target=1).run_many(expr, envs)
     return expr, envs, ref
 
 
 @pytest.mark.parametrize("ndev", (1, 2), ids=("1dev", "2dev"))
 @pytest.mark.parametrize("t,intr", _intrinsic_params())
 def test_pipelined_bit_exact_across_device_counts(t, intr, ndev):
-    """engine="pipelined" matches the compiled engine bit-for-bit through
-    run_many, for every registered target and device count (chunked
-    planning + async dispatch + LPT scheduling must not change results).
-    pipeline_chunk=2 forces several pack/sim pipeline stages."""
+    """engine="pipelined" with pipeline_chunk=2 (several pack/sim pipeline
+    stages) matches the one-device, default-chunk reference bit-for-bit
+    through run_many, for every registered target and device count
+    (chunked planning + async dispatch + LPT scheduling must not change
+    results)."""
     if intr.planner is None:
         pytest.skip("pass-through intrinsic: nothing to pipeline")
     expr, envs, ref = _pipelined_envs(t, intr)
@@ -418,7 +423,7 @@ def test_pipelined_bit_exact_across_device_counts(t, intr, ndev):
     for r, o in zip(ref, outs):
         np.testing.assert_array_equal(
             np.asarray(r), np.asarray(o),
-            err_msg=f"{t.name}:{intr.op} pipelined != compiled ({ndev} devices)",
+            err_msg=f"{t.name}:{intr.op} pipelined != reference ({ndev} devices)",
         )
 
 
@@ -461,7 +466,8 @@ def test_mesh_sharded_batch_parity(t, intr):
     mesh = ila_mod.set_stream_mesh("auto")
     assert mesh is not None
     try:
-        outs = _executor(t, intr, engine="compiled").run_many(expr, envs)
+        outs = _executor(t, intr, engine="pipelined",
+                         pipeline_chunk=2).run_many(expr, envs)
         outs_p = _executor(t, intr, engine="pipelined").run_many(expr, envs)
         outs_f = _executor(t, intr, engine="fused").run_many(expr, envs)
     finally:

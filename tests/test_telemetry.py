@@ -22,7 +22,6 @@ import time
 import numpy as np
 import pytest
 
-from repro.accel.target import CostModel, GroupTiming
 from repro.core.telemetry import (
     TELEMETRY, Histogram, MetricsRegistry, Telemetry, check_metric_names,
 )
@@ -378,33 +377,6 @@ def test_enable_disable_roundtrip():
 
 
 # ---------------------------------------------------------------------------
-# drift probes
-# ---------------------------------------------------------------------------
-
-
-def test_cost_model_drift_summary():
-    m = CostModel("vta")
-    assert m.drift_summary() is None
-    m.record_drift(100.0, 200.0)   # 2x under-priced
-    m.record_drift(100.0, 50.0)    # 2x over-priced
-    d = m.drift_summary()
-    assert d["n"] == 2
-    assert d["ratio_geomean"] == pytest.approx(1.0)  # log-space symmetry
-    assert d["ratio_min"] == pytest.approx(0.5)
-    assert d["ratio_max"] == pytest.approx(2.0)
-    assert d["calibrated"] == 0.0
-    m.record_drift(0.0, 5.0)       # degenerate predictions are ignored
-    assert m.drift_summary()["n"] == 2
-    # fitting a new latency model invalidates drift observed under the old
-    m.calibrate_from_timings([
-        GroupTiming("vta", 4, 100, pack_s=0.01, sim_s=0.02),
-        GroupTiming("vta", 8, 200, pack_s=0.02, sim_s=0.04),
-    ])
-    assert m.drift_summary() is None
-    assert m.latency  # the fit itself landed
-
-
-# ---------------------------------------------------------------------------
 # instrumented layers
 # ---------------------------------------------------------------------------
 
@@ -451,7 +423,7 @@ def test_serving_reject_reasons_are_aggregated_counters():
     b = np.zeros((8,), np.float32)
     prog = ir.call("fasr_linear", ir.Var("x", (4, 16)),
                    ir.Var("w", w.shape), ir.Var("b", b.shape))
-    srv = CosimServer(engine="compiled", queue_depth=1, seed=0)
+    srv = CosimServer(engine="pipelined", queue_depth=1, seed=0)
     srv.add_program("tiny", prog, {"w": w, "b": b})
     # no dispatch thread started: submissions stay queued, so the second
     # and third hit the depth-1 admission bound
@@ -483,7 +455,7 @@ def test_sharded_campaign_merges_worker_spans():
         result = campaign_mod.run_campaign_sharded(
             workers=1, mutant_timeout=300.0, trace_spans=True,
             targets=("vecunit",), faults=("identity",), apps=(),
-            engine="compiled", devices_per_target=1,
+            engine="pipelined", devices_per_target=1,
             op_samples=1, vt2_n=2, seed=0, stat_calib_seeds=0,
         )
         assert len(result.reports) == 1
